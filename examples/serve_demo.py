@@ -1,10 +1,12 @@
 """Train-while-serve demo (ISSUE 7): checkpoint hot-swap end to end.
 
-A trainer subprocess writes full-state checkpoint anchors every round while
-THIS process serves query batches from the same directory via the hot-swap
-watcher (``launch.serve.run_watch``): the server picks up each new anchor
-between query batches, and a deliberately truncated checkpoint file is
-REJECTED loudly while serving continues from the last good step.
+The trainer (``launch.train.run``) writes full-state checkpoint anchors
+every round while a server thread of the SAME process answers query
+batches from that directory via the hot-swap watcher
+(``launch.serve.run_watch``): the server picks up each new anchor between
+query batches, and a deliberately truncated checkpoint file is REJECTED
+loudly while serving continues from the last good step.  One process holds
+the device, so the demo runs on a single accelerator as it does on the CPU.
 
     PYTHONPATH=src python examples/serve_demo.py
 
@@ -21,10 +23,7 @@ Phases:
 The batched static-serving demo (prefill + per-arch decode cache) stays at
 the end.
 """
-import os
 import pathlib
-import subprocess
-import sys
 import tempfile
 import threading
 import time
@@ -32,19 +31,13 @@ import time
 from repro import checkpoint as ckpt
 from repro.launch.serve import run as serve_once
 from repro.launch.serve import run_watch
-
-REPO_SRC = str(pathlib.Path(__file__).resolve().parents[1] / "src")
-ENV = {**os.environ, "PYTHONPATH": REPO_SRC}
+from repro.launch.train import run as train_run
 
 
 def train(ckpt_dir: str, steps: int, *, resume: bool = False):
-    cmd = [sys.executable, "-m", "repro.launch.train", "--arch", "olmo-1b",
-           "--steps", str(steps), "--k", "1", "--eta", "0.05",
-           "--clients", "2", "--batch", "2", "--seq", "32",
-           "--log-every", "1", "--ckpt-dir", ckpt_dir, "--ckpt-every", "1"]
-    if resume:
-        cmd.append("--resume")
-    subprocess.run(cmd, check=True, env=ENV)
+    train_run("olmo-1b", steps=steps, k=1, eta=0.05, m=2, per_client_batch=2,
+              seq_len=32, log_every=1, ckpt_dir=ckpt_dir, ckpt_every=1,
+              resume=resume)
 
 
 with tempfile.TemporaryDirectory() as d:

@@ -11,7 +11,7 @@ with the conventions:
     full transformer parameter tree);
   * per-client entries in ``state`` are stacked with a leading client dim m;
   * ``grad_fn(params_i, batch_i) -> grad`` is the per-client gradient oracle;
-    ``round`` vmaps it over the client dim, so the same code runs the paper's
+    ``round`` maps it over the client dim, so the same code runs the paper's
     least-squares problems and sharded LM training;
   * ``batch`` leaves have leading dim m, or (K, m, ...) when
     ``per_step_batches=True`` (one minibatch per inner gradient step, the
@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import FederatedConfig
+from repro.sharding.constraints import per_client
 
 
 class FedOpt(NamedTuple):
@@ -99,18 +100,43 @@ def arena_grad(grad_fn, spec):
 
     Returns ``(ga, native)`` where ``ga((m, width), batch) -> (m, width)``.
     Oracles advertising ``grad_arena`` run entirely in arena space (0 extra
-    full-state passes); plain grads are vmapped through the pytree boundary
-    (unpack x + pack g: +4 passes per step for multi-leaf trees).
+    full-state passes); plain grads are mapped over the client rows through
+    the pytree boundary (unpack x + pack g: +4 passes per step for
+    multi-leaf trees).
+
+    The plain path is a ``lax.map`` over rows, not a ``vmap`` over the
+    stacked tree: one client's activations are live at a time, and the TPU
+    compile of a model's gradient stays the single-client one (a vmapped
+    full-width LM gradient took minutes to compile for a v5e).  Under a
+    client-sharded mesh each device maps over its own rows (``per_client``).
     """
     factory = getattr(grad_fn, "grad_arena", None)
     if factory is not None:
         return factory(spec), True
-    vgrad = jax.vmap(grad_fn)
 
     def ga(xa, b):
-        return spec.pack_stacked(vgrad(spec.unpack_stacked(xa), b))
+        return map_clients(
+            lambda row, bi: spec.pack(grad_fn(spec.unpack(row), bi)), xa, b)
 
     return ga, False
+
+
+def map_clients(fn, x, batch):
+    """``fn(x_i, batch_i)`` stacked over the leading client dim of ``x`` and
+    ``batch`` (pytrees), one client at a time: a ``lax.map``, run by each
+    device over its own clients under a client-sharded mesh
+    (``per_client``).  Every layout computes client gradients this way, so
+    the arena and pytree rounds see the same per-client arithmetic."""
+    x_leaves, x_def = jax.tree.flatten(x)
+    b_leaves, b_def = jax.tree.flatten(batch)
+    n = len(x_leaves)
+
+    def local(*leaves):
+        return jax.lax.map(
+            lambda a: fn(*a), (jax.tree.unflatten(x_def, leaves[:n]),
+                               jax.tree.unflatten(b_def, leaves[n:])))
+
+    return per_client(local, (*x_leaves, *b_leaves))
 
 
 def use_arena(cfg: FederatedConfig, params=None) -> bool:

@@ -23,7 +23,8 @@ from repro.configs.base import FederatedConfig
 from repro.core import arena, faults, staleness
 from repro.core import tree_util as T
 from repro.core.api import (
-    FedOpt, cohort_batch, run_cohort_inner, use_arena, use_cohort,
+    FedOpt, cohort_batch, map_clients, run_cohort_inner, use_arena,
+    use_cohort,
 )
 from repro.core.gpdmm import _eta_val, _step_for, participation_key, popstore_tail
 from repro.core.scaffold import inner_steps_plain_arena
@@ -209,7 +210,7 @@ def _round(cfg: FederatedConfig, state, grad_fn, batch, per_step_batches=False):
     x_s = state["x_s"]
     m = _num_clients(state, batch, per_step_batches)
     x_s_b = T.tree_broadcast(x_s, m)
-    vgrad = jax.vmap(grad_fn)
+    vgrad = partial(map_clients, grad_fn)
 
     def one_step(x, xs_k):
         b = xs_k if per_step_batches else batch

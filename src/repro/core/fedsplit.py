@@ -27,7 +27,9 @@ import jax.numpy as jnp
 from repro.configs.base import FederatedConfig
 from repro.core import arena
 from repro.core import tree_util as T
-from repro.core.api import FedOpt, arena_grad, resolved_rho, use_arena
+from repro.core.api import (
+    FedOpt, arena_grad, map_clients, resolved_rho, use_arena,
+)
 from repro.kernels import ops
 
 
@@ -125,7 +127,7 @@ def _round_inexact(cfg: FederatedConfig, state, grad_fn, batch, per_step_batches
     K, eta = cfg.inner_steps, cfg.eta
     z_s, x_s = state["z_s"], state["x_s"]
     m = jax.tree.leaves(z_s)[0].shape[0]
-    vgrad = jax.vmap(grad_fn)
+    vgrad = partial(map_clients, grad_fn)
 
     if cfg.fedsplit_init == "z":
         x0 = z_s  # the paper's diagnosed improper init

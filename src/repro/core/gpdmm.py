@@ -33,7 +33,7 @@ from repro.configs.base import FederatedConfig
 from repro.core import arena, faults, staleness
 from repro.core import tree_util as T
 from repro.core.api import (
-    FedOpt, affine_case, arena_grad, cohort_batch, resolved_rho,
+    FedOpt, affine_case, arena_grad, cohort_batch, map_clients, resolved_rho,
     run_cohort_inner, use_arena, use_cohort,
 )
 from repro.kernels import ops
@@ -77,7 +77,7 @@ def inner_steps(grad_fn, x0, x_s_b, lam_s, batch, *, K, eta, rho, per_step,
     """
     eta = _eta_val(eta)
     step_c = 1.0 / (1.0 / eta + rho)
-    vgrad = jax.vmap(grad_fn)
+    vgrad = partial(map_clients, grad_fn)
 
     gbar = None
     if vr_snapshot is not None:

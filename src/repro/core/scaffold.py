@@ -42,8 +42,8 @@ from repro.configs.base import FederatedConfig
 from repro.core import arena, faults, staleness
 from repro.core import tree_util as T
 from repro.core.api import (
-    FedOpt, affine_case, arena_grad, cohort_batch, run_cohort_inner,
-    use_arena, use_cohort,
+    FedOpt, affine_case, arena_grad, cohort_batch, map_clients,
+    run_cohort_inner, use_arena, use_cohort,
 )
 from repro.core.gpdmm import _eta_val, _step_for, participation_key
 from repro.kernels import ops
@@ -337,7 +337,7 @@ def _round(cfg: FederatedConfig, state, grad_fn, batch, per_step_batches=False):
     c_b = T.tree_broadcast(c, m)
     # lam := c - c_i enters the shared fused step with rho = 0
     lam = T.tree_sub(c_b, c_i)
-    vgrad = jax.vmap(grad_fn)
+    vgrad = partial(map_clients, grad_fn)
 
     def one_step(x, xs_k):
         b = xs_k if per_step_batches else batch

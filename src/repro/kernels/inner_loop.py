@@ -50,6 +50,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.fused_update import LANES, VMEM_CAP_BYTES, eq20
+from repro.kernels.round_tail import client_row
 
 
 def vmem_bytes(width: int) -> int:
@@ -72,15 +73,17 @@ def _kernel(*refs, K: int, step, rho: float, has_lam: bool, has_off: bool,
     xk_ref, xb_ref = next(it), next(it)
 
     f32 = jnp.float32
+    # every row operand is a (1, 1, W) block of an (m, 1, W) array: [0]
+    # gives the client's (1, W) row
     H = h_ref[0].astype(f32)  # (W, W), resident for all K steps
-    c = c_ref[...].astype(f32)  # (1, W)
+    c = c_ref[0].astype(f32)  # (1, W)
     if off_ref is not None:  # per-client affine offset: g = H x - (c + off)
-        c = c + off_ref[...].astype(f32)
+        c = c + off_ref[0].astype(f32)
     xs = xs_ref[...].astype(f32)
-    lam = lam_ref[...].astype(f32) if lam_ref is not None else None
-    x0 = x_ref[...].astype(f32)
+    lam = lam_ref[0].astype(f32) if lam_ref is not None else None
+    x0 = x_ref[0].astype(f32)
     if step_ref is not None:  # per-client stepsize operand (core.autotune)
-        step = step_ref[0, 0]
+        step = step_ref[0][:, :1]  # (1, 1): the constant row's first lane
 
     def body(_, carry):
         x, xsum = carry
@@ -93,8 +96,8 @@ def _kernel(*refs, K: int, step, rho: float, has_lam: bool, has_off: bool,
         return x, xsum + x
 
     x_K, xsum = jax.lax.fori_loop(0, K, body, (x0, jnp.zeros_like(x0)))
-    xk_ref[...] = x_K.astype(xk_ref.dtype)
-    xb_ref[...] = (xsum * (1.0 / K)).astype(xb_ref.dtype)
+    xk_ref[0] = x_K.astype(xk_ref.dtype)
+    xb_ref[0] = (xsum * (1.0 / K)).astype(xb_ref.dtype)
 
 
 def inner_loop_affine_pallas(x0, H, c, x_s, lam, step, rho, K: int, *,
@@ -104,7 +107,10 @@ def inner_loop_affine_pallas(x0, H, c, x_s, lam, step, rho, K: int, *,
     (per-client affine offset, g = H x - (c + off)); step: scalar (baked as
     a compile-time constant -- the pre-auto-eta path, bitwise unchanged) or
     (m,) per-client stepsizes loaded as a (1, LANES) row operand per grid
-    step (core.autotune).  Returns (x_K, x_bar), both (m, W)."""
+    step (core.autotune).  Returns (x_K, x_bar), both (m, W).
+
+    Client rows travel as (m, 1, W) with (1, 1, W) blocks: a (1, W) block of
+    an (m, W) array is not (8, 128)-aligned, which the TPU lowering refuses."""
     m, w = x0.shape
     assert w % LANES == 0, f"arena width {w} not a multiple of {LANES}"
     assert H.shape == (m, w, w) and c.shape == (m, w), (H.shape, c.shape)
@@ -113,9 +119,10 @@ def inner_loop_affine_pallas(x0, H, c, x_s, lam, step, rho, K: int, *,
     assert fits_vmem(w), (
         f"width={w}: fused K-step working set {vmem_bytes(w)} B exceeds the "
         f"{VMEM_CAP_BYTES} B VMEM budget -- use the step-at-a-time path")
-    row_bs = pl.BlockSpec((1, w), lambda i: (i, 0))
-    out_sds = jax.ShapeDtypeStruct((m, w), x0.dtype)
-    args = [x0, H, c, x_s.reshape(1, w)]
+    row_bs = pl.BlockSpec((1, 1, w), lambda i: (i, 0, 0))
+    out_sds = jax.ShapeDtypeStruct((m, 1, w), x0.dtype)
+    rows3 = lambda a: a.reshape(m, 1, w)  # noqa: E731
+    args = [rows3(x0), H, rows3(c), x_s.reshape(1, w)]
     in_specs = [
         row_bs,
         pl.BlockSpec((1, w, w), lambda i: (i, 0, 0)),
@@ -123,17 +130,16 @@ def inner_loop_affine_pallas(x0, H, c, x_s, lam, step, rho, K: int, *,
         pl.BlockSpec((1, w), lambda i: (0, 0)),  # server row: every client
     ]
     if lam is not None:
-        args.append(lam)
+        args.append(rows3(lam))
         in_specs.append(row_bs)
     if off is not None:
-        args.append(off)
+        args.append(rows3(off))
         in_specs.append(row_bs)
     has_step = jnp.ndim(step) > 0
     if has_step:
         assert step.shape == (m,), step.shape
-        args.append(jnp.broadcast_to(
-            step.astype(jnp.float32)[:, None], (m, LANES)))
-        in_specs.append(pl.BlockSpec((1, LANES), lambda i: (i, 0)))
+        args.append(client_row(step))
+        in_specs.append(pl.BlockSpec((1, 1, LANES), lambda i: (i, 0, 0)))
     x_K, x_bar = pl.pallas_call(
         functools.partial(_kernel, K=int(K),
                           step=None if has_step else float(step),
@@ -146,4 +152,4 @@ def inner_loop_affine_pallas(x0, H, c, x_s, lam, step, rho, K: int, *,
         out_shape=(out_sds, out_sds),
         interpret=interpret,
     )(*args)
-    return x_K, x_bar
+    return x_K.reshape(m, w), x_bar.reshape(m, w)

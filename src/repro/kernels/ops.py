@@ -1,14 +1,22 @@
 """Jit-ready kernel wrappers with implementation dispatch.
 
 ``impl`` selects the backend:
-  * ``"xla"``              -- chunked pure-jnp path (default; what the CPU
-                              dry-run and the smoke tests lower)
+  * ``"xla"``              -- chunked pure-jnp path
   * ``"pallas"``           -- Pallas TPU kernel (the deployment target)
   * ``"pallas_interpret"`` -- Pallas kernel body interpreted on CPU; used by
                               the kernel test-suite to validate the TPU code.
 
-The global default can be set once via ``set_default_impl`` (the launcher does
-this based on ``jax.default_backend()``).
+``impl=None`` (every library call site) resolves from the platform
+(``default_impl``): ``"pallas"`` when JAX's default backend is a TPU,
+``"xla"`` elsewhere.  Flash attention and wkv6 are the exception: they have
+no Pallas backward, so they resolve to ``"xla"`` everywhere (see
+``flash_attention``).  ``RESOLVED`` records what each op resolved to at its
+last trace, so a run can report the path it took.
+
+Under ``jax.set_mesh`` with client axes that split the client dim, the
+per-client-row kernels run on each device's own rows
+(``sharding.constraints.per_client``): the TPU compiler cannot partition a
+Pallas kernel itself.
 """
 from __future__ import annotations
 
@@ -21,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ref as _ref
+from repro.sharding.constraints import per_client
 
 
 def _step_arr(step):
@@ -32,17 +41,21 @@ def _step_arr(step):
         return None
     return jnp.asarray(step, jnp.float32)
 
-_DEFAULT_IMPL = "xla"
+
+# op name -> implementation it resolved to when last traced
+RESOLVED: dict[str, str] = {}
 
 
-def set_default_impl(impl: str) -> None:
-    global _DEFAULT_IMPL
-    assert impl in ("xla", "pallas", "pallas_interpret")
-    _DEFAULT_IMPL = impl
+def default_impl() -> str:
+    """The platform's implementation: Pallas kernels on a TPU, XLA elsewhere."""
+    return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
-def _resolve(impl: Optional[str]) -> str:
-    return impl or _DEFAULT_IMPL
+def _resolve(impl: Optional[str], op: str) -> str:
+    impl = impl or default_impl()
+    assert impl in ("xla", "pallas", "pallas_interpret"), impl
+    RESOLVED[op] = impl
+    return impl
 
 
 # ---------------------------------------------------------------------------
@@ -142,7 +155,10 @@ def flash_attention(
     q (B,Sq,H,hd); k (B,Sk,Hkv,hd); v (B,Sk,Hkv,vd); positions as in
     ``ref.attention_ref``.
     """
-    impl = _resolve(impl)
+    # No Pallas backward exists, and the model calls this under jax.grad in
+    # every training step, so the default is "xla" on every platform;
+    # impl="pallas" runs the forward-only kernel (serving, compile tests).
+    impl = _resolve(impl or "xla", "flash_attention")
     if impl == "xla":
         return _flash_xla(
             q, k, v, q_pos, k_pos,
@@ -235,8 +251,9 @@ def _wkv6_chunked_xla(r, k, v, w, u, s0, *, chunk: int):
 
 
 def wkv6(r, k, v, w, u, s0, *, chunk: int = 64, impl: Optional[str] = None):
-    """RWKV-6 recurrence. Shapes as in ``ref.wkv6_ref``."""
-    impl = _resolve(impl)
+    """RWKV-6 recurrence. Shapes as in ``ref.wkv6_ref``.  Like
+    ``flash_attention``: no Pallas backward, so "xla" unless asked."""
+    impl = _resolve(impl or "xla", "wkv6")
     if impl == "xla":
         return _wkv6_chunked_xla(r, k, v, w, u, s0, chunk=chunk)
     from repro.kernels import wkv6 as wk
@@ -272,7 +289,7 @@ def fused_update(x, g, xs, lam, step, rho, *, impl: Optional[str] = None,
     leaf); the array form rides the pure-jnp reference -- the per-leaf pytree
     layout is not the per-client-eta deployment path, the arena is.
     """
-    impl = _resolve(impl)
+    impl = _resolve(impl, "fused_update")
     if impl == "xla" or _step_arr(step) is not None:
         return _ref.fused_update_ref(x, g, xs, lam, step, rho)
     from repro.kernels import fused_update as fu
@@ -299,7 +316,7 @@ def fused_update_arena(x, g, x_s, lam, step, rho, *, impl: Optional[str] = None,
     ``step``: scalar (baked into the kernel -- bitwise the pre-auto-eta
     graph) or (m,) per-client stepsizes (``core.autotune``), fed to the
     kernel as a broadcast row operand."""
-    impl = _resolve(impl)
+    impl = _resolve(impl, "fused_update_arena")
     step_a = _step_arr(step)
     if impl == "xla":
         step_b = step if step_a is None else step_a[:, None]
@@ -307,10 +324,11 @@ def fused_update_arena(x, g, x_s, lam, step, rho, *, impl: Optional[str] = None,
             x, g, x_s[None] if x_s.ndim == 1 else x_s, lam, step_b, rho)
     from repro.kernels import round_tail as rt
 
-    return rt.fused_update_arena_pallas(
-        x, g, x_s, lam, step if step_a is None else step_a, rho,
-        block=block, interpret=(impl == "pallas_interpret")
-    )
+    return per_client(
+        lambda x, g, lam, step_a, x_s: rt.fused_update_arena_pallas(
+            x, g, x_s, lam, step if step_a is None else step_a, rho,
+            block=block, interpret=(impl == "pallas_interpret")),
+        (x, g, lam, step_a), (x_s,))
 
 
 def inner_loop_affine(x0, H, c, x_s, lam, step, rho, K: int, *,
@@ -331,7 +349,7 @@ def inner_loop_affine(x0, H, c, x_s, lam, step, rho, K: int, *,
     ``step``: scalar (baked -- bitwise the pre-auto-eta kernel) or (m,)
     per-client stepsizes fed as a row operand (``core.autotune``).
     """
-    impl = _resolve(impl)
+    impl = _resolve(impl, "inner_loop_affine")
     step_a = _step_arr(step)
     if impl == "xla":
         f32 = jnp.float32
@@ -356,10 +374,11 @@ def inner_loop_affine(x0, H, c, x_s, lam, step, rho, K: int, *,
         return x_K.astype(x0.dtype), (xsum * (1.0 / K)).astype(x0.dtype)
     from repro.kernels import inner_loop as il
 
-    return il.inner_loop_affine_pallas(
-        x0, H, c, x_s, lam, step if step_a is None else step_a, rho, K,
-        off=off, interpret=(impl == "pallas_interpret")
-    )
+    return per_client(
+        lambda x0, H, c, lam, off, step_a, x_s: il.inner_loop_affine_pallas(
+            x0, H, c, x_s, lam, step if step_a is None else step_a, rho, K,
+            off=off, interpret=(impl == "pallas_interpret")),
+        (x0, H, c, lam, off, step_a), (x_s,))
 
 
 def scaffold_cv(c_i, x_K, c_s, x_s, alpha, *, impl: Optional[str] = None,
@@ -375,7 +394,7 @@ def scaffold_cv(c_i, x_K, c_s, x_s, alpha, *, impl: Optional[str] = None,
 
     ``alpha``: scalar (baked) or (m,) per-client 1/(K eta_i) under auto-eta
     (``core.autotune``), fed as a row operand."""
-    impl = _resolve(impl)
+    impl = _resolve(impl, "scaffold_cv")
     alpha_a = _step_arr(alpha)
     if impl == "xla":
         f32 = jnp.float32
@@ -385,10 +404,11 @@ def scaffold_cv(c_i, x_K, c_s, x_s, alpha, *, impl: Optional[str] = None,
         return out.astype(c_i.dtype)
     from repro.kernels import round_tail as rt
 
-    return rt.scaffold_cv_pallas(
-        c_i, x_K, c_s, x_s, alpha if alpha_a is None else alpha_a,
-        block=block, interpret=(impl == "pallas_interpret")
-    )
+    return per_client(
+        lambda c_i, x_K, alpha_a, c_s, x_s: rt.scaffold_cv_pallas(
+            c_i, x_K, c_s, x_s, alpha if alpha_a is None else alpha_a,
+            block=block, interpret=(impl == "pallas_interpret")),
+        (c_i, x_K, alpha_a), (c_s, x_s))
 
 
 def affine_inner_fits(width: int) -> bool:
@@ -409,7 +429,7 @@ def round_tail(x_ref, lam_s, x_s, rho, *, with_lam_is: bool = True,
     x_ref, lam_s: (m, width); x_s: (width,).  Returns (lam_is, uplink);
     ``with_lam_is=False`` (the non-trace training path -- callers discard
     lam_is) skips the lam_is output: 3 reads + 1 write, returns (None, u)."""
-    impl = _resolve(impl)
+    impl = _resolve(impl, "round_tail")
     if impl == "xla":
         xr = x_ref.astype(jnp.float32)
         lam = lam_s.astype(jnp.float32)
@@ -419,24 +439,27 @@ def round_tail(x_ref, lam_s, x_s, rho, *, with_lam_is: bool = True,
         return (lam_is.astype(x_ref.dtype) if with_lam_is else None), uplink
     from repro.kernels import round_tail as rt
 
-    return rt.round_tail_pallas(
-        x_ref, lam_s, x_s, rho, with_lam_is=with_lam_is, block=block,
-        interpret=(impl == "pallas_interpret"),
-    )
+    return per_client(
+        lambda x_ref, lam_s, x_s: rt.round_tail_pallas(
+            x_ref, lam_s, x_s, rho, with_lam_is=with_lam_is, block=block,
+            interpret=(impl == "pallas_interpret")),
+        (x_ref, lam_s), (x_s,))
 
 
 def dual_from_uplink(uplink, x_s, rho, *, impl: Optional[str] = None,
                      block: Optional[int] = None):
     """lam_s' = rho (u - x_s') -- the post-all-reduce dual refresh; one pass."""
-    impl = _resolve(impl)
+    impl = _resolve(impl, "dual_from_uplink")
     if impl == "xla":
         out = rho * (uplink.astype(jnp.float32) - x_s.astype(jnp.float32)[None])
         return out.astype(uplink.dtype)
     from repro.kernels import round_tail as rt
 
-    return rt.dual_from_uplink_pallas(
-        uplink, x_s, rho, block=block, interpret=(impl == "pallas_interpret")
-    )
+    return per_client(
+        lambda uplink, x_s: rt.dual_from_uplink_pallas(
+            uplink, x_s, rho, block=block,
+            interpret=(impl == "pallas_interpret")),
+        (uplink,), (x_s,))
 
 
 def screen_uplink(u, ref, *, impl: Optional[str] = None,
@@ -454,7 +477,7 @@ def screen_uplink(u, ref, *, impl: Optional[str] = None,
     reference (graph rounds screen each node against its own carry).
     Returns ``(finite (m,) bool, sq (m,) f32)``.
     """
-    impl = _resolve(impl)
+    impl = _resolve(impl, "screen_uplink")
     if impl == "xla":
         uf = u.astype(jnp.float32)
         rf = ref.astype(jnp.float32)
@@ -465,8 +488,12 @@ def screen_uplink(u, ref, *, impl: Optional[str] = None,
         return jnp.all(fin_e, axis=1), jnp.sum(d * d, axis=1)
     from repro.kernels import screen as sk
 
-    return sk.screen_uplink_pallas(
-        u, ref, block=block, interpret=(impl == "pallas_interpret"))
+    per_row = ref.ndim == 2
+    return per_client(
+        lambda u, ref_r, *ref_s: sk.screen_uplink_pallas(
+            u, ref_r if per_row else ref_s[0], block=block,
+            interpret=(impl == "pallas_interpret")),
+        (u, ref if per_row else None), () if per_row else (ref,))
 
 
 def residual_norm(x, x_prev, *, impl: Optional[str] = None,
@@ -482,15 +509,17 @@ def residual_norm(x, x_prev, *, impl: Optional[str] = None,
     ``||x - x_prev|| / ||x|| < tol`` without a second read of either buffer.
     Returns ``(dx2 (m,) f32, x2 (m,) f32)``; all math in f32.
     """
-    impl = _resolve(impl)
+    impl = _resolve(impl, "residual_norm")
     if impl == "xla":
         xf = x.astype(jnp.float32)
         d = xf - x_prev.astype(jnp.float32)
         return jnp.sum(d * d, axis=1), jnp.sum(xf * xf, axis=1)
     from repro.kernels import residual as rs
 
-    return rs.residual_norm_pallas(
-        x, x_prev, block=block, interpret=(impl == "pallas_interpret"))
+    return per_client(
+        lambda x, x_prev: rs.residual_norm_pallas(
+            x, x_prev, block=block, interpret=(impl == "pallas_interpret")),
+        (x, x_prev))
 
 
 def stale_mix(uplink, cache, buf, fresh, store, w, *, impl: Optional[str] = None,
@@ -510,7 +539,7 @@ def stale_mix(uplink, cache, buf, fresh, store, w, *, impl: Optional[str] = None
     arithmetic runs in f32 and casts back, matching the pallas kernel.
     Returns ``(mixed, buf_new)``.
     """
-    impl = _resolve(impl)
+    impl = _resolve(impl, "stale_mix")
     if impl == "xla":
         cache2 = cache if cache.ndim == 2 else cache[None]
         base = jnp.where(fresh[:, None], uplink, cache2)
@@ -521,9 +550,14 @@ def stale_mix(uplink, cache, buf, fresh, store, w, *, impl: Optional[str] = None
         return mixed, buf_new
     from repro.kernels import stale_mix as sm
 
-    return sm.stale_mix_pallas(
-        uplink, cache, buf, fresh, store, w, block=block,
-        interpret=(impl == "pallas_interpret"))
+    per_row = cache.ndim == 2
+    return per_client(
+        lambda uplink, cache_r, buf, fresh, store, w, *cache_s:
+            sm.stale_mix_pallas(
+                uplink, cache_r if per_row else cache_s[0], buf, fresh,
+                store, w, block=block, interpret=(impl == "pallas_interpret")),
+        (uplink, cache if per_row else None, buf, fresh, store, w),
+        () if per_row else (cache,))
 
 
 def _ef21_row_scales(rowmax, leaf_rows, lo: float):
@@ -553,7 +587,7 @@ def ef21_update(u, u_hat, bits: int, leaf_rows, *, impl: Optional[str] = None,
     ``leaf_rows``: static per-leaf row counts (``ArenaSpec.leaf_rows()``);
     the quantisation scale is per (client, leaf), exactly as the pytree path.
     """
-    impl = _resolve(impl)
+    impl = _resolve(impl, "ef21_update")
     lo = float(2 ** (bits - 1) - 1)
     m, w = u.shape
     rows = w // 128
@@ -567,9 +601,15 @@ def ef21_update(u, u_hat, bits: int, leaf_rows, *, impl: Optional[str] = None,
     from repro.kernels import round_tail as rt
 
     interp = impl == "pallas_interpret"
-    rowmax = rt.ef21_rowmax_pallas(u, u_hat, block=block, interpret=interp)
+    rowmax = per_client(
+        lambda u, u_hat: rt.ef21_rowmax_pallas(u, u_hat, block=block,
+                                               interpret=interp),
+        (u, u_hat))
     scales = _ef21_row_scales(rowmax, leaf_rows, lo)
-    return rt.ef21_apply_pallas(u, u_hat, scales, bits, block=block, interpret=interp)
+    return per_client(
+        lambda u, u_hat, scales: rt.ef21_apply_pallas(
+            u, u_hat, scales, bits, block=block, interpret=interp),
+        (u, u_hat, scales))
 
 
 # ---------------------------------------------------------------------------
@@ -582,7 +622,7 @@ def row_gather(arr, idx, *, impl: Optional[str] = None, block: Optional[int] = N
     int row ids.  One read of the gathered rows + one write of the
     (m_active, width) cohort buffer; the Pallas path rides a scalar-prefetch
     input index map (no materialised permutation)."""
-    impl = _resolve(impl)
+    impl = _resolve(impl, "row_gather")
     if impl == "xla":
         return jnp.take(arr, idx, axis=0)
     from repro.kernels import gather as gk
@@ -599,7 +639,7 @@ def row_scatter(dst, idx, rows, *, impl: Optional[str] = None,
     re-phrases it as a population-grid gather through the inverse position
     table pos[idx[t]] = t with a keep-mask at silent rows, so every output
     row is written exactly once and no input/output aliasing is needed."""
-    impl = _resolve(impl)
+    impl = _resolve(impl, "row_scatter")
     if impl == "xla":
         return dst.at[idx].set(rows, unique_indices=True)
     from repro.kernels import gather as gk
@@ -627,7 +667,7 @@ def neighbor_reduce(z, *, seg, first, sgn, n: int,
     sign).  Node i's slots are contiguous, so the XLA reference is a sorted
     segment-sum; the Pallas kernel fuses the sign apply + reduction into one
     pass with the output row resident in VMEM across each segment."""
-    impl = _resolve(impl)
+    impl = _resolve(impl, "neighbor_reduce")
     if impl == "xla":
         zf = z.astype(jnp.float32)
         signed = jnp.where(jnp.asarray(sgn)[:, None] >= 0, zf, -zf)
@@ -658,7 +698,7 @@ def edge_flip(z, x, c, *, rev, nbr, sgn, mask=None,
     node ``nbr[t]`` fired) keeps z[t] at silent slots -- the stochastic
     node-firing / color-schedule variant.  One pass; both gathers ride the
     Pallas scalar-prefetch index maps (no materialised z[rev] copy)."""
-    impl = _resolve(impl)
+    impl = _resolve(impl, "edge_flip")
     if impl == "xla":
         zf = z.astype(jnp.float32)
         flip = (zf[jnp.asarray(rev)]

@@ -14,11 +14,11 @@ reusable for cohort paths -- the caller reduces over whichever rows
 participated.
 
 Layout: grid ``(m, rows_p // block)`` with the width blocks INNERMOST, so
-each client's two per-lane accumulator rows -- ``(1, LANES)`` f32 blocks of
-the tiny ``(m, LANES)`` outputs -- are revisited across the row's width
+each client's two per-lane accumulator rows -- ``(1, 1, LANES)`` f32 blocks
+of the tiny ``(m, 1, LANES)`` outputs -- are revisited across the row's width
 blocks and stay VMEM-resident (the same revisited-output accumulation
 contract as ``screen`` / ``neighbor_reduce``).  The cheap cross-lane finish
-(sum over LANES) runs on the ``(m, LANES)`` partials outside the kernel.
+(sum over LANES) runs on the per-lane partials outside the kernel.
 
 Zero padding -- the arena tail rows and the ``rows_p - rows`` tile pad,
 zero on BOTH operands by the arena invariant -- contributes zero to both
@@ -39,8 +39,8 @@ def _residual_kernel(x_ref, p_ref, dx_ref, x2_ref):
     x = x_ref[0].astype(jnp.float32)  # (br, LANES)
     p = p_ref[0].astype(jnp.float32)
     d = x - p
-    dx = jnp.sum(d * d, axis=0)  # (LANES,) per-lane partial
-    x2 = jnp.sum(x * x, axis=0)
+    dx = jnp.sum(d * d, axis=0, keepdims=True)  # (1, LANES) per-lane partial
+    x2 = jnp.sum(x * x, axis=0, keepdims=True)
 
     @pl.when(j == 0)
     def _init():
@@ -70,14 +70,14 @@ def residual_norm_pallas(x, x_prev, *, block=None, interpret: bool = False):
     xt, _, rows_p = _tile(x, br)
     pt, _, _ = _tile(x_prev, br)
     client_bs = pl.BlockSpec((1, br, LANES), lambda i, j: (i, j, 0))
-    acc_bs = pl.BlockSpec((1, LANES), lambda i, j: (i, 0))
+    acc_bs = pl.BlockSpec((1, 1, LANES), lambda i, j: (i, 0, 0))
     dx, x2 = pl.pallas_call(
         _residual_kernel,
         grid=(m, rows_p // br),  # width blocks innermost: accumulators stay hot
         in_specs=[client_bs, client_bs],
         out_specs=(acc_bs, acc_bs),
-        out_shape=(jax.ShapeDtypeStruct((m, LANES), jnp.float32),
-                   jax.ShapeDtypeStruct((m, LANES), jnp.float32)),
+        out_shape=(jax.ShapeDtypeStruct((m, 1, LANES), jnp.float32),
+                   jax.ShapeDtypeStruct((m, 1, LANES), jnp.float32)),
         interpret=interpret,
     )(xt, pt)
-    return jnp.sum(dx, axis=1), jnp.sum(x2, axis=1)
+    return jnp.sum(dx, axis=(1, 2)), jnp.sum(x2, axis=(1, 2))

@@ -58,6 +58,20 @@ def _untile(t, width: int, lead):
     return t.reshape(lead + (-1,))[..., :width]
 
 
+def client_row(v):
+    """(m,) per-client scalars -> (m, 1, LANES) f32 constant rows.  The unit
+    middle axis makes the (1, 1, LANES) block's last two dims equal the
+    array's, which the TPU lowering requires of a block that is not
+    (8, 128)-aligned; the kernel reads a (1, LANES) row that broadcasts over
+    its (block, LANES) data tile."""
+    m = v.shape[0]
+    return jnp.broadcast_to(v.astype(jnp.float32)[:, None, None], (m, 1, LANES))
+
+
+# client i's constant row on an (m, width-blocks) grid
+CLIENT_ROW_BS = pl.BlockSpec((1, 1, LANES), lambda i, j: (i, 0, 0))
+
+
 def _resolve_block(block, rows: int) -> int:
     block = block or BLOCK_ROWS
     # clamp to the (8-sublane-aligned) problem size so small paper-scale
@@ -139,14 +153,14 @@ def _scaffold_cv_kernel(ci_ref, xk_ref, c_ref, xs_ref, o_ref, *, alpha: float):
 
 
 def _scaffold_cv_kernel_valpha(ci_ref, xk_ref, c_ref, xs_ref, a_ref, o_ref):
-    # per-client alpha = 1/(K eta_i) loaded as a (1, LANES) row operand
-    # (core.autotune's per-client stepsizes)
+    # per-client alpha = 1/(K eta_i) loaded as a (1, LANES) constant row
+    # (core.autotune's per-client stepsizes), broadcast over the tile
     f32 = jnp.float32
     ci = ci_ref[0].astype(f32)
     xk = xk_ref[0].astype(f32)
     c = c_ref[...].astype(f32)
     xs = xs_ref[...].astype(f32)
-    o_ref[0] = (ci - c + a_ref[0, 0] * (xs - xk)).astype(o_ref.dtype)
+    o_ref[0] = (ci - c + a_ref[0] * (xs - xk)).astype(o_ref.dtype)
 
 
 def scaffold_cv_pallas(c_i, x_K, c_s, x_s, alpha, *, block=None, interpret: bool = False):
@@ -172,9 +186,8 @@ def scaffold_cv_pallas(c_i, x_K, c_s, x_s, alpha, *, block=None, interpret: bool
     in_specs = [client_bs, client_bs, server_bs, server_bs]
     if jnp.ndim(alpha) > 0:
         assert alpha.shape == (m,), alpha.shape
-        args.append(jnp.broadcast_to(
-            alpha.astype(jnp.float32)[:, None], (m, LANES)))
-        in_specs.append(pl.BlockSpec((1, LANES), lambda i, j: (i, 0)))
+        args.append(client_row(alpha))
+        in_specs.append(CLIENT_ROW_BS)
         kernel = _scaffold_cv_kernel_valpha
     else:
         kernel = functools.partial(_scaffold_cv_kernel, alpha=float(alpha))
@@ -224,9 +237,21 @@ def dual_from_uplink_pallas(uplink, x_s, rho, *, block=None, interpret: bool = F
 # (b) fused EF21: rowwise max-abs reduce + quantise-dequantise-integrate
 # ---------------------------------------------------------------------------
 
+def _ef21_block(block, rows: int) -> int:
+    """Row block for the EF21 kernels: their per-row scale operand lies
+    along lanes as a (1, 1, block) block of an (m, 1, rows_p) array, so a
+    block that does not cover all rows must be a multiple of LANES."""
+    br = _resolve_block(block, rows)
+    return br if br >= rows else _ceil_to(br, LANES)
+
+
+# one client's (1, 1, block) slice of the (m, 1, rows_p) per-row scale array
+_ROW_SCALE_BS = lambda br: pl.BlockSpec((1, 1, br), lambda i, j: (i, 0, j))  # noqa: E731
+
+
 def _rowmax_kernel(u_ref, uh_ref, o_ref):
     d = u_ref[0].astype(jnp.float32) - uh_ref[0].astype(jnp.float32)
-    o_ref[0] = jnp.max(jnp.abs(d), axis=-1)
+    o_ref[0] = jnp.max(jnp.abs(d), axis=-1)[None, :]
 
 
 def ef21_rowmax_pallas(u, u_hat, *, block=None, interpret: bool = False):
@@ -234,7 +259,7 @@ def ef21_rowmax_pallas(u, u_hat, *, block=None, interpret: bool = False):
     The only full-size read of the reduction pass."""
     m, w = u.shape
     rows = w // LANES
-    br = _resolve_block(block, rows)
+    br = _ef21_block(block, rows)
     assert_vmem_budget(2, br)
     ut, _, rows_p = _tile(u, br)
     ht, _, _ = _tile(u_hat, br)
@@ -245,17 +270,17 @@ def ef21_rowmax_pallas(u, u_hat, *, block=None, interpret: bool = False):
             pl.BlockSpec((1, br, LANES), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, br, LANES), lambda i, j: (i, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, br), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, rows_p), jnp.float32),
+        out_specs=_ROW_SCALE_BS(br),
+        out_shape=jax.ShapeDtypeStruct((m, 1, rows_p), jnp.float32),
         interpret=interpret,
     )(ut, ht)
-    return out[:, :rows]
+    return out[:, 0, :rows]
 
 
 def _qdq_kernel(u_ref, uh_ref, scale_ref, o_ref, *, lo: float):
     u = u_ref[0].astype(jnp.float32)
     uh = uh_ref[0].astype(jnp.float32)
-    s = scale_ref[0][:, None]  # (br, 1) broadcast over lanes
+    s = scale_ref[0, 0][:, None]  # (br, 1) broadcast over lanes
     q = jnp.clip(jnp.round((u - uh) / s), -lo, lo)
     o_ref[0] = (uh + q * s).astype(o_ref.dtype)
 
@@ -266,7 +291,7 @@ def ef21_apply_pallas(u, u_hat, row_scales, bits: int, *, block=None, interpret:
     clamped), expanded from the per-leaf segment maxima."""
     m, w = u.shape
     rows = w // LANES
-    br = _resolve_block(block, rows)
+    br = _ef21_block(block, rows)
     assert_vmem_budget(4, br)
     lo = float(2 ** (bits - 1) - 1)
     ut, _, rows_p = _tile(u, br)
@@ -280,12 +305,12 @@ def ef21_apply_pallas(u, u_hat, row_scales, bits: int, *, block=None, interpret:
         in_specs=[
             pl.BlockSpec((1, br, LANES), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, br, LANES), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, br), lambda i, j: (i, j)),
+            _ROW_SCALE_BS(br),
         ],
         out_specs=pl.BlockSpec((1, br, LANES), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((m, rows_p, LANES), u.dtype),
         interpret=interpret,
-    )(ut, ht, st)
+    )(ut, ht, st[:, None, :])
     return _untile(out, w, (m,))
 
 
@@ -310,18 +335,19 @@ def _update_kernel_nolam(x_ref, g_ref, xs_ref, o_ref, *, step: float, rho: float
 
 
 def _update_kernel_vstep(x_ref, g_ref, xs_ref, lam_ref, step_ref, o_ref, *, rho: float):
-    # per-client stepsize loaded as a (1, LANES) row operand (core.autotune)
+    # per-client stepsize loaded as a (1, LANES) constant row
+    # (core.autotune), broadcast over the tile
     f32 = jnp.float32
     out = eq20(x_ref[0].astype(f32), g_ref[0].astype(f32),
                xs_ref[...].astype(f32), lam_ref[0].astype(f32),
-               step_ref[0, 0], rho)
+               step_ref[0], rho)
     o_ref[0] = out.astype(o_ref.dtype)
 
 
 def _update_kernel_nolam_vstep(x_ref, g_ref, xs_ref, step_ref, o_ref, *, rho: float):
     f32 = jnp.float32
     out = eq20(x_ref[0].astype(f32), g_ref[0].astype(f32),
-               xs_ref[...].astype(f32), None, step_ref[0, 0], rho)
+               xs_ref[...].astype(f32), None, step_ref[0], rho)
     o_ref[0] = out.astype(o_ref.dtype)
 
 
@@ -346,9 +372,8 @@ def fused_update_arena_pallas(x, g, x_s, lam, step, rho, *, block=None, interpre
         in_specs.append(client_bs)
     if jnp.ndim(step) > 0:
         assert step.shape == (m,), step.shape
-        args.append(jnp.broadcast_to(
-            step.astype(jnp.float32)[:, None], (m, LANES)))
-        in_specs.append(pl.BlockSpec((1, LANES), lambda i, j: (i, 0)))
+        args.append(client_row(step))
+        in_specs.append(CLIENT_ROW_BS)
         kernel = functools.partial(
             _update_kernel_nolam_vstep if lam is None else _update_kernel_vstep,
             rho=float(rho))

@@ -14,11 +14,11 @@ is ~ ``||2 x_s||``.  Non-finite entries are excluded from the deviation
 comparable across backends.
 
 Layout: grid ``(m, rows_p // block)`` with the width blocks INNERMOST, so
-each client's two per-lane accumulator rows -- ``(1, LANES)`` f32 blocks of
-the tiny ``(m, LANES)`` outputs -- are revisited across the row's width
+each client's two per-lane accumulator rows -- ``(1, 1, LANES)`` f32 blocks
+of the tiny ``(m, 1, LANES)`` outputs -- are revisited across the row's width
 blocks and stay VMEM-resident (the same revisited-output accumulation
 contract as ``neighbor_reduce``).  The cheap cross-lane finish (sum / min
-over LANES) runs on the ``(m, LANES)`` partials outside the kernel.
+over LANES) runs on the per-lane partials outside the kernel.
 
 ``ref`` is either the ``(width,)`` server downlink row (centralised rounds)
 or an ``(m, width)`` per-row reference (graph rounds screen each node's
@@ -45,8 +45,8 @@ def _screen_kernel(u_ref, r_ref, sq_ref, fin_ref, *, per_row: bool):
     r = (r_ref[0] if per_row else r_ref[...]).astype(jnp.float32)
     fin_e = jnp.isfinite(u)
     d = jnp.where(fin_e, u - r, 0.0)
-    sq = jnp.sum(d * d, axis=0)  # (LANES,) per-lane partial
-    fin = jnp.min(jnp.where(fin_e, 1.0, 0.0), axis=0)
+    sq = jnp.sum(d * d, axis=0, keepdims=True)  # (1, LANES) per-lane partial
+    fin = jnp.min(jnp.where(fin_e, 1.0, 0.0), axis=0, keepdims=True)
 
     @pl.when(j == 0)
     def _init():
@@ -79,14 +79,14 @@ def screen_uplink_pallas(u, ref, *, block=None, interpret: bool = False):
     client_bs = pl.BlockSpec((1, br, LANES), lambda i, j: (i, j, 0))
     ref_bs = (client_bs if per_row
               else pl.BlockSpec((br, LANES), lambda i, j: (j, 0)))
-    acc_bs = pl.BlockSpec((1, LANES), lambda i, j: (i, 0))
+    acc_bs = pl.BlockSpec((1, 1, LANES), lambda i, j: (i, 0, 0))
     sq, fin = pl.pallas_call(
         functools.partial(_screen_kernel, per_row=per_row),
         grid=(m, rows_p // br),  # width blocks innermost: accumulators stay hot
         in_specs=[client_bs, ref_bs],
         out_specs=(acc_bs, acc_bs),
-        out_shape=(jax.ShapeDtypeStruct((m, LANES), jnp.float32),
-                   jax.ShapeDtypeStruct((m, LANES), jnp.float32)),
+        out_shape=(jax.ShapeDtypeStruct((m, 1, LANES), jnp.float32),
+                   jax.ShapeDtypeStruct((m, 1, LANES), jnp.float32)),
         interpret=interpret,
     )(ut, rt)
-    return jnp.min(fin, axis=1) > 0.5, jnp.sum(sq, axis=1)
+    return jnp.min(fin, axis=(1, 2)) > 0.5, jnp.sum(sq, axis=(1, 2))

@@ -13,9 +13,10 @@ row,
 All the per-client admission bookkeeping (occupancy, age, lateness,
 deadline) is layout-independent integer math done OUTSIDE the kernel
 (``core.staleness``); the kernel only consumes three per-client scalars --
-``fresh``, ``store``, ``w`` -- broadcast to ``(m, LANES)`` f32 rows so each
-grid step reads them as ``(1, LANES)`` VMEM blocks and broadcasts them
-against the ``(block, LANES)`` data tiles (no SMEM scalar plumbing).
+``fresh``, ``store``, ``w`` -- broadcast to ``(m, 1, LANES)`` f32 rows
+(``round_tail.client_row``) so each grid step reads them as ``(1, LANES)``
+VMEM rows and broadcasts them against the ``(block, LANES)`` data tiles (no
+SMEM scalar plumbing).
 
 The admitted-mix guard ``where(w > 0, base + w*(stale - base), base)`` is
 load-bearing for the synchronous collapse: at ``w == 0`` the select returns
@@ -39,7 +40,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.fused_update import LANES, assert_vmem_budget
-from repro.kernels.round_tail import _resolve_block, _tile
+from repro.kernels.round_tail import (
+    CLIENT_ROW_BS, _resolve_block, _tile, client_row,
+)
 
 
 def _stale_mix_kernel(u_ref, c_ref, b_ref, f_ref, s_ref, w_ref,
@@ -47,7 +50,7 @@ def _stale_mix_kernel(u_ref, c_ref, b_ref, f_ref, s_ref, w_ref,
     u = u_ref[0].astype(jnp.float32)  # (br, LANES)
     c = (c_ref[0] if per_row else c_ref[...]).astype(jnp.float32)
     buf = b_ref[0].astype(jnp.float32)
-    fresh = f_ref[0]  # (LANES,) constant row, broadcasts over br
+    fresh = f_ref[0]  # (1, LANES) constant row, broadcasts over br
     w = w_ref[0]
     base = jnp.where(fresh > 0.5, u, c)
     mix = jnp.where(w > 0.0, base + w * (buf - base), base)
@@ -75,22 +78,19 @@ def stale_mix_pallas(uplink, cache, buf, fresh, store, w, *, block=None,
     ut, _, rows_p = _tile(uplink, br)
     ct, _, _ = _tile(cache, br)
     bt, _, _ = _tile(buf, br)
-    const = lambda v: jnp.broadcast_to(  # noqa: E731
-        v.astype(jnp.float32)[:, None], (m, LANES))
     client_bs = pl.BlockSpec((1, br, LANES), lambda i, j: (i, j, 0))
     cache_bs = (client_bs if per_row
                 else pl.BlockSpec((br, LANES), lambda i, j: (j, 0)))
-    scalar_bs = pl.BlockSpec((1, LANES), lambda i, j: (i, 0))
     mixed, buf_new = pl.pallas_call(
         functools.partial(_stale_mix_kernel, per_row=per_row),
         grid=(m, rows_p // br),
         in_specs=[client_bs, cache_bs, client_bs,
-                  scalar_bs, scalar_bs, scalar_bs],
+                  CLIENT_ROW_BS, CLIENT_ROW_BS, CLIENT_ROW_BS],
         out_specs=(client_bs, client_bs),
         out_shape=(jax.ShapeDtypeStruct((m, rows_p, LANES), uplink.dtype),
                    jax.ShapeDtypeStruct((m, rows_p, LANES), buf.dtype)),
         interpret=interpret,
-    )(ut, ct, bt, const(fresh), const(store), const(w))
+    )(ut, ct, bt, client_row(fresh), client_row(store), client_row(w))
     w_out = width - pad
     untile = lambda t: t.reshape(m, rows_p * LANES)[:, :w_out]  # noqa: E731
     return untile(mixed), untile(buf_new)

@@ -24,7 +24,7 @@ import jax
 
 from repro.configs import ARCHS, SHAPES, get_arch, get_shape
 from repro.launch import hlo_stats
-from repro.launch.mesh import make_production_mesh, mesh_context
+from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import build_step
 
 REPORT_DIR = pathlib.Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
@@ -46,7 +46,7 @@ def run_one(arch_name: str, shape_name: str, *, multi_pod: bool, verbose: bool =
     # the lower/compile durations the reports record
     t0 = time.perf_counter()
     bundle = build_step(cfg, shape, mesh)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(
             bundle.fn,
             in_shardings=bundle.in_shardings,

@@ -75,8 +75,6 @@ def collective_bytes(hlo_text: str) -> dict:
 
 def flops_and_bytes(compiled) -> tuple[float, float]:
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # jax 0.4.x: one dict per device
-        ca = ca[0] if ca else {}
     return float(ca.get("flops", 0.0)), float(ca.get("bytes accessed", 0.0))
 
 

@@ -3,36 +3,28 @@
 The target is TPU v5e: one pod = 16 x 16 = 256 chips with axes
 ("data", "model"); the multi-pod configuration stacks 2 pods = 512 chips with
 axes ("pod", "data", "model").  Everything is a function -- importing this
-module never touches jax device state.
+module never touches jax device state.  Activate a mesh with
+``jax.set_mesh(mesh)``: the model's sharding constraints read it from there.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:  # AxisType landed after jax 0.4.x; older jax only has Auto semantics
-    from jax.sharding import AxisType
 
-    _AXIS_KW = lambda n: {"axis_types": (AxisType.Auto,) * n}  # noqa: E731
-except ImportError:
-    _AXIS_KW = lambda n: {}  # noqa: E731
+def _make(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_AXIS_KW(len(axes)))
+    return _make(shape, axes)
 
 
 def make_smoke_mesh(n_data: int = 1, n_model: int = 1):
-    """Tiny mesh over however many (CPU) devices a test process has."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"), **_AXIS_KW(2))
-
-
-def mesh_context(mesh):
-    """``jax.set_mesh(mesh)`` on jax >= 0.6; on 0.4.x the Mesh itself is the
-    (legacy thread-local) context manager the sharding constraints read."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
+    """Tiny ("data", "model") mesh over the process's first devices."""
+    return _make((n_data, n_model), ("data", "model"))
 
 
 # TPU v5e hardware constants used by the roofline analysis (per chip)

@@ -21,7 +21,7 @@ import jax
 
 from repro.configs import get_arch, get_shape
 from repro.launch import hlo_stats
-from repro.launch.mesh import make_production_mesh, mesh_context
+from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import build_step
 
 REPORT_DIR = pathlib.Path(__file__).resolve().parents[3] / "experiments" / "perf"
@@ -48,7 +48,7 @@ def measure(cfg, shape, *, multi_pod=False) -> dict:
     # monotonic perf_counter, not time.time: compile-time deltas between
     # baseline and variant are part of the A/B report
     t0 = time.perf_counter()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(
             bundle.fn,
             in_shardings=bundle.in_shardings,

@@ -28,7 +28,7 @@ import jax
 
 from repro.configs import ARCHS, SHAPES, get_arch, get_shape
 from repro.launch import hlo_stats
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16, make_production_mesh, mesh_context
+from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16, make_production_mesh
 from repro.launch.steps import build_step
 
 REPORT_DIR = pathlib.Path(__file__).resolve().parents[3] / "experiments" / "roofline"
@@ -58,7 +58,7 @@ def _probe_cfg(cfg, n_units: int):
 
 def _measure(cfg, shape, mesh):
     bundle = build_step(cfg, shape, mesh)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(
             bundle.fn, in_shardings=bundle.in_shardings, out_shardings=bundle.out_shardings
         ).lower(*bundle.args)
@@ -172,14 +172,17 @@ def main():
     shapes = [args.shape] if args.shape else list(SHAPES)
     outdir = pathlib.Path(args.out)
     outdir.mkdir(parents=True, exist_ok=True)
+    n_fail = 0
     for a in archs:
         for s in shapes:
             try:
                 rep = analyze(a, s)
-            except Exception as e:
+            except Exception as e:  # reported per cell, and in the exit code
+                n_fail += 1
                 rep = {"arch": a, "shape": s, "status": "failed", "error": str(e)}
                 print(f"[roofline] {a:28s} {s:12s} FAIL {e}")
             (outdir / f"{a}_{s}.json").write_text(json.dumps(rep, indent=2))
+    raise SystemExit(1 if n_fail else 0)
 
 
 if __name__ == "__main__":

@@ -36,6 +36,7 @@ import jax.numpy as jnp
 from repro import checkpoint as ckpt
 from repro import telemetry as tel
 from repro.configs import get_arch
+from repro.launch import compile_cache
 from repro.models import build as build_model
 
 
@@ -363,6 +364,7 @@ def main():
     ap.add_argument("--prom-out", default=None,
                     help="write final counters as a Prometheus textfile")
     args = ap.parse_args()
+    compile_cache.enable()
     tel_kw = dict(telemetry=args.telemetry, trace_out=args.trace_out,
                   metrics_out=args.metrics_out, prom_out=args.prom_out)
     if args.watch:

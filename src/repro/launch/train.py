@@ -3,9 +3,10 @@
     PYTHONPATH=src python -m repro.launch.train \
         --arch olmo-1b --reduced --steps 50 --algorithm gpdmm --k 4
 
-On CPU this drives the reduced configs (the ~100M-scale end-to-end example
-lives in examples/train_federated_lm.py); on a real TPU mesh the same code
-path drives the full configs via --mesh production.
+``--reduced`` (the default) shrinks widths for CPU runs; ``--full`` keeps
+the published widths, and ``--layers N`` then cuts only the depth -- the
+cut that fits a model's published widths on one chip (``chip_smoke.py``
+runs OLMo-1B this way on a TPU v5e).
 
 Checkpointing: ``--ckpt-dir`` saves the FULL federated state (every arena
 buffer, the server pytree, and the round counter) at the end of the run;
@@ -49,7 +50,6 @@ import math
 import os
 import pathlib
 import time
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -57,20 +57,27 @@ import jax.numpy as jnp
 from repro import checkpoint as ckpt
 from repro import telemetry as tel
 from repro.configs import get_arch
-from repro.configs.base import FaultConfig, FederatedConfig, ShapeConfig
+from repro.configs.base import FaultConfig, FederatedConfig
 from repro.core import make as make_fed
 from repro.core import make_scan_rounds, popstore
 from repro.core.api import FedOpt, use_arena, use_cohort, use_popstore
 from repro.data.synthetic import cohort_lm_batches, lm_batches
-from repro.launch.mesh import make_smoke_mesh
-from repro.launch.steps import build_train_step
+from repro.launch import compile_cache
 from repro.models import build as build_model
+
+
+class History(list):
+    """The logged round rows ``run`` returns; ``state`` is the run's final
+    federated state (still on the device), for callers that check it."""
+
+    state = None
 
 
 def run(
     arch: str,
     *,
     reduced: bool = True,
+    layers: int | None = None,
     steps: int = 20,
     algorithm: str = "gpdmm",
     k: int = 2,
@@ -112,6 +119,8 @@ def run(
     cfg = get_arch(arch)
     if reduced:
         cfg = cfg.reduced()
+    if layers is not None:
+        cfg = dataclasses.replace(cfg, n_layers=layers)  # depth only
     fault_cfg = FaultConfig.parse(faults) if isinstance(faults, str) else faults
     if watchdog and not ckpt_dir:
         raise ValueError("--watchdog needs --ckpt-dir (rollback anchors)")
@@ -189,6 +198,9 @@ def run(
         "seq_len": seq_len, "seed": seed, "uplink_bits": uplink_bits,
         "participation": participation,
     }
+    if layers is not None:
+        # joins the fingerprint only when set, so older checkpoints resume
+        run_config["layers"] = layers
     if fault_cfg is not None:
         # the seeded fault trace is part of the trajectory, so it joins the
         # fingerprint -- but only when a schedule is active, so checkpoints
@@ -352,7 +364,7 @@ def run(
         losses = jax.vmap(lambda b: model.loss(params, b)[0])(batch)
         return losses.mean()
 
-    history = []
+    history = History()
     n_rounds = steps - start
 
     def make_data(from_round: int):
@@ -679,6 +691,7 @@ def run(
         raise RuntimeError(
             f"expected >= {expect_rollbacks} watchdog rollbacks, "
             f"saw {rollbacks}")
+    history.state = state
     return history
 
 
@@ -692,6 +705,8 @@ def main():
     ap.add_argument("--arch", default="olmo-1b")
     ap.add_argument("--reduced", action="store_true", default=True)
     ap.add_argument("--full", dest="reduced", action="store_false")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the model to N layers, keeping its widths")
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--algorithm", default="gpdmm",
                     choices=["gpdmm", "agpdmm", "scaffold", "fedavg", "fedsplit"])
@@ -783,8 +798,10 @@ def main():
                     help="jax.profiler output dir (default: next to "
                          "--trace-out, else ./telemetry/jaxprof)")
     args = ap.parse_args()
+    compile_cache.enable()
     run(
-        args.arch, reduced=args.reduced, steps=args.steps, algorithm=args.algorithm,
+        args.arch, reduced=args.reduced, layers=args.layers, steps=args.steps,
+        algorithm=args.algorithm,
         k=args.k, eta=args.eta, tol=args.tol, patience=args.patience,
         m=args.clients, per_client_batch=args.batch,
         seq_len=args.seq, seed=args.seed, ckpt_dir=args.ckpt_dir, resume=args.resume,

@@ -19,7 +19,6 @@ from __future__ import annotations
 
 import os
 import pathlib
-import warnings
 from typing import Optional
 
 
@@ -70,13 +69,9 @@ class RoundProfiler:
         import jax
 
         pathlib.Path(self.out_dir).mkdir(parents=True, exist_ok=True)
-        try:
-            jax.profiler.start_trace(self.out_dir)
-        except Exception as e:  # profiler backend unavailable: degrade loudly
-            warnings.warn(f"[telemetry] jax.profiler capture unavailable: {e}",
-                          RuntimeWarning, stacklevel=2)
-            self.captured = True
-            return
+        # a failing profiler raises: a run that asked for a trace and got
+        # none must not exit 0
+        jax.profiler.start_trace(self.out_dir)
         self.active = True
         print(f"[telemetry] jax.profiler capture started at round "
               f"{round_idx} -> {self.out_dir}", flush=True)
@@ -88,16 +83,11 @@ class RoundProfiler:
     def _stop(self) -> None:
         import jax
 
-        try:
-            jax.profiler.stop_trace()
-        except Exception as e:
-            warnings.warn(f"[telemetry] jax.profiler stop failed: {e}",
-                          RuntimeWarning, stacklevel=2)
-        else:
-            print(f"[telemetry] jax.profiler capture written to "
-                  f"{self.out_dir}", flush=True)
         self.active = False
         self.captured = True
+        jax.profiler.stop_trace()
+        print(f"[telemetry] jax.profiler capture written to "
+              f"{self.out_dir}", flush=True)
 
     def close(self) -> None:
         if self.active:

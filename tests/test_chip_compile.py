@@ -1,0 +1,180 @@
+"""Compile-only checks of the Pallas kernels for a TPU v5e, without a chip.
+
+The TPU compiler is installed even where no chip is attached: it compiles
+for a described ``v5e:2x2`` topology and refuses what the chip would
+refuse -- blocks not aligned to the (8, 128) tiling, kernels past the VMEM
+budget -- which interpret-mode tests cannot see.  Each case passes
+``impl="pallas"`` and asserts the compiled program holds the Pallas custom
+call, at real widths: the packed arena width of ``chip_smoke.py``'s model
+(OLMo-1B at its published widths, 5 layers) for the per-client kernels,
+OLMo-1B's head dims for attention, and 2^20-wide rows on a ring of 8 nodes
+for the graph kernels.
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and the test workers all
+import this file.
+"""
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_arch
+from repro.core import arena, topology
+from repro.kernels import ops
+from repro.models import build as build_model
+
+M = 2  # clients in chip_smoke.py's one-chip run
+SMOKE_LAYERS = 5  # chip_smoke.py's depth cut
+INNER_W = 1024  # the fused K-step kernel keeps (W, W) in VMEM
+GRAPH_W = 2 ** 20  # edge-dual rows of a ring of 8 nodes: 16 rows must fit HBM
+P = dict(impl="pallas")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def sds(topo):
+    """``sds(shape, dtype)``: a shape on one described v5e chip."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+
+
+@pytest.fixture(scope="module")
+def width():
+    cfg = dataclasses.replace(get_arch("olmo-1b"), n_layers=SMOKE_LAYERS)
+    shapes = jax.eval_shape(build_model(cfg).init, jax.random.key(0))
+    return arena.ArenaSpec.from_tree(shapes).width
+
+
+def _rows(S, W):
+    return S((M, W)), S((W,))
+
+
+def _fused_update(S, W, per_client):
+    cl, row = _rows(S, W)
+    if per_client:
+        return (lambda x, g, s, lam, e: ops.fused_update_arena(
+            x, g, s, lam, e, 1.5, **P), (cl, cl, row, cl, S((M,), jnp.float32)))
+    return (lambda x, g, s, lam: ops.fused_update_arena(
+        x, g, s, lam, 0.1, 1.5, **P), (cl, cl, row, cl))
+
+
+def _round_tail(S, W, with_lam_is):
+    cl, row = _rows(S, W)
+    return (lambda x, lam, s: ops.round_tail(
+        x, lam, s, 1.5, with_lam_is=with_lam_is, **P), (cl, cl, row))
+
+
+def _scaffold_cv(S, W, per_client):
+    cl, row = _rows(S, W)
+    if per_client:
+        return (lambda c, x, cs, s, a: ops.scaffold_cv(c, x, cs, s, a, **P),
+                (cl, cl, row, row, S((M,), jnp.float32)))
+    return (lambda c, x, cs, s: ops.scaffold_cv(c, x, cs, s, 2.0, **P),
+            (cl, cl, row, row))
+
+
+def _inner_loop(S, per_client):
+    f32 = jnp.float32
+    cl, row = S((M, INNER_W), f32), S((INNER_W,), f32)
+    H = S((M, INNER_W, INNER_W), f32)
+    if per_client:  # per-client stepsize and SCAFFOLD's offset row
+        return (lambda x, h, c, s, lam, e, off: ops.inner_loop_affine(
+            x, h, c, s, lam, e, 1.5, 3, off=off, **P),
+            (cl, H, cl, row, cl, S((M,), f32), cl))
+    return (lambda x, h, c, s, lam: ops.inner_loop_affine(
+        x, h, c, s, lam, 0.1, 1.5, 3, **P), (cl, H, cl, row, cl))
+
+
+def _graph(S, which):
+    t = topology.ring(8)
+    z, x = S((t.n_slots, GRAPH_W)), S((t.n, GRAPH_W))
+    if which == "neighbor_reduce":
+        return (lambda z: ops.neighbor_reduce(
+            z, seg=t.src, first=t.first_flags(), sgn=t.sgn, n=t.n, **P), (z,))
+    return (lambda z, x: ops.edge_flip(
+        z, x, 1.7, rev=t.rev, nbr=t.nbr, sgn=t.sgn, **P), (z, x))
+
+
+def _flash_attention(S):
+    cfg = get_arch("olmo-1b")
+    hd = cfg.d_model // cfg.n_heads
+    q = S((1, 512, cfg.n_heads, hd))
+    kv = S((1, 512, cfg.n_kv_heads, hd))
+    pos = S((512,), jnp.int32)
+    return (lambda q, k, v, p: ops.flash_attention(q, k, v, p, p, **P),
+            (q, kv, kv, pos))
+
+
+CASES = {
+    "fused_update_arena-scalar": lambda S, W: _fused_update(S, W, False),
+    "fused_update_arena-per_client": lambda S, W: _fused_update(S, W, True),
+    "round_tail": lambda S, W: _round_tail(S, W, False),
+    "round_tail-lam_is": lambda S, W: _round_tail(S, W, True),
+    "dual_from_uplink": lambda S, W: (
+        lambda u, s: ops.dual_from_uplink(u, s, 1.5, **P), _rows(S, W)),
+    "scaffold_cv-scalar": lambda S, W: _scaffold_cv(S, W, False),
+    "scaffold_cv-per_client": lambda S, W: _scaffold_cv(S, W, True),
+    "ef21_rowmax_apply": lambda S, W: (
+        lambda u, h: ops.ef21_update(u, h, 8, (W // 128,), **P),
+        (S((M, W)), S((M, W)))),
+    "screen_uplink": lambda S, W: (
+        lambda u, s: ops.screen_uplink(u, s, **P), _rows(S, W)),
+    "screen_uplink-per_row": lambda S, W: (
+        lambda u, r: ops.screen_uplink(u, r, **P), (S((M, W)), S((M, W)))),
+    "residual_norm": lambda S, W: (
+        lambda x, p: ops.residual_norm(x, p, **P), (S((M, W)), S((M, W)))),
+    "stale_mix": lambda S, W: (
+        lambda u, c, b, f, st, w: ops.stale_mix(u, c, b, f, st, w, **P),
+        (S((M, W)), S((W,)), S((M, W)), S((M,), jnp.bool_),
+         S((M,), jnp.bool_), S((M,), jnp.float32))),
+    "row_gather": lambda S, W: (
+        lambda a, i: ops.row_gather(a, i, **P),
+        (S((2 * M, W)), S((M,), jnp.int32))),
+    "row_scatter": lambda S, W: (
+        lambda d, i, r: ops.row_scatter(d, i, r, **P),
+        (S((2 * M, W)), S((M,), jnp.int32), S((M, W)))),
+    "inner_loop_affine-scalar": lambda S, W: _inner_loop(S, False),
+    "inner_loop_affine-per_client": lambda S, W: _inner_loop(S, True),
+    "neighbor_reduce": lambda S, W: _graph(S, "neighbor_reduce"),
+    "edge_flip": lambda S, W: _graph(S, "edge_flip"),
+    "flash_attention-forward": lambda S, W: _flash_attention(S),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(sds, width, case):
+    fn, args = CASES[case](sds, width)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), (
+        f"{case}: no Pallas kernel in the compiled program")
+
+
+def test_platform_selects_the_implementation(monkeypatch):
+    """``impl=None`` resolves from the platform: Pallas on a TPU, XLA
+    elsewhere -- except attention and wkv6, which have no Pallas backward
+    and stay on XLA inside the training step."""
+    x = jnp.zeros((M, 256))
+    s = jnp.zeros((256,))
+    for backend, want in (("tpu", "pallas"), ("cpu", "xla")):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        assert ops.default_impl() == want
+        jax.eval_shape(lambda u, r: ops.dual_from_uplink(u, r, 1.0), x, s)
+        assert ops.RESOLVED["dual_from_uplink"] == want
+    q = jnp.zeros((1, 128, 2, 64))
+    pos = jnp.arange(128)
+    jax.eval_shape(lambda q: ops.flash_attention(q, q, q, pos, pos), q)
+    assert ops.RESOLVED["flash_attention"] == "xla"
