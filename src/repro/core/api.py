@@ -114,9 +114,15 @@ def arena_grad(grad_fn, spec):
     if factory is not None:
         return factory(spec), True
 
+    def one(row, bi):
+        with jax.named_scope("arena_pack"):
+            params = spec.unpack(row)
+        g = grad_fn(params, bi)
+        with jax.named_scope("arena_pack"):
+            return spec.pack(g)
+
     def ga(xa, b):
-        return map_clients(
-            lambda row, bi: spec.pack(grad_fn(spec.unpack(row), bi)), xa, b)
+        return map_clients(one, xa, b)
 
     return ga, False
 

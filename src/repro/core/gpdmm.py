@@ -131,35 +131,39 @@ def inner_steps_arena(spec, grad_fn, x0, x_s_row, lam, batch, *, K, eta, rho,
     """
     eta = _eta_val(eta)
     step_c = 1.0 / (1.0 / eta + rho)
+    with jax.named_scope("round.inner_loop"):
+        affine = affine_case(grad_fn, spec, per_step=per_step,
+                             vr_snapshot=vr_snapshot)
+        if affine is not None:
+            H, c = affine(spec, batch)
+            return ops.inner_loop_affine(x0, H, c, x_s_row, lam, step_c, rho, K)
 
-    affine = affine_case(grad_fn, spec, per_step=per_step, vr_snapshot=vr_snapshot)
-    if affine is not None:
-        H, c = affine(spec, batch)
-        return ops.inner_loop_affine(x0, H, c, x_s_row, lam, step_c, rho, K)
+        grad_a, _native = arena_grad(grad_fn, spec)
 
-    grad_a, _native = arena_grad(grad_fn, spec)
+        gbar = None
+        if vr_snapshot is not None:
+            assert per_step, "SVRG needs per-step minibatches (K, m, ...)"
+            with jax.named_scope("round.client_grad"):
+                snap_grads = jax.lax.map(lambda b: grad_a(vr_snapshot, b), batch)
+                gbar = jnp.mean(snap_grads, axis=0)
 
-    gbar = None
-    if vr_snapshot is not None:
-        assert per_step, "SVRG needs per-step minibatches (K, m, ...)"
-        snap_grads = jax.lax.map(lambda b: grad_a(vr_snapshot, b), batch)
-        gbar = jnp.mean(snap_grads, axis=0)
+        def one_step(carry, xs_k):
+            x, xsum = carry
+            b = xs_k if per_step else batch
+            with jax.named_scope("round.client_grad"):
+                g = grad_a(x, b)
+                if gbar is not None:
+                    g = g - grad_a(vr_snapshot, b) + gbar
+            with jax.named_scope("round.client_update"):
+                x_new = ops.fused_update_arena(x, g, x_s_row, lam, step_c, rho)
+            return (x_new, xsum + x_new), None
 
-    def one_step(carry, xs_k):
-        x, xsum = carry
-        b = xs_k if per_step else batch
-        g = grad_a(x, b)
-        if gbar is not None:
-            g = g - grad_a(vr_snapshot, b) + gbar
-        x_new = ops.fused_update_arena(x, g, x_s_row, lam, step_c, rho)
-        return (x_new, xsum + x_new), None
-
-    init = (x0, jnp.zeros_like(x0))
-    if per_step:
-        (x_K, xsum), _ = jax.lax.scan(one_step, init, batch)
-    else:
-        (x_K, xsum), _ = jax.lax.scan(one_step, init, None, length=K)
-    return x_K, xsum * (1.0 / K)
+        init = (x0, jnp.zeros_like(x0))
+        if per_step:
+            (x_K, xsum), _ = jax.lax.scan(one_step, init, batch)
+        else:
+            (x_K, xsum), _ = jax.lax.scan(one_step, init, None, length=K)
+        return x_K, xsum * (1.0 / K)
 
 
 def participation_key(cfg: FederatedConfig, round_idx):
@@ -210,9 +214,11 @@ def arena_tail(cfg: FederatedConfig, spec, state, uplink, m):
         uplink = jnp.where(mask[:, None], uplink, u_hat)
     if u_hat is not None:
         new_state["u_hat"] = uplink
-    x_s_new = jnp.mean(uplink, axis=0)  # <- the round's single all-reduce
+    with jax.named_scope("round.server_mean"):
+        x_s_new = jnp.mean(uplink, axis=0)  # <- the round's single all-reduce
     # fused tail pass 2: lam' = rho (u - x_s'), server row broadcast in-kernel
-    lam_s_new = ops.dual_from_uplink(uplink, x_s_new, rho)
+    with jax.named_scope("round.dual_refresh"):
+        lam_s_new = ops.dual_from_uplink(uplink, x_s_new, rho)
     fm = {}
     if fplan is not None or keep is not None:
         tx = faults.combine_mask(pmask, fplan, None)
@@ -233,13 +239,14 @@ def arena_metrics(lam_s_new, x_K, x_s_row, mask=None):
     entered the state.  ``used_arena`` records the (static) layout decision
     so benches can see which path a round actually ran."""
     f32 = jnp.float32
-    return {
-        "lam_sum_norm": jnp.linalg.norm(jnp.sum(lam_s_new.astype(f32), axis=0)),
-        "client_drift": T.masked_client_mean(
-            jnp.sum(jnp.square((x_K - x_s_row[None]).astype(f32)), axis=1), mask
-        ),
-        "used_arena": jnp.ones((), f32),
-    }
+    with jax.named_scope("round.metrics"):
+        return {
+            "lam_sum_norm": jnp.linalg.norm(jnp.sum(lam_s_new.astype(f32), axis=0)),
+            "client_drift": T.masked_client_mean(
+                jnp.sum(jnp.square((x_K - x_s_row[None]).astype(f32)), axis=1), mask
+            ),
+            "used_arena": jnp.ones((), f32),
+        }
 
 
 def cohort_tail(cfg: FederatedConfig, spec, state, uplink, idx, fplan=None):
@@ -267,8 +274,10 @@ def cohort_tail(cfg: FederatedConfig, spec, state, uplink, idx, fplan=None):
     if keep_c is not None:
         uplink = jnp.where(keep_c[:, None], uplink, ops.row_gather(u_hat, idx))
     u_hat_new = ops.row_scatter(u_hat, idx, uplink)
-    x_s_new = jnp.mean(u_hat_new, axis=0)  # <- the round's single all-reduce
-    lam_s_new = ops.dual_from_uplink(u_hat_new, x_s_new, rho)
+    with jax.named_scope("round.server_mean"):
+        x_s_new = jnp.mean(u_hat_new, axis=0)  # <- the round's single all-reduce
+    with jax.named_scope("round.dual_refresh"):
+        lam_s_new = ops.dual_from_uplink(u_hat_new, x_s_new, rho)
     fm = {}
     if fplan is not None or keep is not None:
         fm = faults.fault_metrics(
@@ -417,7 +426,8 @@ def _round_arena_cohort(cfg: FederatedConfig, state, grad_fn, batch, per_step_ba
                                   per_step=per_step_batches)
     x_ref = x_bar if cfg.use_avg else x_K
 
-    _, uplink = ops.round_tail(x_ref, lam_c, x_s_row, rho, with_lam_is=False)
+    with jax.named_scope("round.uplink"):
+        _, uplink = ops.round_tail(x_ref, lam_c, x_s_row, rho, with_lam_is=False)
     fplan = faults.plan(cfg, state["round"], m)
     new_state, keep_c, fm = cohort_tail(cfg, spec, state, uplink, idx, fplan)
     # demoted cohort rows are silent, full stop: the carry keeps its
@@ -461,7 +471,9 @@ def _round_arena(cfg: FederatedConfig, state, grad_fn, batch, per_step_batches, 
 
     # fused tail pass 1: the uplink (and lam_is only when a trace wants it --
     # 3 reads + 1 write on the training path, +1 write with the trace)
-    lam_is, uplink = ops.round_tail(x_ref, lam, x_s_row, rho, with_lam_is=return_trace)
+    with jax.named_scope("round.uplink"):
+        lam_is, uplink = ops.round_tail(x_ref, lam, x_s_row, rho,
+                                        with_lam_is=return_trace)
     new_state, x_s_new, lam_s_new, mask, fm = arena_tail(cfg, spec, state, uplink, m)
 
     # silent clients did not really run their inner steps: keep their carry

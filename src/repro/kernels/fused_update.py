@@ -23,6 +23,9 @@ from jax.experimental import pallas as pl
 # import these from here -- exactly one knob each.
 LANES = 128
 BLOCK_ROWS = 256  # 256 x 128 x 4B x 5 arrays ~ 0.7 MB of VMEM per step
+# name scope of every reshape, pad and slice between the caller's layout
+# and the (rows, LANES) blocks of a Pallas call
+RELAYOUT = "relayout"
 
 
 def ceil_to(x: int, m: int) -> int:
@@ -82,17 +85,20 @@ def fused_update_pallas(x, g, xs, lam, step, rho, *, block: int = BLOCK_ROWS, in
             a = jnp.pad(a, (0, n_pad))
         return a.reshape(-1, LANES)
 
-    flats = [flat(a) for a in args]
+    with jax.named_scope(RELAYOUT):
+        flats = [flat(a) for a in args]
     rows = flats[0].shape[0]
     grid = (rows // block,)
     bs = pl.BlockSpec((block, LANES), lambda i: (i, 0))
     kernel = _kernel_nolam if lam is None else _kernel
     out = pl.pallas_call(
         functools.partial(kernel, step=float(step), rho=float(rho)),
+        name="fused_update",
         grid=grid,
         in_specs=[bs] * len(flats),
         out_specs=bs,
         out_shape=jax.ShapeDtypeStruct((rows, LANES), dtype),
         interpret=interpret,
     )(*flats)
-    return out.reshape(-1)[:n].reshape(shape)
+    with jax.named_scope(RELAYOUT):
+        return out.reshape(-1)[:n].reshape(shape)

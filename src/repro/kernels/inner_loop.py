@@ -49,7 +49,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.fused_update import LANES, VMEM_CAP_BYTES, eq20
+from repro.kernels.fused_update import LANES, RELAYOUT, VMEM_CAP_BYTES, eq20
 from repro.kernels.round_tail import client_row
 
 
@@ -121,8 +121,14 @@ def inner_loop_affine_pallas(x0, H, c, x_s, lam, step, rho, K: int, *,
         f"{VMEM_CAP_BYTES} B VMEM budget -- use the step-at-a-time path")
     row_bs = pl.BlockSpec((1, 1, w), lambda i: (i, 0, 0))
     out_sds = jax.ShapeDtypeStruct((m, 1, w), x0.dtype)
-    rows3 = lambda a: a.reshape(m, 1, w)  # noqa: E731
-    args = [rows3(x0), H, rows3(c), x_s.reshape(1, w)]
+
+    def rows3(a):
+        with jax.named_scope(RELAYOUT):
+            return a.reshape(m, 1, w)
+
+    with jax.named_scope(RELAYOUT):
+        xs_row = x_s.reshape(1, w)
+    args = [rows3(x0), H, rows3(c), xs_row]
     in_specs = [
         row_bs,
         pl.BlockSpec((1, w, w), lambda i: (i, 0, 0)),
@@ -146,10 +152,12 @@ def inner_loop_affine_pallas(x0, H, c, x_s, lam, step, rho, K: int, *,
                           rho=float(rho),
                           has_lam=lam is not None, has_off=off is not None,
                           has_step=has_step),
+        name="inner_loop_affine",
         grid=(m,),
         in_specs=in_specs,
         out_specs=(row_bs, row_bs),
         out_shape=(out_sds, out_sds),
         interpret=interpret,
     )(*args)
-    return x_K.reshape(m, w), x_bar.reshape(m, w)
+    with jax.named_scope(RELAYOUT):
+        return x_K.reshape(m, w), x_bar.reshape(m, w)

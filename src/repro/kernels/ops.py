@@ -46,6 +46,19 @@ def _step_arr(step):
 RESOLVED: dict[str, str] = {}
 
 
+def _scoped(fn):
+    """Run an arena op under a name scope of its own name, its ``RESOLVED``
+    key: the kernel and its operand plumbing then carry the op's name in
+    the compiled HLO's ``op_name`` and in a profiler trace's ``tf_op``."""
+
+    @functools.wraps(fn)
+    def op(*args, **kwargs):
+        with jax.named_scope(fn.__name__):
+            return fn(*args, **kwargs)
+
+    return op
+
+
 def default_impl() -> str:
     """The platform's implementation: Pallas kernels on a TPU, XLA elsewhere."""
     return "pallas" if jax.default_backend() == "tpu" else "xla"
@@ -305,6 +318,7 @@ def fused_update(x, g, xs, lam, step, rho, *, impl: Optional[str] = None,
 # (m, width) client buffers, (width,) server rows, width % 128 == 0)
 # ---------------------------------------------------------------------------
 
+@_scoped
 def fused_update_arena(x, g, x_s, lam, step, rho, *, impl: Optional[str] = None,
                        block: Optional[int] = None):
     """Eq. (20) inner step over the whole packed arena: x, g (m, width);
@@ -331,6 +345,7 @@ def fused_update_arena(x, g, x_s, lam, step, rho, *, impl: Optional[str] = None,
         (x, g, lam, step_a), (x_s,))
 
 
+@_scoped
 def inner_loop_affine(x0, H, c, x_s, lam, step, rho, K: int, *,
                       off=None, impl: Optional[str] = None):
     """The WHOLE K-step eq. (20) inner loop for affine gradient oracles
@@ -381,6 +396,7 @@ def inner_loop_affine(x0, H, c, x_s, lam, step, rho, K: int, *,
         (x0, H, c, lam, off, step_a), (x_s,))
 
 
+@_scoped
 def scaffold_cv(c_i, x_K, c_s, x_s, alpha, *, impl: Optional[str] = None,
                 block: Optional[int] = None):
     """SCAFFOLD eq. (30) control-variate refresh, fused into one pass:
@@ -418,6 +434,7 @@ def affine_inner_fits(width: int) -> bool:
     return il.fits_vmem(width)
 
 
+@_scoped
 def round_tail(x_ref, lam_s, x_s, rho, *, with_lam_is: bool = True,
                impl: Optional[str] = None, block: Optional[int] = None):
     """Fused dual flip + uplink (eqs. 23/24 + Alg. 1 line 8):
@@ -446,6 +463,7 @@ def round_tail(x_ref, lam_s, x_s, rho, *, with_lam_is: bool = True,
         (x_ref, lam_s), (x_s,))
 
 
+@_scoped
 def dual_from_uplink(uplink, x_s, rho, *, impl: Optional[str] = None,
                      block: Optional[int] = None):
     """lam_s' = rho (u - x_s') -- the post-all-reduce dual refresh; one pass."""
@@ -462,6 +480,7 @@ def dual_from_uplink(uplink, x_s, rho, *, impl: Optional[str] = None,
         (uplink,), (x_s,))
 
 
+@_scoped
 def screen_uplink(u, ref, *, impl: Optional[str] = None,
                   block: Optional[int] = None):
     """Fused uplink screening (robustness layer): per-client finite flags
@@ -496,6 +515,7 @@ def screen_uplink(u, ref, *, impl: Optional[str] = None,
         (u, ref if per_row else None), () if per_row else (ref,))
 
 
+@_scoped
 def residual_norm(x, x_prev, *, impl: Optional[str] = None,
                   block: Optional[int] = None):
     """Fused fixed-point residual norms (the early-termination criterion,
@@ -522,6 +542,7 @@ def residual_norm(x, x_prev, *, impl: Optional[str] = None,
         (x, x_prev))
 
 
+@_scoped
 def stale_mix(uplink, cache, buf, fresh, store, w, *, impl: Optional[str] = None,
               block: Optional[int] = None):
     """Fused stale-uplink admission mix (bounded-staleness engine, ISSUE 7):
@@ -577,6 +598,7 @@ def _ef21_row_scales(rowmax, leaf_rows, lo: float):
     return jnp.maximum(scales, 1e-12)
 
 
+@_scoped
 def ef21_update(u, u_hat, bits: int, leaf_rows, *, impl: Optional[str] = None,
                 block: Optional[int] = None):
     """Fused EF21 quantise-delta over the arena: returns the integrated
@@ -617,6 +639,7 @@ def ef21_update(u, u_hat, bits: int, leaf_rows, *, impl: Optional[str] = None,
 # of the population arena, scatter the updated rows back
 # ---------------------------------------------------------------------------
 
+@_scoped
 def row_gather(arr, idx, *, impl: Optional[str] = None, block: Optional[int] = None):
     """Cohort gather out[t] = arr[idx[t]]: arr (m, width), idx (m_active,)
     int row ids.  One read of the gathered rows + one write of the
@@ -631,6 +654,7 @@ def row_gather(arr, idx, *, impl: Optional[str] = None, block: Optional[int] = N
                                 interpret=(impl == "pallas_interpret"))
 
 
+@_scoped
 def row_scatter(dst, idx, rows, *, impl: Optional[str] = None,
                 block: Optional[int] = None):
     """Cohort scatter: returns dst with dst[idx[t]] = rows[t] (idx unique --
@@ -658,6 +682,7 @@ def row_scatter(dst, idx, rows, *, impl: Optional[str] = None,
 # (core.topology layout: (2|E|, width) directed duals, width % 128 == 0)
 # ---------------------------------------------------------------------------
 
+@_scoped
 def neighbor_reduce(z, *, seg, first, sgn, n: int,
                     impl: Optional[str] = None, block: Optional[int] = None):
     """Per-node dual offsets s_i = sum_{j in N(i)} A_{ij} z_{i|j}.
@@ -683,6 +708,7 @@ def neighbor_reduce(z, *, seg, first, sgn, n: int,
     )
 
 
+@_scoped
 def edge_flip(z, x, c, *, rev, nbr, sgn, mask=None,
               impl: Optional[str] = None, block: Optional[int] = None):
     """PDMM's directed dual exchange, written at the receiving slot:

@@ -36,7 +36,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.fused_update import (
-    BLOCK_ROWS, LANES, assert_vmem_budget, ceil_to as _ceil_to, eq20,
+    BLOCK_ROWS, LANES, RELAYOUT, assert_vmem_budget, ceil_to as _ceil_to, eq20,
 )
 
 
@@ -47,15 +47,17 @@ def _tile(arr, block: int):
     assert w % LANES == 0, f"arena width {w} not a multiple of {LANES}"
     rows = w // LANES
     rows_p = _ceil_to(rows, block)
-    t = arr.reshape(arr.shape[:-1] + (rows, LANES))
-    if rows_p != rows:
-        pad = [(0, 0)] * (t.ndim - 2) + [(0, rows_p - rows), (0, 0)]
-        t = jnp.pad(t, pad)
+    with jax.named_scope(RELAYOUT):
+        t = arr.reshape(arr.shape[:-1] + (rows, LANES))
+        if rows_p != rows:
+            pad = [(0, 0)] * (t.ndim - 2) + [(0, rows_p - rows), (0, 0)]
+            t = jnp.pad(t, pad)
     return t, rows, rows_p
 
 
 def _untile(t, width: int, lead):
-    return t.reshape(lead + (-1,))[..., :width]
+    with jax.named_scope(RELAYOUT):
+        return t.reshape(lead + (-1,))[..., :width]
 
 
 def client_row(v):
@@ -65,7 +67,8 @@ def client_row(v):
     (8, 128)-aligned; the kernel reads a (1, LANES) row that broadcasts over
     its (block, LANES) data tile."""
     m = v.shape[0]
-    return jnp.broadcast_to(v.astype(jnp.float32)[:, None, None], (m, 1, LANES))
+    with jax.named_scope(RELAYOUT):
+        return jnp.broadcast_to(v.astype(jnp.float32)[:, None, None], (m, 1, LANES))
 
 
 # client i's constant row on an (m, width-blocks) grid
@@ -120,6 +123,7 @@ def round_tail_pallas(x_ref, lam_s, x_s, rho, *, with_lam_is: bool = True,
     if not with_lam_is:
         up = pl.pallas_call(
             functools.partial(_uplink_kernel, rho=float(rho)),
+            name="round_tail",
             grid=grid,
             in_specs=[client_bs, client_bs, server_bs],
             out_specs=client_bs,
@@ -129,6 +133,7 @@ def round_tail_pallas(x_ref, lam_s, x_s, rho, *, with_lam_is: bool = True,
         return None, _untile(up, w, (m,))
     lam_is, up = pl.pallas_call(
         functools.partial(_round_tail_kernel, rho=float(rho)),
+        name="round_tail",
         grid=grid,
         in_specs=[client_bs, client_bs, server_bs],
         out_specs=(client_bs, client_bs),
@@ -193,6 +198,7 @@ def scaffold_cv_pallas(c_i, x_K, c_s, x_s, alpha, *, block=None, interpret: bool
         kernel = functools.partial(_scaffold_cv_kernel, alpha=float(alpha))
     out = pl.pallas_call(
         kernel,
+        name="scaffold_cv",
         grid=(m, rows_p // br),
         in_specs=in_specs,
         out_specs=client_bs,
@@ -221,6 +227,7 @@ def dual_from_uplink_pallas(uplink, x_s, rho, *, block=None, interpret: bool = F
     st, _, _ = _tile(x_s, br)
     out = pl.pallas_call(
         functools.partial(_dual_kernel, rho=float(rho)),
+        name="dual_from_uplink",
         grid=(m, rows_p // br),
         in_specs=[
             pl.BlockSpec((1, br, LANES), lambda i, j: (i, j, 0)),
@@ -265,6 +272,7 @@ def ef21_rowmax_pallas(u, u_hat, *, block=None, interpret: bool = False):
     ht, _, _ = _tile(u_hat, br)
     out = pl.pallas_call(
         _rowmax_kernel,
+        name="ef21_rowmax",
         grid=(m, rows_p // br),
         in_specs=[
             pl.BlockSpec((1, br, LANES), lambda i, j: (i, j, 0)),
@@ -274,7 +282,8 @@ def ef21_rowmax_pallas(u, u_hat, *, block=None, interpret: bool = False):
         out_shape=jax.ShapeDtypeStruct((m, 1, rows_p), jnp.float32),
         interpret=interpret,
     )(ut, ht)
-    return out[:, 0, :rows]
+    with jax.named_scope(RELAYOUT):
+        return out[:, 0, :rows]
 
 
 def _qdq_kernel(u_ref, uh_ref, scale_ref, o_ref, *, lo: float):
@@ -296,11 +305,14 @@ def ef21_apply_pallas(u, u_hat, row_scales, bits: int, *, block=None, interpret:
     lo = float(2 ** (bits - 1) - 1)
     ut, _, rows_p = _tile(u, br)
     ht, _, _ = _tile(u_hat, br)
-    st = row_scales
-    if rows_p != rows:
-        st = jnp.pad(st, ((0, 0), (0, rows_p - rows)), constant_values=1.0)
+    with jax.named_scope(RELAYOUT):
+        st = row_scales
+        if rows_p != rows:
+            st = jnp.pad(st, ((0, 0), (0, rows_p - rows)), constant_values=1.0)
+        st = st[:, None, :]
     out = pl.pallas_call(
         functools.partial(_qdq_kernel, lo=lo),
+        name="ef21_apply",
         grid=(m, rows_p // br),
         in_specs=[
             pl.BlockSpec((1, br, LANES), lambda i, j: (i, j, 0)),
@@ -310,7 +322,7 @@ def ef21_apply_pallas(u, u_hat, row_scales, bits: int, *, block=None, interpret:
         out_specs=pl.BlockSpec((1, br, LANES), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((m, rows_p, LANES), u.dtype),
         interpret=interpret,
-    )(ut, ht, st[:, None, :])
+    )(ut, ht, st)
     return _untile(out, w, (m,))
 
 
@@ -383,6 +395,7 @@ def fused_update_arena_pallas(x, g, x_s, lam, step, rho, *, block=None, interpre
             step=float(step), rho=float(rho))
     out = pl.pallas_call(
         kernel,
+        name="fused_update_arena",
         grid=(m, rows_p // br),
         in_specs=in_specs,
         out_specs=client_bs,

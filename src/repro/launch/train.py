@@ -37,9 +37,11 @@ strike/rollback instants) as Perfetto-loadable Chrome trace JSON;
 ``--metrics-out metrics.jsonl`` streams every logged history row through
 the crash-safe JSONL sink as it happens, so loss curves survive a crash
 instead of living only in stdout; ``--profile-rounds A:B`` captures a
-``jax.profiler`` device trace for exactly those rounds.  All of it is off
-by default, and the off path adds no per-round host work (the dispatch
-wrappers are only installed when tracing is on).
+``jax.profiler`` device trace for exactly those rounds, with the round
+spans on the trace's own clock (``telemetry.spans``).  The summary counts
+the run's XLA compilations (``jit/compiles``, ``jit/compile_s``).  All of it
+is off by default, and the off path adds no per-round host work (the
+dispatch wrappers are only installed when tracing or profiling is on).
 """
 from __future__ import annotations
 
@@ -141,6 +143,12 @@ def run(
         profile_rounds,
         profile_dir or (str(pathlib.Path(trace_out).parent / "jaxprof")
                         if trace_out else "telemetry/jaxprof"))
+    # the profiler window turns the tracer on inside it, so the span
+    # wrappers go in whenever either can record
+    spans_on = tracer.enabled or prof is not None
+    if tel_on:
+        tel.compiles.install()
+    compiles0 = tel.compiles.totals()
 
     model = build_model(cfg)  # the model ignores cfg.fed (checked)
 
@@ -311,14 +319,18 @@ def run(
 
     def _instrument(fn):
         """Dispatch/sync spans around a round function.  Installed ONLY when
-        tracing is on: the telemetry-off path keeps the original callable
-        (and its async-dispatch overlap) with zero added per-round host
-        work.  The explicit block_until_ready span is what splits "enqueue
-        the round" from "wait for the device" in the trace."""
-        if not tracer.enabled:
+        tracing or profiling is on: the telemetry-off path keeps the
+        original callable (and its async-dispatch overlap) with zero added
+        per-round host work.  The explicit block_until_ready span is what
+        splits "enqueue the round" from "wait for the device" in the trace;
+        outside a profiler window with no ``--trace-out`` the wrapper
+        passes straight through."""
+        if not spans_on:
             return fn
 
         def wrapped(s, b):
+            if not tracer.enabled:
+                return fn(s, b)
             with tracer.span("round/dispatch"):
                 out = fn(s, b)
             with tracer.span("round/block_until_ready"):
@@ -463,9 +475,9 @@ def run(
 
     def traced_batches(it):
         """Wrap the batch stream so each ``next`` is a round/batch_build
-        span.  Only installed when tracing -- the off path iterates the
-        original generator untouched."""
-        if not tracer.enabled:
+        span.  Only installed when tracing or profiling -- the off path
+        iterates the original generator untouched."""
+        if not spans_on:
             return it
 
         def gen():
@@ -670,6 +682,9 @@ def run(
             prof.close()
         if registry is not None:
             registry.gauge("eta_scale").set(eta_scale)
+            n, secs = tel.compiles.totals()
+            registry.counter("jit/compiles").inc(n - compiles0[0])
+            registry.counter("jit/compile_s").inc(secs - compiles0[1])
         if sink is not None:
             sink.write({"kind": "summary", **registry.summary_row()})
             sink.close()

@@ -7,6 +7,13 @@ capture holds whole rounds -- XLA device timelines, host/device transfer
 lanes, and (on TPU) the per-kernel breakdown -- viewable in Perfetto or
 TensorBoard's profile plugin.
 
+Inside the window the global span tracer is on, even without
+``--trace-out``: its spans enter ``jax.profiler`` annotations, so the
+capture holds ``round/dispatch``, ``round/block_until_ready`` and the
+``popstore/*`` phases on ``/host:CPU``, on the device operations' clock.
+Besides the ``.xplane.pb`` the capture writes a ``.trace.json.gz`` that
+Perfetto and ``json`` read, with each device operation's name stack.
+
 Why a WINDOW and not the whole run: the profiler's overhead and trace size
 are per-event, so profiling a 10^4-round job is both slow and unreadable;
 two or three steady-state rounds after compilation has settled is what the
@@ -20,6 +27,8 @@ from __future__ import annotations
 import os
 import pathlib
 from typing import Optional
+
+from repro.telemetry import spans
 
 
 class RoundProfiler:
@@ -39,6 +48,7 @@ class RoundProfiler:
         self.out_dir = str(out_dir)
         self.active = False
         self.captured = False
+        self._tracer_was_on = False
 
     @classmethod
     def parse(cls, spec: Optional[str],
@@ -71,8 +81,10 @@ class RoundProfiler:
         pathlib.Path(self.out_dir).mkdir(parents=True, exist_ok=True)
         # a failing profiler raises: a run that asked for a trace and got
         # none must not exit 0
-        jax.profiler.start_trace(self.out_dir)
+        jax.profiler.start_trace(self.out_dir, create_perfetto_trace=True)
         self.active = True
+        self._tracer_was_on = spans.enabled()
+        spans.get_tracer().configure(enabled=True)
         print(f"[telemetry] jax.profiler capture started at round "
               f"{round_idx} -> {self.out_dir}", flush=True)
 
@@ -85,6 +97,9 @@ class RoundProfiler:
 
         self.active = False
         self.captured = True
+        tracer = spans.get_tracer().configure(enabled=self._tracer_was_on)
+        if not self._tracer_was_on:
+            tracer.drain()  # the window's events served the annotations only
         jax.profiler.stop_trace()
         print(f"[telemetry] jax.profiler capture written to "
               f"{self.out_dir}", flush=True)

@@ -37,12 +37,19 @@ Design constraints, in order:
     chrome://tracing both load a truncated trace, so a killed run keeps
     every span flushed before the crash.
 
+  * ON THE DEVICE'S CLOCK: a span on an enabled tracer also enters a
+    ``jax.profiler.TraceAnnotation`` of the same name, so a profiler
+    capture (``--profile-rounds``, or any ``jax.profiler`` trace) holds the
+    same ``round/*``, ``popstore/*`` and ``ckpt/*`` spans on its
+    ``/host:CPU`` lines, beside the device's operations.  An enabled tracer
+    with no ``trace_out`` records for the annotations alone; ``flush``
+    drops its events.
+
 Span names are ``path/phase`` (taxonomy in docs/telemetry.md).  ``ph`` codes
 emitted: ``X`` (complete span), ``i`` (instant), ``C`` (counter).
 """
 from __future__ import annotations
 
-import functools
 import json
 import os
 import pathlib
@@ -50,6 +57,8 @@ import threading
 import time
 from collections import deque
 from typing import Any, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 class _NullSpan:
@@ -72,9 +81,10 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """One live span on an ENABLED tracer; records on ``__exit__``."""
+    """One live span on an ENABLED tracer; records on ``__exit__``, and
+    holds a profiler annotation of the same name while it is open."""
 
-    __slots__ = ("_tracer", "_name", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, args):
         self._tracer = tracer
@@ -82,11 +92,14 @@ class _Span:
         self._args = args
 
     def __enter__(self):
+        self._annotation = TraceAnnotation(self._name)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter_ns()
+        self._annotation.__exit__(exc_type, exc, tb)
         # ("X", name, start_ns, dur_ns, tid, args) -- rendered at flush
         self._tracer._events.append(
             ("X", self._name, self._t0, t1 - self._t0,
@@ -147,23 +160,6 @@ class Tracer:
         self._events.append(("C", name, time.perf_counter_ns(), 0,
                              threading.get_ident(), value))
 
-    def traced(self, name: Optional[str] = None):
-        """Decorator form: ``@tracer.traced("serve/query")``."""
-
-        def deco(fn):
-            label = name or fn.__qualname__
-
-            @functools.wraps(fn)
-            def wrapper(*a, **kw):
-                if not self.enabled:
-                    return fn(*a, **kw)
-                with self.span(label):
-                    return fn(*a, **kw)
-
-            return wrapper
-
-        return deco
-
     # -- rendering / IO ----------------------------------------------------
 
     def _render(self, ev) -> dict:
@@ -202,7 +198,7 @@ class Tracer:
         with self._lock:
             if self._path is None:
                 # no sink configured: drop (recording without an output file
-                # is only useful through ``drain``)
+                # serves the profiler annotations, or ``drain``)
                 return
             if self._file is None:
                 self._path.parent.mkdir(parents=True, exist_ok=True)
@@ -266,10 +262,6 @@ def instant(name: str, args: Optional[dict] = None) -> None:
 
 def counter(name: str, value: Any) -> None:
     _GLOBAL.counter(name, value)
-
-
-def traced(name: Optional[str] = None):
-    return _GLOBAL.traced(name)
 
 
 def flush() -> None:

@@ -66,20 +66,15 @@ def test_span_nesting_and_chrome_trace_json(tmp_path):
     assert ring["ph"] == "C" and ring["args"] == {"hit": 2, "miss": 1}
 
 
-def test_scalar_counter_and_traced_decorator():
+def test_scalar_counter():
     tr = Tracer().configure(enabled=True)
     tr.counter("hits", 7)
-
-    @tr.traced("work/fn")
-    def fn(x):
-        return x + 1
-
-    assert fn(1) == 2
+    tr.counter("ring", {"hit": 1})
     events = tr.drain()
     assert {"ph": "C", "args": {"value": 7}}.items() <= events[0].items()
-    assert events[1]["name"] == "work/fn" and events[1]["ph"] == "X"
+    assert events[1]["args"] == {"hit": 1}
     tr.configure(enabled=False)
-    assert fn(2) == 3  # decorator bypasses the span when disabled
+    tr.counter("hits", 8)  # a disabled tracer records nothing
     assert tr.drain() == []
 
 
@@ -245,3 +240,128 @@ def test_prometheus_textfile_format(tmp_path):
         assert tel.metrics._NAME_OK.match(name), name
         float(val)
     assert not out.with_suffix(out.suffix + ".tmp").exists()  # atomic write
+
+
+# -- the program's spans and scopes on the profiler's clock -------------------
+
+
+def _host_events(trace_dir, name):
+    """Events called ``name`` on ``/host:CPU`` of the profiler capture."""
+    import glob
+    import gzip
+
+    (path,) = glob.glob(f"{trace_dir}/**/*.trace.json.gz", recursive=True)
+    with gzip.open(path, "rt") as f:
+        events = json.load(f)["traceEvents"]
+    procs = {e["pid"]: e["args"]["name"] for e in events
+             if e.get("ph") == "M" and e["name"] == "process_name"}
+    return [e for e in events if e.get("name") == name
+            and procs.get(e.get("pid")) == "/host:CPU"]
+
+
+def test_enabled_span_lands_on_the_profiler_host_trace(tmp_path):
+    import jax
+    import jax.numpy as jnp
+
+    on, off = Tracer().configure(enabled=True), Tracer()
+    jax.profiler.start_trace(str(tmp_path), create_perfetto_trace=True)
+    try:
+        with on.span("round/dispatch"):
+            jnp.arange(8.0).sum().block_until_ready()
+        with off.span("round/never"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    (ev,) = _host_events(tmp_path, "round/dispatch")
+    assert ev["ph"] == "X" and ev["dur"] > 0
+    assert _host_events(tmp_path, "round/never") == []
+    # the tracer's own record of the span is kept as before
+    assert [e["name"] for e in on.drain()] == ["round/dispatch"]
+
+
+def test_profile_rounds_puts_round_spans_on_the_trace_without_trace_out(tmp_path):
+    prof_dir = tmp_path / "prof"
+    train_run("olmo-1b", reduced=True, steps=3, m=2, per_client_batch=1,
+              seq_len=16, k=1, eta=0.05, log_every=1,
+              profile_rounds="2:2", profile_dir=str(prof_dir))
+    assert len(_host_events(prof_dir, "round/dispatch")) == 1
+    assert len(_host_events(prof_dir, "round/block_until_ready")) == 1
+    # the tracer was on for the window alone, and kept none of its events
+    assert not tel.enabled()
+    assert tel.get_tracer().drain() == []
+
+
+def test_compile_counter_counts_each_compile(tmp_path):
+    import jax
+    import jax.numpy as jnp
+
+    tel.compiles.install()
+    tel.compiles.install()  # one listener, however often installed
+    x7, x9 = jnp.ones(7), jnp.ones(9)
+    n0, s0 = tel.compiles.totals()
+    tracer = tel.get_tracer()
+    tracer.drain()
+    tracer.configure(enabled=True)
+    try:
+        f = jax.jit(lambda x: x * 3.0 + 1.0)
+        f(x7).block_until_ready()
+        f(x7).block_until_ready()  # same shape: no new compile
+        n1, s1 = tel.compiles.totals()
+        f(x9).block_until_ready()  # new shape: one more
+        n2, _ = tel.compiles.totals()
+    finally:
+        tracer.configure(enabled=False)
+    assert n1 - n0 == 1 and s1 > s0
+    assert n2 - n1 == 1
+    marks = [e for e in tracer.drain() if e["name"] == "jit/compile"]
+    assert len(marks) == 2 and all(e["ph"] == "i" for e in marks)
+
+    # train.run's summary reports what its own run compiled
+    path = tmp_path / "m.jsonl"
+    train_run("olmo-1b", reduced=True, steps=2, m=2, per_client_batch=1,
+              seq_len=16, k=1, eta=0.05, log_every=1, metrics_out=str(path))
+    (summary,) = [r for r in tel.read_jsonl(path) if r["kind"] == "summary"]
+    assert summary["jit/compiles"] >= 1 and summary["jit/compile_s"] > 0
+
+
+PHASES = ("round.inner_loop", "round.client_grad", "arena_pack",
+          "round.client_update", "fused_update_arena", "round.uplink",
+          "round_tail", "round.server_mean", "round.dual_refresh",
+          "dual_from_uplink", "round.metrics")
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+def test_round_phases_name_the_compiled_ops(impl, monkeypatch):
+    """Every phase of an arena round names its ops in the compiled HLO, and
+    with the Pallas kernels every tiling pad and reshape is a relayout."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.configs.base import FederatedConfig
+    from repro.core import make
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "default_impl", lambda: impl)
+    params = {"w": jnp.full((300,), 0.1), "b": jnp.zeros((7,))}
+    fed = make(FederatedConfig(algorithm="gpdmm", inner_steps=2, eta=0.05,
+                               num_clients=3, use_arena=True))
+
+    def grad_fn(p, b):  # a plain gradient: the pytree/arena round trip
+        return jax.grad(lambda q: jnp.sum((q["w"][:7] * b + q["b"]) ** 2))(p)
+
+    state = fed.init(params, 3)
+    hlo = jax.jit(lambda s, b: fed.round(s, grad_fn, b)).lower(
+        state, jnp.ones((3, 7))).compile().as_text()
+    ops_named = re.findall(r"= \S+ (\w[\w-]*)\(.*op_name=\"([^\"]*)\"", hlo)
+    stacks = [n for _, n in ops_named]
+    for scope in PHASES:
+        assert any(f"/{scope}/" in n for n in stacks), scope
+    if impl == "pallas_interpret":
+        tiling = [(op, n) for op, n in ops_named if op == "pad"
+                  and re.search(r"/(fused_update_arena|round_tail|dual_from_uplink)/", n)]
+        assert tiling and all("/relayout/" in n for _, n in tiling), tiling
+        assert any(n.endswith("/relayout/reshape") for n in stacks)
+    else:
+        assert not any("/relayout/" in n for n in stacks)
