@@ -166,6 +166,8 @@ def one_chip(dev) -> None:
     steady = float(np.median(secs[1:]))
     print(f"[smoke] implementations: {json.dumps(used, sort_keys=True)}",
           flush=True)
+    print(f"[smoke] arena kernel layouts: "
+          f"{json.dumps(ops.LAYOUT, sort_keys=True)}", flush=True)
     print(f"[smoke] smoke reading, not a benchmark: run {wall:.1f} s; first "
           f"round (compile + run) {secs[0]:.2f} s; later rounds "
           f"{', '.join(f'{s:.3f}' for s in secs[1:])} s; compile "
@@ -175,6 +177,8 @@ def one_chip(dev) -> None:
 
     for op in ("fused_update_arena", "round_tail", "dual_from_uplink"):
         check(used.get(op) == "pallas", f"{op} ran {used.get(op)}, not pallas")
+        # M clients are fewer than a bf16 sublane tile: the tiled layout
+        check(ops.LAYOUT[op][0] == "tiled", f"{op} took {ops.LAYOUT[op]}")
     losses = [row["server_loss"] for row in history]
     check(len(losses) == ROUNDS and all(np.isfinite(losses)),
           f"server losses {losses}")

@@ -44,6 +44,10 @@ def _step_arr(step):
 
 # op name -> implementation it resolved to when last traced
 RESOLVED: dict[str, str] = {}
+# elementwise arena kernel -> the block layout its Pallas call took at its
+# last trace: ("flat", bm, bw) over the (m, width) arena as it lies, or
+# ("tiled", rows) over (m, rows_p, 128) tiles (``round_tail._Blocks``)
+LAYOUT: dict[str, tuple] = {}
 
 
 def _scoped(fn):

@@ -19,13 +19,18 @@ HBM.  On the arena the same math becomes three fused kernels:
                                in-kernel, so the K-step scan issues ONE
                                pallas_call per step instead of one per leaf.
 
-All kernels tile a client row as ``(rows = width // 128, 128)``; the arena
-pads every leaf to a 128-lane multiple, so tiles never straddle leaves and
-the EF21 per-(client, leaf) quantisation scale is a static row-segment
-reduction (same semantics as the per-leaf pytree path).
+The elementwise kernels (eq. (20), the uplink, the dual refresh, SCAFFOLD's
+control variate) read the ``(m, width)`` arena as it lies, in ``(bm, bw)``
+blocks (``_Blocks``), once there are at least a sublane tile of clients.
+With fewer, and in the EF21 kernels always, a client row is tiled as
+``(rows = width // 128, 128)`` and padded to whole blocks of rows; the
+arena pads every leaf to a 128-lane multiple, so tiles never straddle
+leaves and the EF21 per-(client, leaf) quantisation scale is a static
+row-segment reduction (same semantics as the per-leaf pytree path).
 
-Server-row operands use a broadcast index map (block ``(j,)`` for every
-client ``i``) -- the (m, width) broadcast is never materialised in HBM.
+Server-row operands use a broadcast index map (the same width block for
+every client block) -- the (m, width) broadcast is never materialised in
+HBM.
 """
 from __future__ import annotations
 
@@ -38,6 +43,7 @@ from jax.experimental import pallas as pl
 from repro.kernels.fused_update import (
     BLOCK_ROWS, LANES, RELAYOUT, assert_vmem_budget, ceil_to as _ceil_to, eq20,
 )
+from repro.kernels.ops import LAYOUT
 
 
 def _tile(arr, block: int):
@@ -82,6 +88,97 @@ def _resolve_block(block, rows: int) -> int:
     return min(block, max(8, _ceil_to(rows, 8)))
 
 
+def _sublanes(dtype) -> int:
+    """Rows of one (rows, LANES) VMEM tile of ``dtype``: 8 for 32-bit
+    values, 16 for 16-bit."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+# A flat-path block holds up to FLAT_BLOCK_ROWS clients and about
+# FLAT_BLOCK_BYTES of f32 (the kernels' working precision).  On a v5e the
+# eq. (20) kernel over the femnist.full arena ran fastest at (128, 2048):
+# 3.89 ms a call against 4.02 at (8, 16256) and 3.97 at (256, 1024); 2 MiB
+# blocks overflow VMEM (PERF.md, the (bm, bw) sweep).
+FLAT_BLOCK_ROWS = 128
+FLAT_BLOCK_BYTES = 1024 * 1024
+
+
+def _flat_blocks(m: int, w: int, dtype, n_arrays: int, block=None):
+    """``(bm, bw)`` blocks of the client arena as it lies, ``(m, w)`` seen as
+    ``(1, m, w)``; None where m is below the dtype's sublane tile (such
+    blocks would leave most sublanes empty, so those shapes take the tiled
+    path).  ``block``: client rows per block (default ``FLAT_BLOCK_ROWS``),
+    rounded up to the sublane tile and cut to the whole tiles m holds.
+    ``bw`` splits the width evenly into 128-lane multiples of about
+    ``FLAT_BLOCK_BYTES`` of f32 per block."""
+    sub = _sublanes(dtype)
+    if m < sub:
+        return None
+    bm = min(_ceil_to(block or FLAT_BLOCK_ROWS, sub), m // sub * sub)
+    n_w = pl.cdiv(w, max(LANES, FLAT_BLOCK_BYTES // (bm * 4)))
+    bw = _ceil_to(pl.cdiv(w, n_w), LANES)
+    assert_vmem_budget(n_arrays, bm * bw // LANES)
+    return bm, bw
+
+
+class _Blocks:
+    """Operand layout of one elementwise arena kernel: ``(m, width)`` client
+    buffers, ``(width,)`` server rows and ``(m,)`` per-client scalars in,
+    client buffers out.
+
+    Flat (m at least the sublane tile): client buffers enter as ``x[None]``,
+    ``(1, m, width)``, a bitcast, in ``(1, bm, bw)`` blocks, and server rows
+    as ``(1, width)`` in ``(1, bw)`` blocks; the grid runs clients innermost,
+    so a server block stays resident across consecutive steps, and Pallas
+    masks the ragged edge blocks.  Tiled (fewer clients): every operand is
+    reshaped to ``(..., rows_p, LANES)`` and row-padded to ``block`` rows
+    (``_tile``), the output sliced back (``_untile``).  ``ops.LAYOUT`` records
+    which one each kernel took at its last trace."""
+
+    def __init__(self, name, m, w, dtype, n_arrays, block):
+        assert w % LANES == 0, f"arena width {w} not a multiple of {LANES}"
+        self.m, self.w = m, w
+        fb = _flat_blocks(m, w, dtype, n_arrays, block)
+        if fb is None:
+            br = self.br = _resolve_block(block, w // LANES)
+            assert_vmem_budget(n_arrays, br)
+            rows_p = _ceil_to(w // LANES, br)
+            self.grid = (m, rows_p // br)
+            self.client = pl.BlockSpec((1, br, LANES), lambda i, j: (i, j, 0))
+            self.server = pl.BlockSpec((br, LANES), lambda i, j: (j, 0))
+            self.row = CLIENT_ROW_BS
+            self.out_lead = (m, rows_p, LANES)
+            LAYOUT[name] = ("tiled", br)
+        else:
+            self.br = None
+            bm, bw = fb
+            self.grid = (pl.cdiv(w, bw), pl.cdiv(m, bm))
+            self.client = pl.BlockSpec((1, bm, bw), lambda j, i: (0, i, j))
+            self.server = pl.BlockSpec((1, bw), lambda j, i: (0, j))
+            self.row = pl.BlockSpec((1, bm, LANES), lambda j, i: (0, i, 0))
+            self.out_lead = (1, m, w)
+            LAYOUT[name] = ("flat", bm, bw)
+
+    def clients(self, a):
+        return a[None] if self.br is None else _tile(a, self.br)[0]
+
+    servers = clients
+
+    def rows(self, v):
+        """(m,) per-client scalars as f32 rows the kernel reads as
+        ``ref[0][:, :1]``: one value per client row of its block."""
+        if self.br is not None:
+            return client_row(v)
+        with jax.named_scope(RELAYOUT):
+            return jnp.broadcast_to(v.astype(jnp.float32)[None, :, None], (1, self.m, LANES))
+
+    def out_shape(self, dtype):
+        return jax.ShapeDtypeStruct(self.out_lead, dtype)
+
+    def back(self, out):
+        return out[0] if self.br is None else _untile(out, self.w, (self.m,))
+
+
 # ---------------------------------------------------------------------------
 # (a) lam_is + uplink in one pass
 # ---------------------------------------------------------------------------
@@ -110,37 +207,31 @@ def round_tail_pallas(x_ref, lam_s, x_s, rho, *, with_lam_is: bool = True,
     hot path: both callers discard lam_is outside traces) skips the second
     output entirely -- 3 reads + 1 write -- and returns (None, uplink)."""
     m, w = x_ref.shape
-    dtype = x_ref.dtype
-    br = _resolve_block(block, w // LANES)
-    assert_vmem_budget(5 if with_lam_is else 4, br)
-    xt, _, rows_p = _tile(x_ref, br)
-    lt, _, _ = _tile(lam_s, br)
-    st, _, _ = _tile(x_s, br)
-    grid = (m, rows_p // br)
-    client_bs = pl.BlockSpec((1, br, LANES), lambda i, j: (i, j, 0))
-    server_bs = pl.BlockSpec((br, LANES), lambda i, j: (j, 0))
-    out_sds = jax.ShapeDtypeStruct((m, rows_p, LANES), dtype)
+    lay = _Blocks("round_tail", m, w, x_ref.dtype, 5 if with_lam_is else 4, block)
+    args = (lay.clients(x_ref), lay.clients(lam_s), lay.servers(x_s))
+    in_specs = [lay.client, lay.client, lay.server]
+    out_sds = lay.out_shape(x_ref.dtype)
     if not with_lam_is:
         up = pl.pallas_call(
             functools.partial(_uplink_kernel, rho=float(rho)),
             name="round_tail",
-            grid=grid,
-            in_specs=[client_bs, client_bs, server_bs],
-            out_specs=client_bs,
+            grid=lay.grid,
+            in_specs=in_specs,
+            out_specs=lay.client,
             out_shape=out_sds,
             interpret=interpret,
-        )(xt, lt, st)
-        return None, _untile(up, w, (m,))
+        )(*args)
+        return None, lay.back(up)
     lam_is, up = pl.pallas_call(
         functools.partial(_round_tail_kernel, rho=float(rho)),
         name="round_tail",
-        grid=grid,
-        in_specs=[client_bs, client_bs, server_bs],
-        out_specs=(client_bs, client_bs),
+        grid=lay.grid,
+        in_specs=in_specs,
+        out_specs=(lay.client, lay.client),
         out_shape=(out_sds, out_sds),
         interpret=interpret,
-    )(xt, lt, st)
-    return _untile(lam_is, w, (m,)), _untile(up, w, (m,))
+    )(*args)
+    return lay.back(lam_is), lay.back(up)
 
 
 # ---------------------------------------------------------------------------
@@ -158,14 +249,14 @@ def _scaffold_cv_kernel(ci_ref, xk_ref, c_ref, xs_ref, o_ref, *, alpha: float):
 
 
 def _scaffold_cv_kernel_valpha(ci_ref, xk_ref, c_ref, xs_ref, a_ref, o_ref):
-    # per-client alpha = 1/(K eta_i) loaded as a (1, LANES) constant row
-    # (core.autotune's per-client stepsizes), broadcast over the tile
+    # per-client alpha = 1/(K eta_i) read as one value per client row of
+    # the block (core.autotune's per-client stepsizes)
     f32 = jnp.float32
     ci = ci_ref[0].astype(f32)
     xk = xk_ref[0].astype(f32)
     c = c_ref[...].astype(f32)
     xs = xs_ref[...].astype(f32)
-    o_ref[0] = (ci - c + a_ref[0] * (xs - xk)).astype(o_ref.dtype)
+    o_ref[0] = (ci - c + a_ref[0][:, :1] * (xs - xk)).astype(o_ref.dtype)
 
 
 def scaffold_cv_pallas(c_i, x_K, c_s, x_s, alpha, *, block=None, interpret: bool = False):
@@ -179,33 +270,26 @@ def scaffold_cv_pallas(c_i, x_K, c_s, x_s, alpha, *, block=None, interpret: bool
     broadcast row operand.  2 client reads + 1 write instead of the ~5-pass
     per-leaf tmap chain."""
     m, w = c_i.shape
-    br = _resolve_block(block, w // LANES)
-    assert_vmem_budget(5, br)
-    ct, _, rows_p = _tile(c_i, br)
-    xt, _, _ = _tile(x_K, br)
-    cst, _, _ = _tile(c_s, br)
-    st, _, _ = _tile(x_s, br)
-    client_bs = pl.BlockSpec((1, br, LANES), lambda i, j: (i, j, 0))
-    server_bs = pl.BlockSpec((br, LANES), lambda i, j: (j, 0))
-    args = [ct, xt, cst, st]
-    in_specs = [client_bs, client_bs, server_bs, server_bs]
+    lay = _Blocks("scaffold_cv", m, w, c_i.dtype, 5, block)
+    args = [lay.clients(c_i), lay.clients(x_K), lay.servers(c_s), lay.servers(x_s)]
+    in_specs = [lay.client, lay.client, lay.server, lay.server]
     if jnp.ndim(alpha) > 0:
         assert alpha.shape == (m,), alpha.shape
-        args.append(client_row(alpha))
-        in_specs.append(CLIENT_ROW_BS)
+        args.append(lay.rows(alpha))
+        in_specs.append(lay.row)
         kernel = _scaffold_cv_kernel_valpha
     else:
         kernel = functools.partial(_scaffold_cv_kernel, alpha=float(alpha))
     out = pl.pallas_call(
         kernel,
         name="scaffold_cv",
-        grid=(m, rows_p // br),
+        grid=lay.grid,
         in_specs=in_specs,
-        out_specs=client_bs,
-        out_shape=jax.ShapeDtypeStruct((m, rows_p, LANES), c_i.dtype),
+        out_specs=lay.client,
+        out_shape=lay.out_shape(c_i.dtype),
         interpret=interpret,
     )(*args)
-    return _untile(out, w, (m,))
+    return lay.back(out)
 
 
 # ---------------------------------------------------------------------------
@@ -221,23 +305,17 @@ def _dual_kernel(u_ref, xs_ref, o_ref, *, rho: float):
 def dual_from_uplink_pallas(uplink, x_s, rho, *, block=None, interpret: bool = False):
     """uplink: (m, width); x_s: (width,).  Returns lam_s' = rho (u - x_s)."""
     m, w = uplink.shape
-    br = _resolve_block(block, w // LANES)
-    assert_vmem_budget(3, br)
-    ut, _, rows_p = _tile(uplink, br)
-    st, _, _ = _tile(x_s, br)
+    lay = _Blocks("dual_from_uplink", m, w, uplink.dtype, 3, block)
     out = pl.pallas_call(
         functools.partial(_dual_kernel, rho=float(rho)),
         name="dual_from_uplink",
-        grid=(m, rows_p // br),
-        in_specs=[
-            pl.BlockSpec((1, br, LANES), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((br, LANES), lambda i, j: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, br, LANES), lambda i, j: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, rows_p, LANES), uplink.dtype),
+        grid=lay.grid,
+        in_specs=[lay.client, lay.server],
+        out_specs=lay.client,
+        out_shape=lay.out_shape(uplink.dtype),
         interpret=interpret,
-    )(ut, st)
-    return _untile(out, w, (m,))
+    )(lay.clients(uplink), lay.servers(x_s))
+    return lay.back(out)
 
 
 # ---------------------------------------------------------------------------
@@ -347,19 +425,19 @@ def _update_kernel_nolam(x_ref, g_ref, xs_ref, o_ref, *, step: float, rho: float
 
 
 def _update_kernel_vstep(x_ref, g_ref, xs_ref, lam_ref, step_ref, o_ref, *, rho: float):
-    # per-client stepsize loaded as a (1, LANES) constant row
-    # (core.autotune), broadcast over the tile
+    # per-client stepsize read as one value per client row of the block
+    # (core.autotune)
     f32 = jnp.float32
     out = eq20(x_ref[0].astype(f32), g_ref[0].astype(f32),
                xs_ref[...].astype(f32), lam_ref[0].astype(f32),
-               step_ref[0], rho)
+               step_ref[0][:, :1], rho)
     o_ref[0] = out.astype(o_ref.dtype)
 
 
 def _update_kernel_nolam_vstep(x_ref, g_ref, xs_ref, step_ref, o_ref, *, rho: float):
     f32 = jnp.float32
     out = eq20(x_ref[0].astype(f32), g_ref[0].astype(f32),
-               xs_ref[...].astype(f32), None, step_ref[0], rho)
+               xs_ref[...].astype(f32), None, step_ref[0][:, :1], rho)
     o_ref[0] = out.astype(o_ref.dtype)
 
 
@@ -368,24 +446,19 @@ def fused_update_arena_pallas(x, g, x_s, lam, step, rho, *, block=None, interpre
     lam: (m, width) or None (dual term dropped).  ``step``: scalar (baked as
     a compile-time constant -- the pre-auto-eta path, bitwise unchanged) or
     (m,) per-client stepsizes riding a broadcast row operand
-    (core.autotune).  One pallas_call over the whole packed buffer."""
+    (core.autotune).  One pallas_call over the whole packed buffer, written
+    into x's buffer where the caller no longer needs x."""
     m, w = x.shape
-    br = _resolve_block(block, w // LANES)
-    assert_vmem_budget(4 if lam is None else 5, br)
-    xt, _, rows_p = _tile(x, br)
-    gt, _, _ = _tile(g, br)
-    st, _, _ = _tile(x_s, br)
-    client_bs = pl.BlockSpec((1, br, LANES), lambda i, j: (i, j, 0))
-    server_bs = pl.BlockSpec((br, LANES), lambda i, j: (j, 0))
-    args, in_specs = [xt, gt, st], [client_bs, client_bs, server_bs]
+    lay = _Blocks("fused_update_arena", m, w, x.dtype, 4 if lam is None else 5, block)
+    args = [lay.clients(x), lay.clients(g), lay.servers(x_s)]
+    in_specs = [lay.client, lay.client, lay.server]
     if lam is not None:
-        lt, _, _ = _tile(lam, br)
-        args.append(lt)
-        in_specs.append(client_bs)
+        args.append(lay.clients(lam))
+        in_specs.append(lay.client)
     if jnp.ndim(step) > 0:
         assert step.shape == (m,), step.shape
-        args.append(client_row(step))
-        in_specs.append(CLIENT_ROW_BS)
+        args.append(lay.rows(step))
+        in_specs.append(lay.row)
         kernel = functools.partial(
             _update_kernel_nolam_vstep if lam is None else _update_kernel_vstep,
             rho=float(rho))
@@ -396,10 +469,13 @@ def fused_update_arena_pallas(x, g, x_s, lam, step, rho, *, block=None, interpre
     out = pl.pallas_call(
         kernel,
         name="fused_update_arena",
-        grid=(m, rows_p // br),
+        grid=lay.grid,
         in_specs=in_specs,
-        out_specs=client_bs,
-        out_shape=jax.ShapeDtypeStruct((m, rows_p, LANES), x.dtype),
+        out_specs=lay.client,
+        out_shape=lay.out_shape(x.dtype),
+        # the step overwrites x, which the inner loop's carry drops: without
+        # the alias XLA copies the whole arena before every call
+        input_output_aliases={0: 0},
         interpret=interpret,
     )(*args)
-    return _untile(out, w, (m,))
+    return lay.back(out)

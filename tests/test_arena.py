@@ -1,12 +1,14 @@
 """Flat client-state arena + fused round-tail kernels (ISSUE 1 tentpole).
 
 Covers: pack/unpack round trips across dtypes and odd (non-multiple-of-128)
-leaf sizes, interpret-mode parity of every round-tail kernel against the
-per-leaf pytree reference, arena-vs-pytree parity of whole GPDMM/AGPDMM/
+leaf sizes, interpret-mode parity of every round-tail kernel (the
+elementwise ones bit for bit against the XLA path, on the tiled and the
+flat layout with ragged blocks), arena-vs-pytree parity of whole GPDMM/AGPDMM/
 FedSplit rounds (incl. the EF21-quantised and partial-participation
 variants), the KKT invariant on the arena path, and the VMEM budget guard.
 """
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -98,43 +100,84 @@ def test_leaf_view_matches_leaf():
 
 
 # ---------------------------------------------------------------------------
-# kernel parity (interpret mode) vs the pytree reference
+# kernel parity (interpret mode) vs plain float32 arithmetic and the XLA path
 # ---------------------------------------------------------------------------
 
+# (m, width) of the elementwise arena kernels' parity cases.  "odd": the
+# packed odd-size tree, m = 5 below every sublane tile (the tiled path);
+# "ragged": m = 37 clients in blocks of 32 rows, and the width of 131 lane
+# rows in three 5632-lane blocks, the last of each ragged (the flat path)
+ARENA_CASES = {"odd": (5, None), "ragged": (37, 131 * 128)}
+
+
+def arena_operands(case, dtype, n_clients, n_rows, seed):
+    """``n_clients`` (m, width) client buffers, ``n_rows`` (width,) server rows
+    and an (m,) per-client stepsize of ``case``."""
+    m, w = ARENA_CASES[case]
+    if w is None:
+        w = arena.ArenaSpec.from_tree(odd_tree(jax.random.key(0))).width
+    ks = iter(jax.random.split(jax.random.key(seed), n_clients + n_rows + 1))
+    clients = [jax.random.normal(next(ks), (m, w)).astype(dtype) for _ in range(n_clients)]
+    rows = [jax.random.normal(next(ks), (w,)).astype(dtype) for _ in range(n_rows)]
+    step = jax.random.uniform(next(ks), (m,), minval=0.01, maxval=0.1)
+    return clients, rows, step
+
+
+def f32(a):
+    return np.asarray(a, np.float32)
+
+
+def assert_layout(op, impl, m, dtype):
+    """The Pallas call took the flat path iff m reaches the dtype's
+    sublane tile (8 rows of 32-bit values, 16 of 16-bit)."""
+    if impl == "pallas_interpret":
+        want = "flat" if m >= 32 // jnp.dtype(dtype).itemsize else "tiled"
+        assert ops.LAYOUT[op][0] == want, ops.LAYOUT[op]
+
+
+def assert_bitwise_xla(fn, *args):
+    """``fn(impl, *args)`` gives the same bits with the Pallas kernel
+    (interpret mode) as with ``impl="xla"``, both jitted: the blocks,
+    ragged edges included, change no element.  (Eagerly, the XLA path runs
+    op by op and rounds where the jitted graph may fuse a multiply-add.)"""
+    got, want = (jax.jit(functools.partial(fn, impl))(*args)
+                 for impl in ("pallas_interpret", "xla"))
+    np.testing.assert_array_equal(f32(got), f32(want))
+
+
 @pytest.mark.parametrize("impl", IMPLS)
+@pytest.mark.parametrize("case", sorted(ARENA_CASES))
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_round_tail_parity(impl, dtype):
-    m, rho = 5, 2.5
-    tree = odd_tree(jax.random.key(5), dtype, m=m)
-    spec = arena.ArenaSpec.from_tree(tree, stacked=True)
-    lam_tree = odd_tree(jax.random.key(6), dtype, m=m)
-    xs_tree = odd_tree(jax.random.key(7), dtype)
-    xs_b = T.tree_broadcast(xs_tree, m)
-    lam_is_t = T.tmap(lambda s, xr, l: rho * (s - xr) - l, xs_b, tree, lam_tree)
-    up_t = T.tmap(lambda xr, l: xr - l / rho, tree, lam_is_t)
+def test_round_tail_parity(impl, case, dtype):
+    rho = 2.5
+    (x, lam), (xs,), _ = arena_operands(case, dtype, 2, 1, 5)
+    m = x.shape[0]
+    xf, lf, sf = f32(x), f32(lam), f32(xs)[None]
+    lam_is_exp = rho * (sf - xf) - lf
+    up_exp = xf - lam_is_exp / rho
 
-    lam_is, up = ops.round_tail(
-        spec.pack_stacked(tree), spec.pack_stacked(lam_tree), spec.pack(xs_tree), rho, impl=impl
-    )
+    lam_is, up = ops.round_tail(x, lam, xs, rho, impl=impl)
+    assert_layout("round_tail", impl, m, dtype)
     tol = 1e-5 if dtype == jnp.float32 else 5e-2
-    np.testing.assert_allclose(
-        np.asarray(lam_is, np.float32), np.asarray(spec.pack_stacked(lam_is_t), np.float32),
-        atol=tol, rtol=tol)
-    np.testing.assert_allclose(
-        np.asarray(up, np.float32), np.asarray(spec.pack_stacked(up_t), np.float32),
-        atol=tol, rtol=tol)
+    np.testing.assert_allclose(f32(lam_is), lam_is_exp, atol=tol, rtol=tol)
+    np.testing.assert_allclose(f32(up), up_exp, atol=tol, rtol=tol)
 
-    lam_new = ops.dual_from_uplink(up, spec.pack(xs_tree), rho, impl=impl)
-    exp = rho * (np.asarray(up, np.float32) - np.asarray(spec.pack(xs_tree), np.float32)[None])
-    np.testing.assert_allclose(np.asarray(lam_new, np.float32), exp, atol=tol, rtol=tol)
+    lam_new = ops.dual_from_uplink(up, xs, rho, impl=impl)
+    assert_layout("dual_from_uplink", impl, m, dtype)
+    exp = rho * (f32(up) - sf)
+    np.testing.assert_allclose(f32(lam_new), exp, atol=tol, rtol=tol)
 
     # uplink-only hot-path variant: same uplink, no lam_is output
-    none_lam, up2 = ops.round_tail(
-        spec.pack_stacked(tree), spec.pack_stacked(lam_tree), spec.pack(xs_tree), rho,
-        with_lam_is=False, impl=impl)
+    none_lam, up2 = ops.round_tail(x, lam, xs, rho, with_lam_is=False, impl=impl)
     assert none_lam is None
-    np.testing.assert_allclose(np.asarray(up2, np.float32), np.asarray(up, np.float32),
-                               atol=tol, rtol=tol)
+    np.testing.assert_allclose(f32(up2), f32(up), atol=tol, rtol=tol)
+    if impl == "pallas_interpret":
+        assert_bitwise_xla(lambda i, x, lam, xs: ops.round_tail(
+            x, lam, xs, rho, impl=i), x, lam, xs)
+        assert_bitwise_xla(lambda i, x, lam, xs: ops.round_tail(
+            x, lam, xs, rho, with_lam_is=False, impl=i)[1], x, lam, xs)
+        assert_bitwise_xla(lambda i, u, xs: ops.dual_from_uplink(
+            u, xs, rho, impl=i), up, xs)
 
 
 @pytest.mark.parametrize("impl", IMPLS)
@@ -158,17 +201,50 @@ def test_ef21_parity(impl, bits, dtype):
 
 
 @pytest.mark.parametrize("impl", IMPLS)
-def test_fused_update_arena_parity(impl):
-    m = 4
-    tree = odd_tree(jax.random.key(9), m=m)
-    spec = arena.ArenaSpec.from_tree(tree, stacked=True)
-    x = spec.pack_stacked(tree)
-    g = x * 0.3
-    lam = x * 0.1 + 0.05
-    xs = spec.pack(odd_tree(jax.random.key(10)))
-    out = ops.fused_update_arena(x, g, xs, lam, 0.05, 3.0, impl=impl)
-    exp = np.asarray(x) - 0.05 * (np.asarray(g) + 3.0 * (np.asarray(x) - np.asarray(xs)[None]) + np.asarray(lam))
-    np.testing.assert_allclose(np.asarray(out), exp, atol=1e-5, rtol=1e-5)
+@pytest.mark.parametrize("case", sorted(ARENA_CASES))
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("per_client_step", [False, True])
+@pytest.mark.parametrize("with_lam", [True, False])
+def test_fused_update_arena_parity(impl, case, dtype, per_client_step, with_lam):
+    """Eq. (20) over the arena in all four kernel variants: scalar or
+    per-client step, with the dual term or without (lam=None)."""
+    (x, g, lam), (xs,), step_v = arena_operands(case, dtype, 3, 1, 9)
+    m = x.shape[0]
+    step = step_v if per_client_step else 0.05
+    lam = lam if with_lam else None
+    out = ops.fused_update_arena(x, g, xs, lam, step, 3.0, impl=impl)
+    assert_layout("fused_update_arena", impl, m, dtype)
+    step_e = np.asarray(step_v)[:, None] if per_client_step else 0.05
+    acc = f32(g) + 3.0 * (f32(x) - f32(xs)[None])
+    if with_lam:
+        acc = acc + f32(lam)
+    exp = f32(x) - step_e * acc
+    tol = 1e-5 if dtype == jnp.float32 else 5e-2
+    np.testing.assert_allclose(f32(out), exp, atol=tol, rtol=tol)
+    if impl == "pallas_interpret":
+        assert_bitwise_xla(lambda i, x, g, xs, lam, step: ops.fused_update_arena(
+            x, g, xs, lam, step if per_client_step else 0.05, 3.0, impl=i),
+            x, g, xs, lam, step_v)
+
+
+@pytest.mark.parametrize("impl", IMPLS)
+@pytest.mark.parametrize("case", sorted(ARENA_CASES))
+@pytest.mark.parametrize("per_client_alpha", [False, True])
+def test_scaffold_cv_parity(impl, case, per_client_alpha):
+    """SCAFFOLD's control-variate refresh c_i' = c_i - c + alpha (x_s - x_K)
+    with two server rows, for a scalar and a per-client alpha."""
+    (c_i, x_k), (c_s, xs), step_v = arena_operands(case, jnp.float32, 2, 2, 13)
+    alpha_v = 1.0 / (5 * step_v)
+    alpha = alpha_v if per_client_alpha else 4.0
+    out = ops.scaffold_cv(c_i, x_k, c_s, xs, alpha, impl=impl)
+    assert_layout("scaffold_cv", impl, c_i.shape[0], jnp.float32)
+    alpha_e = np.asarray(alpha_v)[:, None] if per_client_alpha else 4.0
+    exp = f32(c_i) - f32(c_s)[None] + alpha_e * (f32(xs)[None] - f32(x_k))
+    np.testing.assert_allclose(f32(out), exp, atol=1e-5, rtol=1e-5)
+    if impl == "pallas_interpret":
+        assert_bitwise_xla(lambda i, c, x, cs, xs, a: ops.scaffold_cv(
+            c, x, cs, xs, a if per_client_alpha else 4.0, impl=i),
+            c_i, x_k, c_s, xs, alpha_v)
 
 
 @pytest.mark.parametrize("impl", IMPLS)

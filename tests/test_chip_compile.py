@@ -15,6 +15,8 @@ one process at a time may load the TPU library, and the test workers all
 import this file.
 """
 import dataclasses
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +29,9 @@ from repro.kernels import ops
 from repro.models import build as build_model
 
 M = 2  # clients in chip_smoke.py's one-chip run
+# the femnist.full benchmark cell's client arena: 3,550 writers, 48,670
+# softmax parameters packed to 48,768 lanes, f32
+ARENA_M, ARENA_W = 3550, 48768
 SMOKE_LAYERS = 5  # chip_smoke.py's depth cut
 INNER_W = 1024  # the fused K-step kernel keeps (W, W) in VMEM
 GRAPH_W = 2 ** 20  # edge-dual rows of a ring of 8 nodes: 16 rows must fit HBM
@@ -161,6 +166,62 @@ def test_kernel_compiles_for_v5e(sds, width, case):
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text(), (
         f"{case}: no Pallas kernel in the compiled program")
+
+
+def _hlo_shapes(hlo: str) -> dict:
+    """Instruction name -> (opcode, dims) of every array-valued instruction."""
+    return {n: (op, tuple(int(d) for d in dims.split(",") if d))
+            for n, dims, op in re.findall(
+                r"%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(", hlo)}
+
+
+def _operand_ranks(hlo: str, shapes: dict):
+    """Ranks of the operands of the one Pallas call in ``hlo``."""
+    calls = re.findall(r"custom-call\(([^)]*)\), custom_call_target=\"tpu_custom_call\"", hlo)
+    assert len(calls) == 1, calls
+    return [len(shapes[a.strip().lstrip("%")][1]) for a in calls[0].split(",")]
+
+
+ARENA_CASES = {
+    # name: (op, donated arguments, (client, server) operands of the call)
+    "fused_update_arena": (lambda x, g, s, lam: ops.fused_update_arena(
+        x, g, s, lam, 0.1, 1.5, **P), "ccsc", (3, 1)),
+    "fused_update_arena-per_client": (lambda x, g, s, lam, e: ops.fused_update_arena(
+        x, g, s, lam, e, 1.5, **P), "ccscm", None),
+    "round_tail": (lambda x, lam, s: ops.round_tail(
+        x, lam, s, 1.5, with_lam_is=False, **P)[1], "ccs", (2, 1)),
+    "round_tail-lam_is": (lambda x, lam, s: ops.round_tail(
+        x, lam, s, 1.5, **P), "ccs", None),
+    "dual_from_uplink": (lambda u, s: ops.dual_from_uplink(u, s, 1.5, **P), "cs", None),
+    "scaffold_cv-per_client": (lambda c, x, cs, s, a: ops.scaffold_cv(
+        c, x, cs, s, a, **P), "ccssm", None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ARENA_CASES))
+def test_arena_kernels_read_the_arena_in_place(sds, case):
+    """At the femnist.full cell's shape the elementwise arena kernels read
+    the (m, width) buffers as they lie: the compiled program holds no pad
+    and no copy of the client arena's size (the eq. (20) kernel writes its
+    donated x in place), and the Pallas call reads rank-3 ``(1, m, width)``
+    client operands beside rank-2 ``(1, width)`` server rows -- the operand
+    signature by which the benchmark's roofline readers know a kernel."""
+    fn, kinds, signature = ARENA_CASES[case]
+    shape = {"c": ((ARENA_M, ARENA_W), jnp.float32), "s": ((ARENA_W,), jnp.float32),
+             "m": ((ARENA_M,), jnp.float32)}
+    args = [sds(*shape[k]) for k in kinds]
+    # the eq. (20) kernel writes over x, which the inner loop donates
+    donate = 0 if case.startswith("fused_update_arena") else ()
+    hlo = jax.jit(fn, donate_argnums=donate).lower(*args).compile().as_text()
+    shapes = _hlo_shapes(hlo)
+    big = [(n, op, dims) for n, (op, dims) in shapes.items()
+           if op in ("pad", "copy") and math.prod(dims) >= ARENA_M * ARENA_W]
+    assert not big, big
+    ranks = _operand_ranks(hlo, shapes)
+    assert ranks[:kinds.index("s")] == [3] * kinds.index("s"), ranks
+    if signature is not None:
+        assert (ranks.count(3), ranks.count(2)) == signature, ranks
+    assert ops.LAYOUT[case.split("-")[0]][0] == "flat"
 
 
 def test_platform_selects_the_implementation(monkeypatch):
