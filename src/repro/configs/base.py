@@ -475,13 +475,20 @@ class ArchConfig:
     microbatch: Optional[int] = None  # split the per-client batch into this
     #   many grad-accumulation chunks inside each inner step (activation
     #   memory / microbatch, same FLOPs; see EXPERIMENTS.md SSPerf)
-    dtype: str = "bfloat16"
+    dtype: str = "bfloat16"  # compute: activations and matmul operands
+    state_dtype: Optional[str] = None  # stored weights, the federated state;
+    #   None -> dtype.  Set apart from dtype for mixed precision: the forward
+    #   casts the stored weights to dtype once, at entry (models/model.py)
     source: str = ""  # citation
 
     # ------------------------------------------------------------------
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim if self.head_dim is not None else self.d_model // self.n_heads
+
+    @property
+    def resolved_state_dtype(self) -> str:
+        return self.state_dtype or self.dtype
 
     @property
     def pattern_len(self) -> int:

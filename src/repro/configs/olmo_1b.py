@@ -1,7 +1,10 @@
 """olmo-1b -- dense, non-parametric LayerNorm [arXiv:2402.00838].
 
 16L d_model=2048 16H (kv=16) d_ff=8192 vocab=50304.  OLMo uses non-parametric
-LayerNorm (no scale/bias) and tied embeddings.
+LayerNorm (no scale/bias) and tied embeddings.  It trains in bf16 mixed
+precision (the distributed-training part of its section 3): weights and
+optimiser state stored in float32, forward and backward computed in
+bfloat16.
 """
 from repro.configs.base import ArchConfig, FederatedConfig
 
@@ -21,6 +24,8 @@ CONFIG = ArchConfig(
     act="silu",
     subquadratic=False,  # long_500k skipped (full attention; see DESIGN.md)
     fed=FederatedConfig(algorithm="gpdmm", layout="client_axis"),
+    dtype="bfloat16",
+    state_dtype="float32",
     microbatch=4,  # grad-accum chunks per inner step (activation memory)
     source="arXiv:2402.00838 (OLMo)",
 )

@@ -127,6 +127,47 @@ def arena_grad(grad_fn, spec):
     return ga, False
 
 
+def step_by_client(spec, grad_fn, update, x, batch, rows, shared, vr=None):
+    """One inner step of every client, a client at a time: client i's
+    gradient is taken at its row of the ``(m, width)`` arena ``x``, packed,
+    and applied at once by ``update(x, g_i, i, *rows, *shared)``, which
+    returns ``x`` with row i stepped.  One client's gradient row is live at
+    a time.  ``rows`` lead with the client dim (the duals, per-client
+    stepsizes), ``shared`` are replicated (the server row); under a
+    client-sharded mesh each device steps its own clients (``per_client``).
+    ``vr = (snapshot, gbar)``, both ``(m, width)``: SVRG, client i's
+    gradient corrected by ``gbar_i - grad f_i(snapshot_i)`` on the same
+    batch."""
+    b_leaves, b_def = jax.tree.flatten(batch)
+    nb = len(b_leaves)
+    snap, gbar = vr if vr is not None else (None, None)
+    row = lambda a, i: jax.lax.dynamic_index_in_dim(a, i, keepdims=False)  # noqa: E731
+
+    def local(x, snap, gbar, *args):
+        b = jax.tree.unflatten(b_def, args[:nb])
+
+        def body(i, x):
+            bi = jax.tree.map(lambda t: row(t, i), b)
+            with jax.named_scope("round.client_grad"):
+                with jax.named_scope("arena_pack"):
+                    params = spec.unpack(row(x, i))
+                g = grad_fn(params, bi)
+                if snap is not None:
+                    with jax.named_scope("arena_pack"):
+                        g_snap = grad_fn(spec.unpack(row(snap, i)), bi)
+                    g = jax.tree.map(jnp.subtract, g, g_snap)
+                with jax.named_scope("arena_pack"):
+                    g = spec.pack(g)
+                if gbar is not None:
+                    g = g + row(gbar, i)
+            with jax.named_scope("round.client_update"):
+                return update(x, g, i, *args[nb:])
+
+        return jax.lax.fori_loop(0, x.shape[0], body, x)
+
+    return per_client(local, (x, snap, gbar, *b_leaves, *rows), shared)
+
+
 def map_clients(fn, x, batch):
     """``fn(x_i, batch_i)`` stacked over the leading client dim of ``x`` and
     ``batch`` (pytrees), one client at a time: a ``lax.map``, run by each

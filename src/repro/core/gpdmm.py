@@ -34,7 +34,7 @@ from repro.core import arena, faults, staleness
 from repro.core import tree_util as T
 from repro.core.api import (
     FedOpt, affine_case, arena_grad, cohort_batch, map_clients, resolved_rho,
-    run_cohort_inner, use_arena, use_cohort,
+    run_cohort_inner, step_by_client, use_arena, use_cohort,
 )
 from repro.kernels import ops
 
@@ -122,8 +122,11 @@ def inner_steps_arena(spec, grad_fn, x0, x_s_row, lam, batch, *, K, eta, rho,
       2. ``grad_fn.grad_arena``: one fused-update kernel per step with the
          gradient evaluated directly on the packed buffer -- 0 boundary
          passes.
-      3. plain ``grad_fn``: same scan, paying the unpack->vgrad->pack
-         round trip through the model's pytree each step.
+      3. plain ``grad_fn``: same scan, paying the unpack->grad->pack round
+         trip through the model's pytree each step, one client at a time
+         (``step_by_client``): each client's row is stepped as soon as its
+         gradient is made (``ops.fused_update_client``), so no ``(m,
+         width)`` gradient is stacked.
 
     ``eta`` may be a scalar, the per-client tuple (auto-eta), or an
     already-gathered per-cohort row -- array forms ride the kernels as a
@@ -138,24 +141,35 @@ def inner_steps_arena(spec, grad_fn, x0, x_s_row, lam, batch, *, K, eta, rho,
             H, c = affine(spec, batch)
             return ops.inner_loop_affine(x0, H, c, x_s_row, lam, step_c, rho, K)
 
-        grad_a, _native = arena_grad(grad_fn, spec)
+        grad_a, native = arena_grad(grad_fn, spec)
 
-        gbar = None
+        vr = None
         if vr_snapshot is not None:
             assert per_step, "SVRG needs per-step minibatches (K, m, ...)"
             with jax.named_scope("round.client_grad"):
                 snap_grads = jax.lax.map(lambda b: grad_a(vr_snapshot, b), batch)
-                gbar = jnp.mean(snap_grads, axis=0)
+                vr = (vr_snapshot, jnp.mean(snap_grads, axis=0))
+        # a per-client stepsize rides with the duals as a client row
+        step_rows = () if np.ndim(step_c) == 0 else (jnp.asarray(step_c, jnp.float32),)
+
+        def update(x, g, i, lam, *rest):
+            *st, x_s_row = rest
+            return ops.fused_update_client(x, g, x_s_row, lam, i,
+                                           st[0] if st else step_c, rho)
 
         def one_step(carry, xs_k):
             x, xsum = carry
             b = xs_k if per_step else batch
-            with jax.named_scope("round.client_grad"):
-                g = grad_a(x, b)
-                if gbar is not None:
-                    g = g - grad_a(vr_snapshot, b) + gbar
-            with jax.named_scope("round.client_update"):
-                x_new = ops.fused_update_arena(x, g, x_s_row, lam, step_c, rho)
+            if native:
+                with jax.named_scope("round.client_grad"):
+                    g = grad_a(x, b)
+                    if vr is not None:
+                        g = g - grad_a(vr[0], b) + vr[1]
+                with jax.named_scope("round.client_update"):
+                    x_new = ops.fused_update_arena(x, g, x_s_row, lam, step_c, rho)
+            else:
+                x_new = step_by_client(spec, grad_fn, update, x, b,
+                                       (lam, *step_rows), (x_s_row,), vr=vr)
             return (x_new, xsum + x_new), None
 
         init = (x0, jnp.zeros_like(x0))

@@ -44,9 +44,8 @@ def _step_arr(step):
 
 # op name -> implementation it resolved to when last traced
 RESOLVED: dict[str, str] = {}
-# elementwise arena kernel -> the block layout its Pallas call took at its
-# last trace: ("flat", bm, bw) over the (m, width) arena as it lies, or
-# ("tiled", rows) over (m, rows_p, 128) tiles (``round_tail._Blocks``)
+# elementwise arena kernel -> the (bm, bw) blocks its Pallas call read the
+# (m, width) arena in at its last trace (``round_tail._flat_blocks``)
 LAYOUT: dict[str, tuple] = {}
 
 
@@ -347,6 +346,30 @@ def fused_update_arena(x, g, x_s, lam, step, rho, *, impl: Optional[str] = None,
             x, g, x_s, lam, step if step_a is None else step_a, rho,
             block=block, interpret=(impl == "pallas_interpret")),
         (x, g, lam, step_a), (x_s,))
+
+
+@_scoped
+def fused_update_client(x, g, x_s, lam, i, step, rho, *,
+                        impl: Optional[str] = None):
+    """Eq. (20) on row ``i`` of the arena alone: x, lam (m, width); g
+    (width,) client i's gradient; x_s (width,) the server row; ``i`` a
+    traced client index.  ``step``: scalar, or (m,) per-client stepsizes
+    of which row i's is taken.  Returns x with row i stepped and the other
+    rows as they were, written in place.  The caller runs it on its own
+    client rows (``core.api.step_by_client`` does so under a client-sharded
+    mesh)."""
+    impl = _resolve(impl, "fused_update_client")
+    step_a = _step_arr(step)
+    if impl == "xla":
+        row = lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False)  # noqa: E731
+        new = _ref.fused_update_ref(row(x), g, x_s, row(lam),
+                                    step if step_a is None else row(step_a), rho)
+        return jax.lax.dynamic_update_index_in_dim(x, new, i, 0)
+    from repro.kernels import round_tail as rt
+
+    return rt.fused_update_client_pallas(
+        x, g, x_s, lam, i, step if step_a is None else step_a, rho,
+        interpret=(impl == "pallas_interpret"))
 
 
 @_scoped

@@ -21,8 +21,10 @@ HBM.  On the arena the same math becomes three fused kernels:
 
 The elementwise kernels (eq. (20), the uplink, the dual refresh, SCAFFOLD's
 control variate) read the ``(m, width)`` arena as it lies, in ``(bm, bw)``
-blocks (``_Blocks``), once there are at least a sublane tile of clients.
-With fewer, and in the EF21 kernels always, a client row is tiled as
+blocks (``_Blocks``); fewer clients than a sublane tile make one block of
+all m rows.  ``fused_update_client_pallas`` steps one client's row of the
+arena in place, for rounds that apply each client's gradient as soon as it
+is made.  In the EF21 kernels a client row is tiled as
 ``(rows = width // 128, 128)`` and padded to whole blocks of rows; the
 arena pads every leaf to a 128-lane multiple, so tiles never straddle
 leaves and the EF21 per-(client, leaf) quantisation scale is a static
@@ -39,6 +41,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.fused_update import (
     BLOCK_ROWS, LANES, RELAYOUT, assert_vmem_budget, ceil_to as _ceil_to, eq20,
@@ -105,78 +108,61 @@ FLAT_BLOCK_BYTES = 1024 * 1024
 
 def _flat_blocks(m: int, w: int, dtype, n_arrays: int, block=None):
     """``(bm, bw)`` blocks of the client arena as it lies, ``(m, w)`` seen as
-    ``(1, m, w)``; None where m is below the dtype's sublane tile (such
-    blocks would leave most sublanes empty, so those shapes take the tiled
-    path).  ``block``: client rows per block (default ``FLAT_BLOCK_ROWS``),
-    rounded up to the sublane tile and cut to the whole tiles m holds.
-    ``bw`` splits the width evenly into 128-lane multiples of about
-    ``FLAT_BLOCK_BYTES`` of f32 per block."""
+    ``(1, m, w)``.  ``block``: client rows per block (default
+    ``FLAT_BLOCK_ROWS``), rounded up to the sublane tile and cut to the
+    whole tiles m holds; fewer clients than a tile make one block of all m
+    rows (a block dim equal to the array's is legal at any size).  ``bw``
+    splits the width evenly into 128-lane multiples of about
+    ``FLAT_BLOCK_BYTES`` of f32 per block, its rows counted as the VMEM
+    tile pads them."""
     sub = _sublanes(dtype)
-    if m < sub:
-        return None
-    bm = min(_ceil_to(block or FLAT_BLOCK_ROWS, sub), m // sub * sub)
-    n_w = pl.cdiv(w, max(LANES, FLAT_BLOCK_BYTES // (bm * 4)))
+    bm = m if m < sub else min(_ceil_to(block or FLAT_BLOCK_ROWS, sub), m // sub * sub)
+    rows = _ceil_to(bm, 8)
+    n_w = pl.cdiv(w, max(LANES, FLAT_BLOCK_BYTES // (rows * 4)))
     bw = _ceil_to(pl.cdiv(w, n_w), LANES)
-    assert_vmem_budget(n_arrays, bm * bw // LANES)
+    assert_vmem_budget(n_arrays, rows * bw // LANES)
     return bm, bw
 
 
 class _Blocks:
     """Operand layout of one elementwise arena kernel: ``(m, width)`` client
     buffers, ``(width,)`` server rows and ``(m,)`` per-client scalars in,
-    client buffers out.
-
-    Flat (m at least the sublane tile): client buffers enter as ``x[None]``,
-    ``(1, m, width)``, a bitcast, in ``(1, bm, bw)`` blocks, and server rows
-    as ``(1, width)`` in ``(1, bw)`` blocks; the grid runs clients innermost,
+    client buffers out.  Client buffers enter as ``x[None]``, ``(1, m,
+    width)``, a bitcast, in ``(1, bm, bw)`` blocks, and server rows as
+    ``(1, width)`` in ``(1, bw)`` blocks; the grid runs clients innermost,
     so a server block stays resident across consecutive steps, and Pallas
-    masks the ragged edge blocks.  Tiled (fewer clients): every operand is
-    reshaped to ``(..., rows_p, LANES)`` and row-padded to ``block`` rows
-    (``_tile``), the output sliced back (``_untile``).  ``ops.LAYOUT`` records
-    which one each kernel took at its last trace."""
+    masks the ragged edge blocks.  ``ops.LAYOUT`` records each kernel's
+    ``(bm, bw)`` at its last trace."""
 
     def __init__(self, name, m, w, dtype, n_arrays, block):
         assert w % LANES == 0, f"arena width {w} not a multiple of {LANES}"
-        self.m, self.w = m, w
-        fb = _flat_blocks(m, w, dtype, n_arrays, block)
-        if fb is None:
-            br = self.br = _resolve_block(block, w // LANES)
-            assert_vmem_budget(n_arrays, br)
-            rows_p = _ceil_to(w // LANES, br)
-            self.grid = (m, rows_p // br)
-            self.client = pl.BlockSpec((1, br, LANES), lambda i, j: (i, j, 0))
-            self.server = pl.BlockSpec((br, LANES), lambda i, j: (j, 0))
-            self.row = CLIENT_ROW_BS
-            self.out_lead = (m, rows_p, LANES)
-            LAYOUT[name] = ("tiled", br)
-        else:
-            self.br = None
-            bm, bw = fb
-            self.grid = (pl.cdiv(w, bw), pl.cdiv(m, bm))
-            self.client = pl.BlockSpec((1, bm, bw), lambda j, i: (0, i, j))
-            self.server = pl.BlockSpec((1, bw), lambda j, i: (0, j))
-            self.row = pl.BlockSpec((1, bm, LANES), lambda j, i: (0, i, 0))
-            self.out_lead = (1, m, w)
-            LAYOUT[name] = ("flat", bm, bw)
+        self.m = m
+        bm, bw = _flat_blocks(m, w, dtype, n_arrays, block)
+        self.grid = (pl.cdiv(w, bw), pl.cdiv(m, bm))
+        self.client = pl.BlockSpec((1, bm, bw), lambda j, i: (0, i, j))
+        self.server = pl.BlockSpec((1, bw), lambda j, i: (0, j))
+        self.row = pl.BlockSpec((1, bm, LANES), lambda j, i: (0, i, 0))
+        self.out_lead = (1, m, w)
+        LAYOUT[name] = (bm, bw)
 
-    def clients(self, a):
-        return a[None] if self.br is None else _tile(a, self.br)[0]
+    @staticmethod
+    def clients(a):
+        return a[None]
 
     servers = clients
 
     def rows(self, v):
         """(m,) per-client scalars as f32 rows the kernel reads as
         ``ref[0][:, :1]``: one value per client row of its block."""
-        if self.br is not None:
-            return client_row(v)
         with jax.named_scope(RELAYOUT):
             return jnp.broadcast_to(v.astype(jnp.float32)[None, :, None], (1, self.m, LANES))
 
     def out_shape(self, dtype):
         return jax.ShapeDtypeStruct(self.out_lead, dtype)
 
-    def back(self, out):
-        return out[0] if self.br is None else _untile(out, self.w, (self.m,))
+    @staticmethod
+    def back(out):
+        return out[0]
 
 
 # ---------------------------------------------------------------------------
@@ -479,3 +465,59 @@ def fused_update_arena_pallas(x, g, x_s, lam, step, rho, *, block=None, interpre
         interpret=interpret,
     )(*args)
     return lay.back(out)
+
+
+def _update_client_kernel(i_ref, x_ref, g_ref, xs_ref, lam_ref, *refs, bm: int,
+                          step, rho: float):
+    # refs: (out,), or (per-client steps, out) with one value per client row
+    # of the block (core.autotune)
+    *step_ref, o_ref = refs
+    f32 = jnp.float32
+    x = x_ref[0].astype(f32)
+    out = eq20(x, g_ref[0].astype(f32), xs_ref[...].astype(f32),
+               lam_ref[0].astype(f32), step_ref[0][0][:, :1] if step_ref else step, rho)
+    # the block's client rows start at the block of rows that holds client i
+    row = i_ref[0] // bm * bm + jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    o_ref[0] = jnp.where(row == i_ref[0], out, x).astype(o_ref.dtype)
+
+
+def fused_update_client_pallas(x, g, x_s, lam, i, step, rho, *, interpret: bool = False):
+    """Eq. (20) on client ``i``'s row alone: x, lam (m, width); g (width,)
+    client i's gradient; x_s (width,) server row; i a traced int32.
+    ``step``: scalar (baked) or (m,) per-client stepsizes riding a row
+    operand.  Writes x in place (donate it or XLA copies it); the other
+    rows pass through.  The client index rides a scalar-prefetch operand,
+    and each grid step reads the ``(1, bm, bw)`` block of rows that holds
+    client i: all m rows below a sublane tile, else the tile.  So at m of
+    a sublane tile or more a call moves a whole tile of x and lam for one
+    client's row."""
+    m, w = x.shape
+    bm, bw = _flat_blocks(m, w, x.dtype, 5, _sublanes(x.dtype))
+    LAYOUT["fused_update_client"] = (bm, bw)
+    client = pl.BlockSpec((1, bm, bw), lambda j, i: (0, i[0] // bm, j))
+    in_specs = [client, pl.BlockSpec((1, 1, bw), lambda j, i: (0, 0, j)),
+                pl.BlockSpec((1, bw), lambda j, i: (0, j)), client]
+    args = [x[None], g[None, None], x_s[None], lam[None]]
+    if jnp.ndim(step) > 0:
+        assert step.shape == (m,), step.shape
+        in_specs.append(pl.BlockSpec((1, bm, LANES), lambda j, i: (0, i[0] // bm, 0)))
+        with jax.named_scope(RELAYOUT):
+            args.append(jnp.broadcast_to(step.astype(jnp.float32)[None, :, None],
+                                         (1, m, LANES)))
+        step = None
+    else:
+        step = float(step)
+    out = pl.pallas_call(
+        functools.partial(_update_client_kernel, bm=bm, step=step, rho=float(rho)),
+        name="fused_update_client",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(pl.cdiv(w, bw),),
+            in_specs=in_specs,
+            out_specs=client,
+        ),
+        out_shape=jax.ShapeDtypeStruct((1, m, w), x.dtype),
+        input_output_aliases={1: 0},
+        interpret=interpret,
+    )(jnp.reshape(i, (1,)).astype(jnp.int32), *args)
+    return out[0]

@@ -38,6 +38,7 @@ from repro import telemetry as tel
 from repro.configs import get_arch
 from repro.launch import compile_cache
 from repro.models import build as build_model
+from repro.models.model import compute_params
 
 
 def load_with_retry(ckpt_dir: str, step: int, *, retries: int = 3,
@@ -139,7 +140,9 @@ def run(arch: str, *, reduced: bool = True, batch: int = 4, prompt_len: int = 64
         cfg = cfg.reduced()
     model = build_model(cfg)
     key = jax.random.key(seed)
-    params = model.init(key)
+    # weights stored in a state dtype apart from the compute dtype are cast
+    # once here, not at every prefill and decode step
+    params = compute_params(cfg, model.init(key))
 
     if cfg.n_codebooks > 1:
         prompts = jax.random.randint(key, (batch, cfg.n_codebooks, prompt_len), 0, cfg.vocab_size)
@@ -239,7 +242,7 @@ def run_watch(arch: str, *, ckpt_dir: str, reduced: bool = True,
                 f"{wait_first:.0f}s")
         time.sleep(poll_interval)
         payload = watcher.poll()
-    params = payload["server"]
+    params = compute_params(cfg, payload["server"])
     print(f"[serve] serving step {watcher.step} "
           f"(round {int(payload['round'])}) from {ckpt_dir}", flush=True)
 
@@ -286,7 +289,7 @@ def run_watch(arch: str, *, ckpt_dir: str, reduced: bool = True,
             fresh = watcher.poll()
         if fresh is not None:
             swap_s = time.perf_counter() - t_poll
-            payload, params = fresh, fresh["server"]
+            payload, params = fresh, compute_params(cfg, fresh["server"])
             registry.histogram("serve/swap_latency_s").observe(swap_s)
             tracer.instant("serve/swap", {"step": watcher.step,
                                           "round": int(payload["round"]),

@@ -29,7 +29,7 @@ from repro.sharding.constraints import constrain
 # ---------------------------------------------------------------------------
 
 def model_init(key, cfg: ArchConfig):
-    dtype = L._dtype(cfg.dtype)
+    dtype = L._dtype(cfg.resolved_state_dtype)
     ks = jax.random.split(key, 5)
     p: dict[str, Any] = {}
     sp: dict[str, Any] = {}
@@ -66,13 +66,18 @@ def model_init(key, cfg: ArchConfig):
 # ---------------------------------------------------------------------------
 
 def _embed(cfg: ArchConfig, params, tokens, patches=None):
+    # the rows a lookup gathers are cast to the compute dtype, not the table:
+    # the lookup's gradient, a scatter-add over every position of a token,
+    # then accumulates in the state dtype (in bfloat16 it lost 2 % of OLMo's
+    # embedding gradient over 2048 Zipf tokens)
+    dtype = L._dtype(cfg.dtype)
     if cfg.n_codebooks > 1:
         # tokens (B, K, S): summed codebook embeddings
         x = 0.0
         for kb in range(cfg.n_codebooks):
-            x = x + jnp.take(params["embed"]["w"][kb], tokens[:, kb], axis=0)
+            x = x + jnp.take(params["embed"]["w"][kb], tokens[:, kb], axis=0).astype(dtype)
         return x
-    x = L.embed_apply(params["embed"], tokens)
+    x = L.embed_apply(params["embed"], tokens).astype(dtype)
     if cfg.frontend == "vision" and patches is not None:
         pj = params["projector"]
         pre = jax.nn.gelu(patches.astype(x.dtype) @ pj["w1"]) @ pj["w2"]
@@ -97,10 +102,25 @@ def _head(cfg: ArchConfig, params, x):
 # forward / loss
 # ---------------------------------------------------------------------------
 
+def compute_params(cfg: ArchConfig, params):
+    """The stored weights cast to the compute dtype, where the two differ
+    (``cfg.state_dtype``).  The forward pass does this once, at entry, so
+    ``jax.grad`` transposes the cast and returns the gradient in the state
+    dtype.  The embedding's lookups read the stored table (``_embed``)."""
+    state, compute = L._dtype(cfg.resolved_state_dtype), L._dtype(cfg.dtype)
+    if state == compute:
+        return params
+    with jax.named_scope("model.param_cast"):
+        return jax.tree.map(lambda a: a.astype(compute) if a.dtype == state else a,
+                            params)
+
+
 def forward(cfg: ArchConfig, params, batch, *, mode="train", cache=None, pos=None,
             cache_cap: int = 0, window_override: Optional[int] = None,
             exact_moe: bool = False):
-    x = _embed(cfg, params, batch["tokens"], batch.get("patches"))
+    stored, params = params, compute_params(cfg, params)
+    x = _embed(cfg, dict(params, embed=stored["embed"]), batch["tokens"],
+               batch.get("patches"))
     x, new_cache, aux = S.stack_apply(
         cfg, params["stack"], x, mode=mode, cache=cache, pos=pos,
         cache_cap=cache_cap, window_override=window_override, exact_moe=exact_moe,

@@ -104,9 +104,9 @@ def test_leaf_view_matches_leaf():
 # ---------------------------------------------------------------------------
 
 # (m, width) of the elementwise arena kernels' parity cases.  "odd": the
-# packed odd-size tree, m = 5 below every sublane tile (the tiled path);
-# "ragged": m = 37 clients in blocks of 32 rows, and the width of 131 lane
-# rows in three 5632-lane blocks, the last of each ragged (the flat path)
+# packed odd-size tree, m = 5 below every sublane tile (one block of all
+# rows); "ragged": m = 37 clients in blocks of 32 rows, and the width of 131
+# lane rows in three 5632-lane blocks, the last of each ragged
 ARENA_CASES = {"odd": (5, None), "ragged": (37, 131 * 128)}
 
 
@@ -128,11 +128,13 @@ def f32(a):
 
 
 def assert_layout(op, impl, m, dtype):
-    """The Pallas call took the flat path iff m reaches the dtype's
-    sublane tile (8 rows of 32-bit values, 16 of 16-bit)."""
+    """The Pallas call read the arena in blocks of whole sublane tiles of
+    clients (8 rows of 32-bit values, 16 of 16-bit), or in one block of all
+    m rows where m is below a tile."""
     if impl == "pallas_interpret":
-        want = "flat" if m >= 32 // jnp.dtype(dtype).itemsize else "tiled"
-        assert ops.LAYOUT[op][0] == want, ops.LAYOUT[op]
+        sub = 32 // jnp.dtype(dtype).itemsize
+        bm = ops.LAYOUT[op][0]
+        assert bm == m if m < sub else bm % sub == 0, ops.LAYOUT[op]
 
 
 def assert_bitwise_xla(fn, *args):
@@ -229,6 +231,47 @@ def test_fused_update_arena_parity(impl, case, dtype, per_client_step, with_lam)
 
 @pytest.mark.parametrize("impl", IMPLS)
 @pytest.mark.parametrize("case", sorted(ARENA_CASES))
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_fused_update_client_parity(impl, case, dtype):
+    """Eq. (20) on one client's row of the arena: that row stepped, every
+    other row bitwise as it was, for the first, a middle and the last
+    client (blocks of all m rows below a sublane tile, else of the tile
+    that holds the client)."""
+    (x, lam), (g, xs), _ = arena_operands(case, dtype, 2, 2, 11)
+    m = x.shape[0]
+    for i in sorted({0, m // 2, m - 1}):
+        out = ops.fused_update_client(x, g, xs, lam, jnp.int32(i), 0.05, 3.0, impl=impl)
+        exp = f32(x[i]) - 0.05 * (f32(g) + 3.0 * (f32(x[i]) - f32(xs)) + f32(lam[i]))
+        tol = 1e-5 if dtype == jnp.float32 else 5e-2
+        np.testing.assert_allclose(f32(out[i]), exp, atol=tol, rtol=tol)
+        rest = np.arange(m) != i
+        np.testing.assert_array_equal(f32(out)[rest], f32(x)[rest])
+        if impl == "pallas_interpret":
+            assert_bitwise_xla(lambda im, x, g, xs, lam: ops.fused_update_client(
+                x, g, xs, lam, jnp.int32(i), 0.05, 3.0, impl=im), x, g, xs, lam)
+
+
+@pytest.mark.parametrize("impl", IMPLS)
+@pytest.mark.parametrize("case", sorted(ARENA_CASES))
+def test_fused_update_client_per_client_step(impl, case):
+    """As above with (m,) per-client stepsizes (``core.autotune``): row i
+    takes its own step, every other row stays bitwise as it was."""
+    (x, lam), (g, xs), step_v = arena_operands(case, jnp.float32, 2, 2, 17)
+    m = x.shape[0]
+    for i in sorted({0, m // 2, m - 1}):
+        out = ops.fused_update_client(x, g, xs, lam, jnp.int32(i), step_v, 3.0, impl=impl)
+        exp = f32(x[i]) - f32(step_v[i]) * (
+            f32(g) + 3.0 * (f32(x[i]) - f32(xs)) + f32(lam[i]))
+        np.testing.assert_allclose(f32(out[i]), exp, atol=1e-5, rtol=1e-5)
+        rest = np.arange(m) != i
+        np.testing.assert_array_equal(f32(out)[rest], f32(x)[rest])
+        if impl == "pallas_interpret":
+            assert_bitwise_xla(lambda im, x, g, xs, lam, st: ops.fused_update_client(
+                x, g, xs, lam, jnp.int32(i), st, 3.0, impl=im), x, g, xs, lam, step_v)
+
+
+@pytest.mark.parametrize("impl", IMPLS)
+@pytest.mark.parametrize("case", sorted(ARENA_CASES))
 @pytest.mark.parametrize("per_client_alpha", [False, True])
 def test_scaffold_cv_parity(impl, case, per_client_alpha):
     """SCAFFOLD's control-variate refresh c_i' = c_i - c + alpha (x_s - x_K)
@@ -317,6 +360,37 @@ def test_round_parity_arena_vs_pytree(prob, algo, variant):
             continue
         np.testing.assert_allclose(float(ma[km]), float(mp[km]), atol=1e-4,
                                    err_msg=f"{algo}/{variant}: metrics[{km}]")
+
+
+@pytest.mark.parametrize("impl", IMPLS)
+@pytest.mark.parametrize("algo", ["gpdmm", "agpdmm"])
+def test_round_parity_by_client(prob, algo, impl, monkeypatch):
+    """A plain gradient oracle (no arena attributes) steps the arena a
+    client at a time (``core.api.step_by_client``): the rounds match the
+    pytree path's, with the kernels in XLA and in Pallas."""
+    monkeypatch.setattr(ops, "default_impl", lambda: impl)
+    kw = dict(algorithm=algo, inner_steps=3, eta=0.5 / prob.L)
+    x0 = jnp.zeros((prob.d,))
+    batch = prob.batch()
+    states = {}
+    for use_arena in [True, False]:
+        opt = make(FederatedConfig(use_arena=use_arena, **kw))
+        s = opt.init(x0, prob.m)
+        for _ in range(3):
+            s, _ = opt.round(s, lambda x, b: prob.grad(x, b), batch)
+        states[use_arena] = s
+    sa, sp = states[True], states[False]
+    assert ops.RESOLVED["fused_update_client"] == impl
+    assert set(sa) == set(sp)
+    spec = arena.ArenaSpec.from_tree(sp["x_s"])
+    # the duals rho (u - x_s) carry the float32 rounding of the states (a
+    # few 1e-7 at their size, about 2) times rho (165 here)
+    rho = 1.0 / (kw["inner_steps"] * kw["eta"])
+    for k in sorted(set(sa) - {"round"}):
+        want = sp[k] if k == "x_s" else spec.pack_stacked(sp[k])
+        atol = 1e-6 * rho if k.startswith("lam") else 1e-5
+        np.testing.assert_allclose(np.asarray(sa[k]), np.asarray(want),
+                                   atol=atol, rtol=1e-5, err_msg=k)
 
 
 @pytest.mark.parametrize("init", ["z", "xs"])

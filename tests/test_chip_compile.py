@@ -6,7 +6,7 @@ refuse -- blocks not aligned to the (8, 128) tiling, kernels past the VMEM
 budget -- which interpret-mode tests cannot see.  Each case passes
 ``impl="pallas"`` and asserts the compiled program holds the Pallas custom
 call, at real widths: the packed arena width of ``chip_smoke.py``'s model
-(OLMo-1B at its published widths, 5 layers) for the per-client kernels,
+(OLMo-1B at its published widths, 4 layers) for the per-client kernels,
 OLMo-1B's head dims for attention, and 2^20-wide rows on a ring of 8 nodes
 for the graph kernels.
 
@@ -32,7 +32,7 @@ M = 2  # clients in chip_smoke.py's one-chip run
 # the femnist.full benchmark cell's client arena: 3,550 writers, 48,670
 # softmax parameters packed to 48,768 lanes, f32
 ARENA_M, ARENA_W = 3550, 48768
-SMOKE_LAYERS = 5  # chip_smoke.py's depth cut
+SMOKE_LAYERS = 4  # chip_smoke.py's and the olmo1b.silo2 cell's depth cut
 INNER_W = 1024  # the fused K-step kernel keeps (W, W) in VMEM
 GRAPH_W = 2 ** 20  # edge-dual rows of a ring of 8 nodes: 16 rows must fit HBM
 P = dict(impl="pallas")
@@ -127,6 +127,21 @@ def _flash_attention(S):
 CASES = {
     "fused_update_arena-scalar": lambda S, W: _fused_update(S, W, False),
     "fused_update_arena-per_client": lambda S, W: _fused_update(S, W, True),
+    "fused_update_client": lambda S, W: (
+        lambda x, g, s, lam, i: ops.fused_update_client(x, g, s, lam, i, 0.1, 1.5, **P),
+        (S((M, W)), S((W,)), S((W,)), S((M, W)), S((), jnp.int32))),
+    "fused_update_client-f32": lambda S, W: (
+        lambda x, g, s, lam, i: ops.fused_update_client(x, g, s, lam, i, 0.1, 1.5, **P),
+        (S((M, W), jnp.float32), S((W,), jnp.float32), S((W,), jnp.float32),
+         S((M, W), jnp.float32), S((), jnp.int32))),
+    "fused_update_client-per_client": lambda S, W: (
+        lambda x, g, s, lam, i, st: ops.fused_update_client(x, g, s, lam, i, st, 1.5, **P),
+        (S((M, W), jnp.float32), S((W,), jnp.float32), S((W,), jnp.float32),
+         S((M, W), jnp.float32), S((), jnp.int32), S((M,), jnp.float32))),
+    "fused_update_client-tile": lambda S, W: (
+        lambda x, g, s, lam, i: ops.fused_update_client(x, g, s, lam, i, 0.1, 1.5, **P),
+        (S((3 * 8, ARENA_W), jnp.float32), S((ARENA_W,), jnp.float32),
+         S((ARENA_W,), jnp.float32), S((3 * 8, ARENA_W), jnp.float32), S((), jnp.int32))),
     "round_tail": lambda S, W: _round_tail(S, W, False),
     "round_tail-lam_is": lambda S, W: _round_tail(S, W, True),
     "dual_from_uplink": lambda S, W: (
@@ -221,7 +236,41 @@ def test_arena_kernels_read_the_arena_in_place(sds, case):
     assert ranks[:kinds.index("s")] == [3] * kinds.index("s"), ranks
     if signature is not None:
         assert (ranks.count(3), ranks.count(2)) == signature, ranks
-    assert ops.LAYOUT[case.split("-")[0]][0] == "flat"
+    assert ops.LAYOUT[case.split("-")[0]][0] % 8 == 0
+
+
+def test_silo_round_fits_one_chip(topo, monkeypatch):
+    """The olmo1b.silo2 round compiles for one v5e: OLMo-1B at its
+    published widths and 4 layers, float32 state and bfloat16 compute, two
+    silos, one 2048-token sequence each.  Its five float32 arena rows (x_s,
+    two x_c, two lam) leave the round about 8 GB, which holds because a
+    plain gradient steps the arena a client at a time and the kernels read
+    the arena in place: no copy or pad of the client arena's size."""
+    from repro.configs.base import FederatedConfig
+    from repro.core import make
+
+    monkeypatch.setattr(ops, "default_impl", lambda: "pallas")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    on_chip = lambda t: jax.tree.map(  # noqa: E731
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), t)
+    model = build_model(dataclasses.replace(get_arch("olmo-1b"), n_layers=SMOKE_LAYERS))
+    fed = make(FederatedConfig(algorithm="gpdmm", inner_steps=2, eta=0.05,
+                               num_clients=M, layout="client_axis"))
+    params = jax.eval_shape(model.init, jax.random.key(0))
+    state = on_chip(jax.eval_shape(lambda p: fed.init(p, M), params))
+    toks = jax.ShapeDtypeStruct((M, 1, 2048), jnp.int32, sharding=one_chip)
+
+    def one_round(s, b):
+        return fed.round(s, lambda p, bi: jax.grad(lambda q: model.loss(q, bi)[0])(p), b)
+
+    compiled = jax.jit(one_round, donate_argnums=0).lower(
+        state, {"tokens": toks, "targets": toks}).compile()
+    width = arena.ArenaSpec.from_tree(params).width
+    assert state["x_c"].shape == (M, width) and state["x_c"].dtype == jnp.float32
+    big = [(n, op, dims) for n, (op, dims) in _hlo_shapes(compiled.as_text()).items()
+           if op in ("pad", "copy") and math.prod(dims) >= M * width]
+    assert not big, big
+    assert ops.RESOLVED["fused_update_client"] == "pallas"
 
 
 def test_platform_selects_the_implementation(monkeypatch):

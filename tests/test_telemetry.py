@@ -325,7 +325,7 @@ def test_compile_counter_counts_each_compile(tmp_path):
 
 
 PHASES = ("round.inner_loop", "round.client_grad", "arena_pack",
-          "round.client_update", "fused_update_arena", "round.uplink",
+          "round.client_update", "fused_update_client", "round.uplink",
           "round_tail", "round.server_mean", "round.dual_refresh",
           "dual_from_uplink", "round.metrics")
 
@@ -333,7 +333,8 @@ PHASES = ("round.inner_loop", "round.client_grad", "arena_pack",
 @pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
 def test_round_phases_name_the_compiled_ops(impl, monkeypatch):
     """Every phase of an arena round names its ops in the compiled HLO, and
-    with the Pallas kernels every tiling pad and reshape is a relayout."""
+    the Pallas kernels read the arena as it lies: no tiling pad around
+    them, nothing under ``relayout``."""
     import re
 
     import jax
@@ -358,10 +359,7 @@ def test_round_phases_name_the_compiled_ops(impl, monkeypatch):
     stacks = [n for _, n in ops_named]
     for scope in PHASES:
         assert any(f"/{scope}/" in n for n in stacks), scope
-    if impl == "pallas_interpret":
-        tiling = [(op, n) for op, n in ops_named if op == "pad"
-                  and re.search(r"/(fused_update_arena|round_tail|dual_from_uplink)/", n)]
-        assert tiling and all("/relayout/" in n for _, n in tiling), tiling
-        assert any(n.endswith("/relayout/reshape") for n in stacks)
-    else:
-        assert not any("/relayout/" in n for n in stacks)
+    tiling = [(op, n) for op, n in ops_named if op == "pad"
+              and re.search(r"/(fused_update_client|round_tail|dual_from_uplink)/", n)]
+    assert not tiling, tiling
+    assert not any("/relayout/" in n for n in stacks)
