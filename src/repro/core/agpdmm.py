@@ -64,6 +64,7 @@ def popstore_body(cfg: FederatedConfig, spec, m: int, grad_fn, per_step):
                 spec, grad_fn, x0, x_s_row, lam_t, b, K=K, eta=eta_t,
                 rho=rho, per_step=per_step,
                 vr_snapshot=x0 if cfg.variance_reduction == "svrg" else None,
+                average=False,
             )
 
         rows = (lam_c,) + ((jnp.asarray(eta_v)[idx],) if per_client else ())
@@ -110,6 +111,7 @@ def _round_arena_cohort(cfg: FederatedConfig, state, grad_fn, batch, per_step_ba
             spec, grad_fn, x0, x_s_row, lam_t, b, K=K, eta=eta_t, rho=rho,
             per_step=per_step_batches,
             vr_snapshot=x0 if cfg.variance_reduction == "svrg" else None,
+            average=False,
         )
 
     rows = (lam_c,) + ((jnp.asarray(eta_v)[idx],) if per_client else ())
@@ -141,6 +143,7 @@ def _round_arena(cfg: FederatedConfig, state, grad_fn, batch, per_step_batches):
         spec, grad_fn, x0, x_s_row, lam, batch, K=K, eta=cfg.eta, rho=rho,
         per_step=per_step_batches,
         vr_snapshot=x0 if cfg.variance_reduction == "svrg" else None,
+        average=False,
     )
 
     _, uplink = ops.round_tail(x_K, lam, x_s_row, rho, with_lam_is=False)

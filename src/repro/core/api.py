@@ -215,7 +215,7 @@ def use_arena(cfg: FederatedConfig, params=None) -> bool:
     return True
 
 
-def affine_case(grad_fn, spec, *, per_step=False, vr_snapshot=None):
+def affine_case(grad_fn, spec, *, per_step=False, svrg=False):
     """Gate the fused K-step affine kernel for ``grad_fn`` on ``spec``.
 
     Returns the oracle's ``affine_arena`` factory when the whole inner loop
@@ -226,7 +226,7 @@ def affine_case(grad_fn, spec, *, per_step=False, vr_snapshot=None):
     decidable from shapes alone, so it costs nothing inside jit.
     """
     affine = getattr(grad_fn, "affine_arena", None)
-    if affine is None or per_step or vr_snapshot is not None:
+    if affine is None or per_step or svrg:
         return None
     from repro.kernels import ops
 

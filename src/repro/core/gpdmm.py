@@ -109,24 +109,31 @@ def inner_steps(grad_fn, x0, x_s_b, lam_s, batch, *, K, eta, rho, per_step,
 
 
 def inner_steps_arena(spec, grad_fn, x0, x_s_row, lam, batch, *, K, eta, rho,
-                      per_step, vr_snapshot=None):
+                      per_step, vr_snapshot=None, average=True):
     """Arena counterpart of ``inner_steps``: client state carried as one
-    ``(m, width)`` buffer, end to end.
+    ``(m, width)`` buffer, end to end.  Returns ``(x_K, x_sum)``: x_sum is
+    what x_bar is made from, with the factor ``avg_scale`` gives (the
+    running sum of the K iterates, scaled by 1/K inside the uplink kernel),
+    and None with ``average=False``, where no sum is formed.
 
     Gradient oracle resolution (``core.api`` protocol), fastest first:
 
       1. ``grad_fn.affine_arena`` + the width fits VMEM (and the plain
          full-batch case): the WHOLE K-step loop is ONE fused kernel
          (``kernels/inner_loop.py``) -- 1 HBM read + 1 write of the client
-         state for the entire inner loop.
+         state for the entire inner loop.  It returns x_bar itself.
       2. ``grad_fn.grad_arena``: one fused-update kernel per step with the
          gradient evaluated directly on the packed buffer -- 0 boundary
-         passes.
+         passes.  The kernel carries the running sum beside x
+         (``ops.fused_update_arena_sum``): the first step, peeled out of the
+         scan, writes x_1 as the sum, and each later step adds to it in
+         place.
       3. plain ``grad_fn``: same scan, paying the unpack->grad->pack round
          trip through the model's pytree each step, one client at a time
          (``step_by_client``): each client's row is stepped as soon as its
          gradient is made (``ops.fused_update_client``), so no ``(m,
-         width)`` gradient is stacked.
+         width)`` gradient is stacked.  The sum is an add after each step:
+         peeling the first step would trace the model's gradient twice.
 
     ``eta`` may be a scalar, the per-client tuple (auto-eta), or an
     already-gathered per-cohort row -- array forms ride the kernels as a
@@ -136,10 +143,11 @@ def inner_steps_arena(spec, grad_fn, x0, x_s_row, lam, batch, *, K, eta, rho,
     step_c = 1.0 / (1.0 / eta + rho)
     with jax.named_scope("round.inner_loop"):
         affine = affine_case(grad_fn, spec, per_step=per_step,
-                             vr_snapshot=vr_snapshot)
+                             svrg=vr_snapshot is not None)
         if affine is not None:
             H, c = affine(spec, batch)
-            return ops.inner_loop_affine(x0, H, c, x_s_row, lam, step_c, rho, K)
+            x_K, x_bar = ops.inner_loop_affine(x0, H, c, x_s_row, lam, step_c, rho, K)
+            return x_K, x_bar if average else None
 
         grad_a, native = arena_grad(grad_fn, spec)
 
@@ -152,32 +160,79 @@ def inner_steps_arena(spec, grad_fn, x0, x_s_row, lam, batch, *, K, eta, rho,
         # a per-client stepsize rides with the duals as a client row
         step_rows = () if np.ndim(step_c) == 0 else (jnp.asarray(step_c, jnp.float32),)
 
+        def batch_at(k):
+            if not per_step:
+                return batch
+            return jax.tree.map(
+                lambda t: jax.lax.dynamic_index_in_dim(t, k, keepdims=False), batch)
+
+        def scan_steps(step, carry, first=0):
+            """``carry = step(carry, batch_at(k))`` for inner steps k =
+            first..K-1."""
+            return jax.lax.scan(lambda c, k: (step(c, batch_at(k)), None),
+                                carry, jnp.arange(first, K))[0]
+
+        if native:
+            def grad(x, b):
+                with jax.named_scope("round.client_grad"):
+                    g = grad_a(x, b)
+                    if vr is not None:
+                        g = g - grad_a(vr[0], b) + vr[1]
+                return g
+
+            def plain(x, b):
+                with jax.named_scope("round.client_update"):
+                    return ops.fused_update_arena(x, grad(x, b), x_s_row, lam,
+                                                  step_c, rho)
+
+            if not average or K == 1:
+                x_K = scan_steps(plain, x0)
+                return x_K, x_K if average else None
+
+            def summing(carry, b):
+                x, xsum = carry
+                g = grad(x, b)
+                with jax.named_scope("round.client_update"):
+                    return ops.fused_update_arena_sum(x, g, x_s_row, lam, xsum,
+                                                      step_c, rho)
+
+            return scan_steps(summing, summing((x0, None), batch_at(0)), first=1)
+
         def update(x, g, i, lam, *rest):
             *st, x_s_row = rest
             return ops.fused_update_client(x, g, x_s_row, lam, i,
                                            st[0] if st else step_c, rho)
 
-        def one_step(carry, xs_k):
-            x, xsum = carry
-            b = xs_k if per_step else batch
-            if native:
-                with jax.named_scope("round.client_grad"):
-                    g = grad_a(x, b)
-                    if vr is not None:
-                        g = g - grad_a(vr[0], b) + vr[1]
-                with jax.named_scope("round.client_update"):
-                    x_new = ops.fused_update_arena(x, g, x_s_row, lam, step_c, rho)
-            else:
-                x_new = step_by_client(spec, grad_fn, update, x, b,
-                                       (lam, *step_rows), (x_s_row,), vr=vr)
-            return (x_new, xsum + x_new), None
+        def by_client(x, b):
+            return step_by_client(spec, grad_fn, update, x, b,
+                                  (lam, *step_rows), (x_s_row,), vr=vr)
 
-        init = (x0, jnp.zeros_like(x0))
-        if per_step:
-            (x_K, xsum), _ = jax.lax.scan(one_step, init, batch)
-        else:
-            (x_K, xsum), _ = jax.lax.scan(one_step, init, None, length=K)
-        return x_K, xsum * (1.0 / K)
+        if not average:
+            return scan_steps(by_client, x0), None
+
+        def by_client_sum(carry, b):
+            x_new = by_client(carry[0], b)
+            return x_new, carry[1] + x_new
+
+        return scan_steps(by_client_sum, (x0, jnp.zeros_like(x0)))
+
+
+def avg_scale(spec, grad_fn, K, *, per_step, svrg=False):
+    """The factor that makes x_bar (eq. 23) of ``inner_steps_arena``'s
+    second output, handed to ``ops.round_tail``: 1/K on the running sum of
+    the iterates, None where the affine K-step kernel returns x_bar itself."""
+    affine = affine_case(grad_fn, spec, per_step=per_step, svrg=svrg)
+    return None if affine is not None else 1.0 / K
+
+
+def uplink_ref(cfg: FederatedConfig, spec, grad_fn, per_step, x_K, x_sum):
+    """``(x_ref, scale)`` for ``ops.round_tail``: the average of the inner
+    iterates (eq. 23) as ``inner_steps_arena``'s sum and its factor, or x_K
+    (eq. 24, ``use_avg=False``) as it is."""
+    if not cfg.use_avg:
+        return x_K, None
+    return x_sum, avg_scale(spec, grad_fn, cfg.inner_steps, per_step=per_step,
+                            svrg=cfg.variance_reduction == "svrg")
 
 
 def participation_key(cfg: FederatedConfig, round_idx):
@@ -371,15 +426,16 @@ def popstore_body(cfg: FederatedConfig, spec, m: int, grad_fn, per_step):
             return inner_steps_arena(
                 spec, grad_fn, x0, x_s_row, lam_t, b, K=K, eta=eta_t,
                 rho=rho, per_step=per_step, vr_snapshot=snap,
+                average=cfg.use_avg,
             )
 
         rows = (x0_c, lam_c) + (
             (jnp.asarray(eta_v)[idx],) if per_client else ())
-        x_K, x_bar = run_cohort_inner(cfg, inner, rows, batch_c,
+        x_K, x_sum = run_cohort_inner(cfg, inner, rows, batch_c,
                                       per_step=per_step)
-        x_ref = x_bar if cfg.use_avg else x_K
+        x_ref, scale = uplink_ref(cfg, spec, grad_fn, per_step, x_K, x_sum)
         _, uplink = ops.round_tail(x_ref, lam_c, x_s_row, rho,
-                                   with_lam_is=False)
+                                   with_lam_is=False, scale=scale)
         uplink, keep_c, fm = popstore_tail(cfg, spec, x_s_row, u_hat_c,
                                            uplink, idx, round_idx, m)
         x_K_kept = (x_K if keep_c is None
@@ -432,16 +488,17 @@ def _round_arena_cohort(cfg: FederatedConfig, state, grad_fn, batch, per_step_ba
                 if cfg.variance_reduction == "svrg" else None)
         return inner_steps_arena(
             spec, grad_fn, x0, x_s_row, lam_t, b, K=K, eta=eta_t, rho=rho,
-            per_step=per_step_batches, vr_snapshot=snap,
+            per_step=per_step_batches, vr_snapshot=snap, average=cfg.use_avg,
         )
 
     rows = (x0_c, lam_c) + ((jnp.asarray(eta_v)[idx],) if per_client else ())
-    x_K, x_bar = run_cohort_inner(cfg, inner, rows, batch_c,
+    x_K, x_sum = run_cohort_inner(cfg, inner, rows, batch_c,
                                   per_step=per_step_batches)
-    x_ref = x_bar if cfg.use_avg else x_K
+    x_ref, scale = uplink_ref(cfg, spec, grad_fn, per_step_batches, x_K, x_sum)
 
     with jax.named_scope("round.uplink"):
-        _, uplink = ops.round_tail(x_ref, lam_c, x_s_row, rho, with_lam_is=False)
+        _, uplink = ops.round_tail(x_ref, lam_c, x_s_row, rho,
+                                   with_lam_is=False, scale=scale)
     fplan = faults.plan(cfg, state["round"], m)
     new_state, keep_c, fm = cohort_tail(cfg, spec, state, uplink, idx, fplan)
     # demoted cohort rows are silent, full stop: the carry keeps its
@@ -477,17 +534,18 @@ def _round_arena(cfg: FederatedConfig, state, grad_fn, batch, per_step_batches, 
     snapshot = None
     if cfg.variance_reduction == "svrg":
         snapshot = jnp.broadcast_to(x_s_row[None], x_c.shape)
-    x_K, x_bar = inner_steps_arena(
+    x_K, x_sum = inner_steps_arena(
         spec, grad_fn, x_c, x_s_row, lam, batch, K=K, eta=cfg.eta, rho=rho,
         per_step=per_step_batches, vr_snapshot=snapshot,
+        average=cfg.use_avg or return_trace,
     )
-    x_ref = x_bar if cfg.use_avg else x_K
+    x_ref, scale = uplink_ref(cfg, spec, grad_fn, per_step_batches, x_K, x_sum)
 
     # fused tail pass 1: the uplink (and lam_is only when a trace wants it --
     # 3 reads + 1 write on the training path, +1 write with the trace)
     with jax.named_scope("round.uplink"):
         lam_is, uplink = ops.round_tail(x_ref, lam, x_s_row, rho,
-                                        with_lam_is=return_trace)
+                                        with_lam_is=return_trace, scale=scale)
     new_state, x_s_new, lam_s_new, mask, fm = arena_tail(cfg, spec, state, uplink, m)
 
     # silent clients did not really run their inner steps: keep their carry
@@ -500,8 +558,11 @@ def _round_arena(cfg: FederatedConfig, state, grad_fn, batch, per_step_batches, 
     }
     metrics = arena_metrics(lam_s_new, x_K, x_s_row, mask) | fm
     if return_trace:
+        s_bar = avg_scale(spec, grad_fn, K, per_step=per_step_batches,
+                          svrg=snapshot is not None)
+        x_bar = x_sum if s_bar is None else x_sum * s_bar
         metrics["trace"] = {
-            "x_ref": spec.unpack_stacked(x_ref),
+            "x_ref": spec.unpack_stacked(x_bar if cfg.use_avg else x_K),
             "x_bar": spec.unpack_stacked(x_bar),
             "lam_is": spec.unpack_stacked(lam_is),
             "x_K": spec.unpack_stacked(x_K),

@@ -336,9 +336,7 @@ def fused_update_arena(x, g, x_s, lam, step, rho, *, impl: Optional[str] = None,
     impl = _resolve(impl, "fused_update_arena")
     step_a = _step_arr(step)
     if impl == "xla":
-        step_b = step if step_a is None else step_a[:, None]
-        return _ref.fused_update_ref(
-            x, g, x_s[None] if x_s.ndim == 1 else x_s, lam, step_b, rho)
+        return _update_arena_xla(x, g, x_s, lam, step, step_a, rho)
     from repro.kernels import round_tail as rt
 
     return per_client(
@@ -346,6 +344,37 @@ def fused_update_arena(x, g, x_s, lam, step, rho, *, impl: Optional[str] = None,
             x, g, x_s, lam, step if step_a is None else step_a, rho,
             block=block, interpret=(impl == "pallas_interpret")),
         (x, g, lam, step_a), (x_s,))
+
+
+def _update_arena_xla(x, g, x_s, lam, step, step_a, rho):
+    step_b = step if step_a is None else step_a[:, None]
+    return _ref.fused_update_ref(
+        x, g, x_s[None] if x_s.ndim == 1 else x_s, lam, step_b, rho)
+
+
+def fused_update_arena_sum(x, g, x_s, lam, xsum, step, rho, *,
+                           impl: Optional[str] = None,
+                           block: Optional[int] = None):
+    """Eq. (20) over the arena, as ``fused_update_arena`` with the dual, and
+    the running sum of the inner iterates in the same pass: returns (x',
+    xsum + x'), the sum in the state dtype.  ``xsum=None`` is the first
+    step, whose sum is x' itself: it reads no zeros buffer, and runs under
+    the plain kernel's name with its operands.  Later steps run under
+    ``fused_update_arena_sum`` and write the sum in place."""
+    name = "fused_update_arena" if xsum is None else "fused_update_arena_sum"
+    with jax.named_scope(name):
+        impl = _resolve(impl, name)
+        step_a = _step_arr(step)
+        if impl == "xla":
+            new = _update_arena_xla(x, g, x_s, lam, step, step_a, rho)
+            return new, new if xsum is None else xsum + new
+        from repro.kernels import round_tail as rt
+
+        return per_client(
+            lambda x, g, lam, xsum, step_a, x_s: rt.fused_update_arena_sum_pallas(
+                x, g, x_s, lam, xsum, step if step_a is None else step_a, rho,
+                block=block, interpret=(impl == "pallas_interpret")),
+            (x, g, lam, xsum, step_a), (x_s,))
 
 
 @_scoped
@@ -463,7 +492,8 @@ def affine_inner_fits(width: int) -> bool:
 
 @_scoped
 def round_tail(x_ref, lam_s, x_s, rho, *, with_lam_is: bool = True,
-               impl: Optional[str] = None, block: Optional[int] = None):
+               scale: Optional[float] = None, impl: Optional[str] = None,
+               block: Optional[int] = None):
     """Fused dual flip + uplink (eqs. 23/24 + Alg. 1 line 8):
 
         lam_is = rho (x_s - x_ref) - lam_s
@@ -472,10 +502,15 @@ def round_tail(x_ref, lam_s, x_s, rho, *, with_lam_is: bool = True,
     3 HBM reads + 2 writes in one pass instead of ~4 separate passes.
     x_ref, lam_s: (m, width); x_s: (width,).  Returns (lam_is, uplink);
     ``with_lam_is=False`` (the non-trace training path -- callers discard
-    lam_is) skips the lam_is output: 3 reads + 1 write, returns (None, u)."""
+    lam_is) skips the lam_is output: 3 reads + 1 write, returns (None, u).
+
+    ``scale``: None reads ``x_ref`` as it is.  A static factor (1/K) makes
+    the first operand the running sum of the K inner iterates: the kernel
+    forms x_ref = sum * scale in the state dtype, the rounding of an XLA
+    ``sum * scale``, so the average is never a pass of its own."""
     impl = _resolve(impl, "round_tail")
     if impl == "xla":
-        xr = x_ref.astype(jnp.float32)
+        xr = (x_ref if scale is None else x_ref * scale).astype(jnp.float32)
         lam = lam_s.astype(jnp.float32)
         xs = x_s.astype(jnp.float32)[None]
         lam_is = rho * (xs - xr) - lam
@@ -485,8 +520,8 @@ def round_tail(x_ref, lam_s, x_s, rho, *, with_lam_is: bool = True,
 
     return per_client(
         lambda x_ref, lam_s, x_s: rt.round_tail_pallas(
-            x_ref, lam_s, x_s, rho, with_lam_is=with_lam_is, block=block,
-            interpret=(impl == "pallas_interpret")),
+            x_ref, lam_s, x_s, rho, with_lam_is=with_lam_is, scale=scale,
+            block=block, interpret=(impl == "pallas_interpret")),
         (x_ref, lam_s), (x_s,))
 
 

@@ -8,7 +8,9 @@ HBM.  On the arena the same math becomes three fused kernels:
 
   * ``round_tail_pallas``   -- lam_is = rho (x_s - x_ref) - lam_s  and the
                                uplink u = x_ref - lam_is / rho in ONE pass:
-                               3 reads + 2 writes instead of ~4 passes.
+                               3 reads + 2 writes instead of ~4 passes; with
+                               a ``scale`` it reads the running sum of the
+                               inner iterates and forms x_bar in VMEM.
   * ``ef21_*``              -- EF21 quantise-delta in TWO passes: a rowwise
                                max-abs reduction (the only full read of
                                u/u_hat) + the quantise-dequantise-integrate
@@ -17,7 +19,9 @@ HBM.  On the arena the same math becomes three fused kernels:
   * ``fused_update_arena_pallas`` -- the eq. (20) inner step over the whole
                                packed buffer with the server row broadcast
                                in-kernel, so the K-step scan issues ONE
-                               pallas_call per step instead of one per leaf.
+                               pallas_call per step instead of one per leaf;
+                               ``fused_update_arena_sum_pallas`` carries the
+                               running sum of the iterates beside it.
 
 The elementwise kernels (eq. (20), the uplink, the dual refresh, SCAFFOLD's
 control variate) read the ``(m, width)`` arena as it lies, in ``(bm, bw)``
@@ -40,6 +44,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -169,8 +174,19 @@ class _Blocks:
 # (a) lam_is + uplink in one pass
 # ---------------------------------------------------------------------------
 
-def _round_tail_kernel(xr_ref, lam_ref, xs_ref, lam_is_ref, up_ref, *, rho: float):
+def _x_ref(xr_ref, scale):
+    """The uplink's x_ref block in f32: as it lies, or x_bar = sum * scale
+    formed from the running sum of iterates and rounded to the state dtype,
+    as an XLA multiply by the scale in that dtype would round it."""
     xr = xr_ref[0].astype(jnp.float32)
+    if scale is None:
+        return xr
+    return (xr * scale).astype(xr_ref.dtype).astype(jnp.float32)
+
+
+def _round_tail_kernel(xr_ref, lam_ref, xs_ref, lam_is_ref, up_ref, *, rho: float,
+                       scale):
+    xr = _x_ref(xr_ref, scale)
     lam = lam_ref[0].astype(jnp.float32)
     xs = xs_ref[...].astype(jnp.float32)
     lam_is = rho * (xs - xr) - lam
@@ -178,28 +194,41 @@ def _round_tail_kernel(xr_ref, lam_ref, xs_ref, lam_is_ref, up_ref, *, rho: floa
     up_ref[0] = (xr - lam_is / rho).astype(up_ref.dtype)
 
 
-def _uplink_kernel(xr_ref, lam_ref, xs_ref, up_ref, *, rho: float):
+def _uplink_kernel(xr_ref, lam_ref, xs_ref, up_ref, *, rho: float, scale):
     # uplink only (lam_is algebraically eliminated): u = 2 x_ref - x_s + lam/rho
-    xr = xr_ref[0].astype(jnp.float32)
+    xr = _x_ref(xr_ref, scale)
     lam = lam_ref[0].astype(jnp.float32)
     xs = xs_ref[...].astype(jnp.float32)
     up_ref[0] = (xr - (rho * (xs - xr) - lam) / rho).astype(up_ref.dtype)
 
 
+def _dtype_scale(scale, dtype):
+    """``scale`` as an f32 Python float, rounded to ``dtype`` first: the
+    factor an XLA multiply of a ``dtype`` array by the Python scalar uses.
+    None passes through."""
+    if scale is None:
+        return None
+    return float(np.asarray(scale, jnp.dtype(dtype)).astype(np.float32))
+
+
 def round_tail_pallas(x_ref, lam_s, x_s, rho, *, with_lam_is: bool = True,
-                      block=None, interpret: bool = False):
+                      scale=None, block=None, interpret: bool = False):
     """x_ref, lam_s: (m, width); x_s: (width,) server row.  Returns
     (lam_is, uplink), both (m, width).  ``with_lam_is=False`` (the training
     hot path: both callers discard lam_is outside traces) skips the second
-    output entirely -- 3 reads + 1 write -- and returns (None, uplink)."""
+    output entirely -- 3 reads + 1 write -- and returns (None, uplink).
+    ``scale``: None reads x_ref as it is; a static factor reads the running
+    sum of the inner iterates there and forms x_bar = sum * scale in VMEM,
+    so no pass of its own materialises the average."""
     m, w = x_ref.shape
     lay = _Blocks("round_tail", m, w, x_ref.dtype, 5 if with_lam_is else 4, block)
     args = (lay.clients(x_ref), lay.clients(lam_s), lay.servers(x_s))
     in_specs = [lay.client, lay.client, lay.server]
     out_sds = lay.out_shape(x_ref.dtype)
+    kw = dict(rho=float(rho), scale=_dtype_scale(scale, x_ref.dtype))
     if not with_lam_is:
         up = pl.pallas_call(
-            functools.partial(_uplink_kernel, rho=float(rho)),
+            functools.partial(_uplink_kernel, **kw),
             name="round_tail",
             grid=lay.grid,
             in_specs=in_specs,
@@ -209,7 +238,7 @@ def round_tail_pallas(x_ref, lam_s, x_s, rho, *, with_lam_is: bool = True,
         )(*args)
         return None, lay.back(up)
     lam_is, up = pl.pallas_call(
-        functools.partial(_round_tail_kernel, rho=float(rho)),
+        functools.partial(_round_tail_kernel, **kw),
         name="round_tail",
         grid=lay.grid,
         in_specs=in_specs,
@@ -465,6 +494,66 @@ def fused_update_arena_pallas(x, g, x_s, lam, step, rho, *, block=None, interpre
         interpret=interpret,
     )(*args)
     return lay.back(out)
+
+
+def _update_sum_kernel(x_ref, g_ref, xs_ref, lam_ref, *refs, step, rho: float,
+                       carry: bool):
+    # refs: ([per-client steps], [running sum], x', sum').  Eq. (20), and
+    # beside it the running sum of the iterates: added in f32 and rounded
+    # to the state dtype each step, as an XLA add of the two would be
+    *ins, o_ref, s_ref = refs
+    f32 = jnp.float32
+    st = step if step is not None else ins[0][0][:, :1]
+    new = eq20(x_ref[0].astype(f32), g_ref[0].astype(f32),
+               xs_ref[...].astype(f32), lam_ref[0].astype(f32), st, rho)
+    new = new.astype(o_ref.dtype)
+    o_ref[0] = new
+    acc = new.astype(f32)
+    if carry:
+        acc = ins[-1][0].astype(f32) + acc
+    s_ref[0] = acc.astype(s_ref.dtype)
+
+
+def fused_update_arena_sum_pallas(x, g, x_s, lam, xsum, step, rho, *, block=None,
+                                  interpret: bool = False):
+    """Eq. (20) as ``fused_update_arena_pallas`` with lam, and the running
+    sum of the inner iterates as a second output: returns (x', sum').
+    ``xsum=None`` is the first step: the sum is x' itself, and the call
+    reads what the plain kernel reads (x, g, lam, the server row) under its
+    name, ``fused_update_arena``.  Later steps read the sum too, under
+    ``fused_update_arena_sum``, and write it in place beside x (donate both
+    or XLA copies them)."""
+    m, w = x.shape
+    first = xsum is None
+    name = "fused_update_arena" if first else "fused_update_arena_sum"
+    lay = _Blocks(name, m, w, x.dtype, 6 if first else 7, block)
+    args = [lay.clients(x), lay.clients(g), lay.servers(x_s), lay.clients(lam)]
+    in_specs = [lay.client, lay.client, lay.server, lay.client]
+    if jnp.ndim(step) > 0:
+        assert step.shape == (m,), step.shape
+        args.append(lay.rows(step))
+        in_specs.append(lay.row)
+        step = None
+    else:
+        step = float(step)
+    aliases = {0: 0}
+    if not first:
+        aliases[len(args)] = 1
+        args.append(lay.clients(xsum))
+        in_specs.append(lay.client)
+    out_sds = lay.out_shape(x.dtype)
+    out, acc = pl.pallas_call(
+        functools.partial(_update_sum_kernel, step=step, rho=float(rho),
+                          carry=not first),
+        name=name,
+        grid=lay.grid,
+        in_specs=in_specs,
+        out_specs=(lay.client, lay.client),
+        out_shape=(out_sds, out_sds),
+        input_output_aliases=aliases,
+        interpret=interpret,
+    )(*args)
+    return lay.back(out), lay.back(acc)
 
 
 def _update_client_kernel(i_ref, x_ref, g_ref, xs_ref, lam_ref, *refs, bm: int,

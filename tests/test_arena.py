@@ -232,6 +232,64 @@ def test_fused_update_arena_parity(impl, case, dtype, per_client_step, with_lam)
 @pytest.mark.parametrize("impl", IMPLS)
 @pytest.mark.parametrize("case", sorted(ARENA_CASES))
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("per_client_step", [False, True])
+@pytest.mark.parametrize("form", ["first", "accumulate"])
+def test_fused_update_arena_sum_parity(impl, case, dtype, per_client_step, form):
+    """Eq. (20) with the running sum of the inner iterates beside it: x' is
+    bitwise the plain kernel's, and the sum bitwise the XLA add of x' in
+    the state dtype (the first step's sum is x' itself), in both dtypes."""
+    (x, g, lam, xsum), (xs,), step_v = arena_operands(case, dtype, 4, 1, 19)
+    m = x.shape[0]
+    xsum = xsum if form == "accumulate" else None
+    step = step_v if per_client_step else 0.05
+    out, acc = ops.fused_update_arena_sum(x, g, xs, lam, xsum, step, 3.0, impl=impl)
+    name = "fused_update_arena" if xsum is None else "fused_update_arena_sum"
+    assert ops.RESOLVED[name] == impl
+    assert_layout(name, impl, m, dtype)
+    want = ops.fused_update_arena(x, g, xs, lam, step, 3.0, impl=impl)
+    np.testing.assert_array_equal(f32(out), f32(want))
+    np.testing.assert_array_equal(
+        f32(acc), f32(want if xsum is None else xsum + want))
+    assert acc.dtype == dtype
+    if impl == "pallas_interpret":
+        assert_bitwise_xla(lambda i, x, g, xs, lam, st: ops.fused_update_arena_sum(
+            x, g, xs, lam, xsum, st if per_client_step else 0.05, 3.0, impl=i),
+            x, g, xs, lam, step_v)
+
+
+@pytest.mark.parametrize("impl", IMPLS)
+@pytest.mark.parametrize("case", sorted(ARENA_CASES))
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("with_lam_is", [False, True])
+def test_round_tail_scale_parity(impl, case, dtype, with_lam_is):
+    """The uplink kernel handed the running sum of K = 3 iterates and the
+    scale 1/K gives bitwise what it gives on the average formed by an XLA
+    ``sum * (1/K)`` first, in both dtypes.  One allowance: on the CPU, XLA
+    may contract the interpret-mode kernel's f32 ``sum * scale`` into the
+    subtract that reads it (one FMA: x_bar unrounded), so there the f32
+    outputs are held to four ulps of their largest element instead."""
+    rho, K = 2.5, 3
+    (xsum, lam), (xs,), _ = arena_operands(case, dtype, 2, 1, 23)
+    xsum = (xsum * K).astype(dtype)
+    got = jax.jit(lambda a, b, c: ops.round_tail(
+        a, b, c, rho, with_lam_is=with_lam_is, scale=1.0 / K, impl=impl))(xsum, lam, xs)
+    want = jax.jit(lambda a, b, c: ops.round_tail(
+        a * (1.0 / K), b, c, rho, with_lam_is=with_lam_is, impl=impl))(xsum, lam, xs)
+    assert (got[0] is None) == (not with_lam_is)
+    for a, b in zip(got, want):
+        if a is None:
+            continue
+        assert a.dtype == dtype
+        if impl == "pallas_interpret" and dtype == jnp.float32:
+            ulp = np.spacing(np.abs(f32(b)).max())
+            np.testing.assert_allclose(f32(a), f32(b), rtol=0, atol=4 * ulp)
+        else:
+            np.testing.assert_array_equal(f32(a), f32(b))
+
+
+@pytest.mark.parametrize("impl", IMPLS)
+@pytest.mark.parametrize("case", sorted(ARENA_CASES))
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_fused_update_client_parity(impl, case, dtype):
     """Eq. (20) on one client's row of the arena: that row stepped, every
     other row bitwise as it was, for the first, a middle and the last
@@ -391,6 +449,119 @@ def test_round_parity_by_client(prob, algo, impl, monkeypatch):
         atol = 1e-6 * rho if k.startswith("lam") else 1e-5
         np.testing.assert_allclose(np.asarray(sa[k]), np.asarray(want),
                                    atol=atol, rtol=1e-5, err_msg=k)
+
+
+SOFTMAX_F, SOFTMAX_C = 20, 5
+
+
+def softmax_problem(m, K, seed=21):
+    """The paper's softmax regression at a small size: its arena-native
+    oracle, per-step mini-batches (K, m, 8, F) and a start point."""
+    from repro.core.softmax import SoftmaxRegression
+
+    sm = SoftmaxRegression(SOFTMAX_F, SOFTMAX_C)
+    kx, ky, kw = jax.random.split(jax.random.key(seed), 3)
+    batch = {"x": jax.random.normal(kx, (K, m, 8, SOFTMAX_F)),
+             "y": jax.random.randint(ky, (K, m, 8), 0, SOFTMAX_C)}
+    return sm.oracle(), batch, 0.1 * jax.random.normal(kw, (sm.dim,))
+
+
+def xla_average(monkeypatch):
+    """Route the round through the composition the summing kernels replace:
+    each eq. (20) step by the plain kernel, ``xsum + x_new`` as an XLA add
+    after it from a zeros buffer, and ``xsum * (1/K)`` as an XLA multiply
+    ahead of the unscaled uplink kernel."""
+    plain, tail = ops.fused_update_arena, ops.round_tail
+
+    def summed(x, g, x_s, lam, xsum, step, rho):
+        new = plain(x, g, x_s, lam, step, rho)
+        return new, (jnp.zeros_like(x) if xsum is None else xsum) + new
+
+    def scaled(x_ref, lam, x_s, rho, *, scale=None, **kw):
+        return tail(x_ref if scale is None else x_ref * scale, lam, x_s, rho, **kw)
+
+    monkeypatch.setattr(ops, "fused_update_arena_sum", summed)
+    monkeypatch.setattr(ops, "round_tail", scaled)
+
+
+@pytest.mark.parametrize("eta", ["scalar", "per_client"])
+@pytest.mark.parametrize("body", ["masked", "cohort", "popstore"])
+def test_round_average_formed_in_kernels(body, eta, monkeypatch):
+    """A GPDMM arena round on softmax regression whose uplink reads the
+    average of the inner iterates (eq. 23) forms that average inside the
+    kernels -- the eq. (20) kernel carries the running sum, the uplink
+    kernel applies 1/K -- and its state comes out bitwise equal, at f32, to
+    the composition of XLA passes it replaces, with the Pallas kernels in
+    interpret mode: the full masked round, the cohort round and the
+    popstore body, at a scalar and a per-client stepsize."""
+    from repro.core import gpdmm
+
+    monkeypatch.setattr(ops, "default_impl", lambda: "pallas_interpret")
+    m, K = 6, 3
+    oracle, batch, x0 = softmax_problem(m, K)
+    eta_v = 0.5 if eta == "scalar" else tuple(np.linspace(0.3, 0.7, m).tolist())
+    cfg = FederatedConfig(algorithm="gpdmm", inner_steps=K, eta=eta_v,
+                          use_arena=True, cohort=body != "masked",
+                          participation=1.0 if body == "masked" else 0.5)
+    spec = arena.ArenaSpec.from_tree(x0)
+
+    def run():
+        if body == "popstore":
+            idx = T.cohort_indices(gpdmm.participation_key(cfg, 0), m,
+                                   cfg.participation)[0]
+            row = spec.pack(x0)
+            k1, k2 = jax.random.split(jax.random.key(3))
+            staged = {"u_hat": row + 0.01 * jax.random.normal(k1, (idx.shape[0], spec.width)),
+                      "x_c": row + 0.01 * jax.random.normal(k2, (idx.shape[0], spec.width))}
+            body_fn = gpdmm.popstore_body(cfg, spec, m, oracle, True)
+            return jax.jit(body_fn)({"x_s": x0}, staged, idx, jnp.int32(0), batch)[0]
+        opt = make(cfg)
+        step = jax.jit(lambda s: opt.round(s, oracle, batch, True))
+        s = opt.init(x0, m)
+        for _ in range(2):  # the second round steps from nonzero duals
+            s, _ = step(s)
+        return s
+
+    monkeypatch.setattr(ops, "RESOLVED", {})
+    got = run()
+    assert ops.RESOLVED["fused_update_arena_sum"] == "pallas_interpret"
+    xla_average(monkeypatch)
+    monkeypatch.setattr(ops, "RESOLVED", {})
+    want = run()
+    assert "fused_update_arena_sum" not in ops.RESOLVED
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("algo, extra, summing", [
+    ("gpdmm", {}, True),
+    ("gpdmm", {"use_avg": False}, False),
+    ("agpdmm", {}, False),
+    ("scaffold", {}, False),
+    ("fedavg", {}, False),
+    ("fedsplit", {"rho": 1.0}, False),
+])
+def test_running_sum_kernel_only_where_the_average_is_read(algo, extra, summing,
+                                                           monkeypatch):
+    """The summing eq. (20) kernel runs only in GPDMM rounds whose uplink
+    reads the average of the iterates: once per step after the first, which
+    keeps the plain kernel's name.  AGPDMM, ``use_avg=False``, SCAFFOLD,
+    FedAvg and FedSplit trace the kernels they traced before, and the
+    average's running sum is not formed at all."""
+    import re
+
+    monkeypatch.setattr(ops, "default_impl", lambda: "pallas")
+    m, K = 4, 3
+    oracle, batch, x0 = softmax_problem(m, K)
+    opt = make(FederatedConfig(algorithm=algo, inner_steps=K, eta=0.5,
+                               use_arena=True, **extra))
+    state = opt.init(x0, m)
+    jaxpr = str(jax.make_jaxpr(lambda s: opt.round(s, oracle, batch, True))(state))
+    names = re.findall(r"\bname=(\w+)", jaxpr)
+    assert ("fused_update_arena_sum" in names) == summing, names
+    if algo in ("gpdmm", "agpdmm"):
+        assert "fused_update_arena" in names and "round_tail" in names, names
 
 
 @pytest.mark.parametrize("init", ["z", "xs"])

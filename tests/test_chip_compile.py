@@ -144,6 +144,12 @@ CASES = {
          S((ARENA_W,), jnp.float32), S((3 * 8, ARENA_W), jnp.float32), S((), jnp.int32))),
     "round_tail": lambda S, W: _round_tail(S, W, False),
     "round_tail-lam_is": lambda S, W: _round_tail(S, W, True),
+    # the olmo1b.silo2 uplink: the running sum of the iterates in, 1/K
+    # applied in the kernel, float32 state at m = 2
+    "round_tail-scale": lambda S, W: (
+        lambda x, lam, s: ops.round_tail(x, lam, s, 1.5, with_lam_is=False,
+                                         scale=0.5, **P),
+        (S((M, W), jnp.float32), S((M, W), jnp.float32), S((W,), jnp.float32))),
     "dual_from_uplink": lambda S, W: (
         lambda u, s: ops.dual_from_uplink(u, s, 1.5, **P), _rows(S, W)),
     "scaffold_cv-scalar": lambda S, W: _scaffold_cv(S, W, False),
@@ -203,8 +209,16 @@ ARENA_CASES = {
         x, g, s, lam, 0.1, 1.5, **P), "ccsc", (3, 1)),
     "fused_update_arena-per_client": (lambda x, g, s, lam, e: ops.fused_update_arena(
         x, g, s, lam, e, 1.5, **P), "ccscm", None),
+    # the first step carries the running sum out beside x and reads what
+    # the plain kernel reads; later steps read the sum too
+    "fused_update_arena-sum_first": (lambda x, g, s, lam: ops.fused_update_arena_sum(
+        x, g, s, lam, None, 0.1, 1.5, **P), "ccsc", (3, 1)),
+    "fused_update_arena_sum": (lambda x, g, s, lam, xsum: ops.fused_update_arena_sum(
+        x, g, s, lam, xsum, 0.1, 1.5, **P), "ccscc", (4, 1)),
     "round_tail": (lambda x, lam, s: ops.round_tail(
         x, lam, s, 1.5, with_lam_is=False, **P)[1], "ccs", (2, 1)),
+    "round_tail-scale": (lambda x, lam, s: ops.round_tail(
+        x, lam, s, 1.5, with_lam_is=False, scale=0.2, **P)[1], "ccs", (2, 1)),
     "round_tail-lam_is": (lambda x, lam, s: ops.round_tail(
         x, lam, s, 1.5, **P), "ccs", None),
     "dual_from_uplink": (lambda u, s: ops.dual_from_uplink(u, s, 1.5, **P), "cs", None),
@@ -225,8 +239,10 @@ def test_arena_kernels_read_the_arena_in_place(sds, case):
     shape = {"c": ((ARENA_M, ARENA_W), jnp.float32), "s": ((ARENA_W,), jnp.float32),
              "m": ((ARENA_M,), jnp.float32)}
     args = [sds(*shape[k]) for k in kinds]
-    # the eq. (20) kernel writes over x, which the inner loop donates
-    donate = 0 if case.startswith("fused_update_arena") else ()
+    # the eq. (20) kernels write over x, and the summing one over the sum,
+    # which the inner loop donates
+    donate = ((0, 4) if case == "fused_update_arena_sum"
+              else 0 if case.startswith("fused_update_arena") else ())
     hlo = jax.jit(fn, donate_argnums=donate).lower(*args).compile().as_text()
     shapes = _hlo_shapes(hlo)
     big = [(n, op, dims) for n, (op, dims) in shapes.items()

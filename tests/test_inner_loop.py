@@ -19,7 +19,9 @@ import pytest
 from repro.configs.base import FederatedConfig
 from repro.core import arena, make, make_scan_rounds, quadratic
 from repro.core import tree_util as T
-from repro.core.gpdmm import inner_steps, inner_steps_arena, participation_key
+from repro.core.gpdmm import (
+    avg_scale, inner_steps, inner_steps_arena, participation_key,
+)
 from repro.core.softmax import SoftmaxRegression
 from repro.kernels import ops
 from repro.kernels.fused_update import VMEM_CAP_BYTES
@@ -61,9 +63,10 @@ def test_inner_loop_affine_parity(prob, impl, K):
     # reference 2: step-at-a-time arena scan with the plain (wrapped) grad
     x0a, lama = spec.pack_stacked(x0_t), spec.pack_stacked(lam_t)
     xsa = spec.pack(xs_t)
-    x_K_scan, x_bar_scan = inner_steps_arena(
+    x_K_scan, x_sum = inner_steps_arena(
         spec, prob.grad, x0a, xsa, lama, prob.batch(),
         K=K, eta=eta, rho=rho, per_step=False)
+    x_bar_scan = x_sum * avg_scale(spec, prob.grad, K, per_step=False)
 
     # the fused kernel under test
     oracle = prob.oracle()
