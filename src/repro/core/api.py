@@ -246,7 +246,9 @@ def affine_case(grad_fn, spec, *, per_step=False, svrg=False):
 # the documented (sum_active uplink + sum_silent u_hat) / m identity and
 # keeps it bit-identical to the masked path's mean-of-selected-rows.  The
 # helpers below are the cross-algorithm plumbing; the per-algorithm cohort
-# rounds live next to their masked siblings in gpdmm/agpdmm/scaffold/fedavg.
+# rounds live next to their masked siblings in gpdmm (which also serves
+# agpdmm), scaffold and fedavg, and all of them admit their uplink through
+# core.uplink.admit.
 
 
 # algorithms with a cohort round implementation (the four arena rounds);

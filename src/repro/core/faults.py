@@ -213,28 +213,30 @@ def needs_cache(cfg: FederatedConfig) -> bool:
     return screening_on(cfg) or (cfg.faults is not None and cfg.faults.any)
 
 
-def _keep_from(cfg: FederatedConfig, finite, sq):
+def _keep_from(cfg: FederatedConfig, finite, sq, among):
     keep = finite
     if cfg.screen_mult > 0.0:
-        # median over the rows that screened finite; all-NaN median (no
-        # finite row at all) propagates NaN -> comparison False -> every row
-        # already demoted by the finite flag, consistently
-        med = jnp.nanmedian(jnp.where(finite, sq, jnp.nan))
+        # median over the transmitting rows that screened finite; all-NaN
+        # median (no such row at all) propagates NaN -> comparison False ->
+        # every row demoted, consistently
+        rows = finite if among is None else finite & among
+        med = jnp.nanmedian(jnp.where(rows, sq, jnp.nan))
         keep = keep & (sq <= jnp.float32(cfg.screen_mult)
                        * jnp.maximum(med, jnp.float32(1e-12)))
     return keep
 
 
-def screen_keep(cfg: FederatedConfig, uplink, ref):
+def screen_keep(cfg: FederatedConfig, uplink, ref, among=None):
     """Screen a (rows, width) uplink buffer against the downlink reference
     ``ref`` ((width,) broadcast row, or (rows, width) per-row).  Returns the
     (rows,) bool KEEP mask: finite and not a norm outlier
-    (> screen_mult x the round median squared deviation)."""
+    (> screen_mult x the median squared deviation over the rows in
+    ``among``, the round's transmitters; None = every row)."""
     finite, sq = ops.screen_uplink(uplink, ref)
-    return _keep_from(cfg, finite, sq)
+    return _keep_from(cfg, finite, sq, among)
 
 
-def screen_keep_tree(cfg: FederatedConfig, uplink, ref_tree):
+def screen_keep_tree(cfg: FederatedConfig, uplink, ref_tree, among=None):
     """``screen_keep`` over a stacked client pytree vs the server pytree:
     the flags/deviations reduce over ALL leaves, so the rule matches the
     packed-arena screen on the same state."""
@@ -250,7 +252,7 @@ def screen_keep_tree(cfg: FederatedConfig, uplink, ref_tree):
         d = jnp.where(fin_e, uf - rf, 0.0)
         finite = finite & jnp.all(fin_e, axis=1)
         sq = sq + jnp.sum(d * d, axis=1)
-    return _keep_from(cfg, finite, sq)
+    return _keep_from(cfg, finite, sq, among)
 
 
 def combine_mask(mask, plan_: Optional[FaultPlan], keep):

@@ -26,9 +26,31 @@ from repro.core.api import (
     FedOpt, cohort_batch, map_clients, run_cohort_inner, use_arena,
     use_cohort,
 )
-from repro.core.gpdmm import _eta_val, _step_for, participation_key, popstore_tail
+from repro.core.gpdmm import _eta_val, _step_for, arena_metrics
 from repro.core.scaffold import inner_steps_plain_arena
+from repro.core.uplink import admit, participation_key
 from repro.kernels import ops
+
+
+def _cohort_steps(cfg: FederatedConfig, spec, grad_fn, per_step, x_s_row,
+                  batch_c, idx):
+    """The cohort's plain K-step loop from the server row, tiled by
+    ``cohort_tile`` when set; shared by the device cohort round and the
+    popstore body."""
+    eta = _eta_val(cfg.eta)
+    per_client = np.ndim(eta) > 0
+
+    def inner(rows, b):
+        eta_t = rows[0] if per_client else eta  # tiled with the batch
+        mc = jax.tree.leaves(b)[0].shape[1 if per_step else 0]
+        x0 = jnp.broadcast_to(x_s_row[None], (mc, spec.width))
+        return inner_steps_plain_arena(
+            spec, grad_fn, x0, x_s_row, b, K=cfg.inner_steps, eta=eta_t,
+            per_step=per_step,
+        )
+
+    rows = (jnp.asarray(eta)[idx],) if per_client else ()
+    return run_cohort_inner(cfg, inner, rows, batch_c, per_step=per_step)
 
 
 def popstore_body(cfg: FederatedConfig, spec, m: int, grad_fn, per_step):
@@ -36,35 +58,15 @@ def popstore_body(cfg: FederatedConfig, spec, m: int, grad_fn, per_step):
     the cohort runs the plain K-step loop from the server row; only the
     staged ``u_hat`` rows (EF21 integrator / silence fallback) move, and the
     host driver maintains the population mean incrementally."""
-    K, eta = cfg.inner_steps, _eta_val(cfg.eta)
-    per_client = np.ndim(eta) > 0
-    f32 = jnp.float32
 
     def body(server, staged, idx, round_idx, batch):
         x_s_row = spec.pack(server["x_s"])
-        u_hat_c = staged["u_hat"]
-        batch_c = cohort_batch(batch, idx, m, per_step)
-
-        def inner(rows, b):
-            eta_t = rows[0] if per_client else eta  # tiled with the batch
-            mc = jax.tree.leaves(b)[0].shape[1 if per_step else 0]
-            x0 = jnp.broadcast_to(x_s_row[None], (mc, spec.width))
-            return inner_steps_plain_arena(
-                spec, grad_fn, x0, x_s_row, b, K=K, eta=eta_t,
-                per_step=per_step,
-            )
-
-        rows = (jnp.asarray(eta)[idx],) if per_client else ()
-        x_K = run_cohort_inner(cfg, inner, rows, batch_c, per_step=per_step)
-        uplink, keep_c, fm = popstore_tail(cfg, spec, x_s_row, u_hat_c, x_K,
-                                           idx, round_idx, m)
-        metrics = {
-            "client_drift": T.masked_client_mean(
-                jnp.sum(jnp.square((x_K - x_s_row[None]).astype(f32)),
-                        axis=1), keep_c),
-            "used_arena": jnp.ones((), f32),
-        } | fm
-        return {"u_hat": uplink}, {}, metrics
+        x_K = _cohort_steps(cfg, spec, grad_fn, per_step, x_s_row,
+                            cohort_batch(batch, idx, m, per_step), idx)
+        adm = admit(cfg, x_K, arena=spec, round_idx=round_idx, m=m,
+                    ref=x_s_row, fallback=staged["u_hat"], idx=idx)
+        return ({"u_hat": adm.admitted}, {},
+                arena_metrics(adm, x_K, x_s_row))
 
     return body
 
@@ -87,8 +89,6 @@ def _round_arena_cohort(cfg: FederatedConfig, state, grad_fn, batch, per_step_ba
     arena-resident u_hat cache, and the server mean over the scattered
     buffer realises (sum_active x_K + sum_silent u_hat) / m exactly as the
     masked path's mean-of-selected-rows."""
-    K, eta = cfg.inner_steps, _eta_val(cfg.eta)
-    per_client = np.ndim(eta) > 0
     spec = arena.ArenaSpec.from_tree(state["x_s"])
     u_hat = state["u_hat"]  # guaranteed: participation < 1 carries the cache
     m = u_hat.shape[0]
@@ -96,51 +96,18 @@ def _round_arena_cohort(cfg: FederatedConfig, state, grad_fn, batch, per_step_ba
     idx, _mask = T.cohort_indices(
         participation_key(cfg, state["round"]), m, cfg.participation
     )
-    batch_c = cohort_batch(batch, idx, m, per_step_batches)
-
-    def inner(rows, b):
-        eta_t = rows[0] if per_client else eta  # tiled with the batch
-        mc = jax.tree.leaves(b)[0].shape[1 if per_step_batches else 0]
-        x0 = jnp.broadcast_to(x_s_row[None], (mc, spec.width))
-        return inner_steps_plain_arena(
-            spec, grad_fn, x0, x_s_row, b, K=K, eta=eta_t,
-            per_step=per_step_batches,
-        )
-
-    rows = (jnp.asarray(eta)[idx],) if per_client else ()
-    x_K = run_cohort_inner(cfg, inner, rows, batch_c, per_step=per_step_batches)
-
-    uplink = x_K
-    if cfg.uplink_bits is not None:  # EF21 on the cohort's cached rows only
-        uplink = ops.ef21_update(uplink, ops.row_gather(u_hat, idx),
-                                 cfg.uplink_bits, spec.leaf_rows())
-    fplan = faults.plan(cfg, state["round"], m)
-    plan_c = faults.take(fplan, idx)
-    uplink = faults.inject(cfg.faults, plan_c, uplink)
-    keep = None
-    if faults.screening_on(cfg):
-        keep = faults.screen_keep(cfg, uplink, x_s_row)
-    keep_c = faults.combine_mask(None, plan_c, keep)
-    if keep_c is not None:
-        uplink = jnp.where(keep_c[:, None], uplink, ops.row_gather(u_hat, idx))
-    u_hat_new = ops.row_scatter(u_hat, idx, uplink)
+    x_K = _cohort_steps(cfg, spec, grad_fn, per_step_batches, x_s_row,
+                        cohort_batch(batch, idx, m, per_step_batches), idx)
+    adm = admit(cfg, x_K, arena=spec, round_idx=state["round"], m=m,
+                ref=x_s_row, fallback=ops.row_gather(u_hat, idx), idx=idx)
+    u_hat_new = ops.row_scatter(u_hat, idx, adm.admitted)
     x_s_new = jnp.mean(u_hat_new, axis=0)  # <- the round's single all-reduce
     new_state = {
         "u_hat": u_hat_new,
         "x_s": spec.unpack(x_s_new),
         "round": state["round"] + 1,
     }
-    f32 = jnp.float32
-    metrics = {
-        "client_drift": T.masked_client_mean(
-            jnp.sum(jnp.square((x_K - x_s_row[None]).astype(f32)), axis=1),
-            keep_c),
-        "used_arena": jnp.ones((), f32),
-    }
-    if fplan is not None or keep is not None:
-        metrics |= faults.fault_metrics(
-            fplan, None if plan_c is None else ~plan_c.silent, keep)
-    return new_state, metrics
+    return new_state, arena_metrics(adm, x_K, x_s_row)
 
 
 def _round_arena(cfg: FederatedConfig, state, grad_fn, batch, per_step_batches):
@@ -155,52 +122,14 @@ def _round_arena(cfg: FederatedConfig, state, grad_fn, batch, per_step_batches):
     x_K = inner_steps_plain_arena(
         spec, grad_fn, x0, x_s_row, batch, K=K, eta=eta, per_step=per_step_batches,
     )
-
-    uplink = x_K
-    new_state = {}
-    u_hat = state.get("u_hat")  # arena-resident (m, width) or absent
-    if cfg.uplink_bits is not None:  # fused EF21: 2 passes instead of ~4
-        uplink = ops.ef21_update(uplink, u_hat, cfg.uplink_bits, spec.leaf_rows())
-    # robustness layer: inject -> participation -> screen -> combined select
-    fplan = faults.plan(cfg, state["round"], m)
-    uplink = faults.inject(cfg.faults, fplan, uplink)
-    pmask = None
-    if cfg.participation < 1.0:
-        pmask = T.participation_mask(
-            participation_key(cfg, state["round"]), m, cfg.participation
-        )
-    keep = None
-    if faults.screening_on(cfg):
-        keep = faults.screen_keep(cfg, uplink, x_s_row)
-    mask = faults.combine_mask(pmask, fplan, keep)
-    sm = {}
-    if faults.async_on(cfg):
-        # bounded-staleness engine: delayed rows buffer, arrivals mix into
-        # the cached server view (u_hat guaranteed: async carries the cache)
-        uplink, mask, stale_up, sm = staleness.step_arena(
-            cfg, fplan, uplink, u_hat, mask, state)
-        new_state |= stale_up
-    elif mask is not None:
-        # silent clients transmit nothing; the server keeps its cached view
-        uplink = jnp.where(mask[:, None], uplink, u_hat)
-    if u_hat is not None:
-        new_state["u_hat"] = uplink
-    x_s_new = jnp.mean(uplink, axis=0)  # <- the round's single all-reduce
-    new_state |= {"x_s": spec.unpack(x_s_new), "round": state["round"] + 1}
-    f32 = jnp.float32
-    metrics = {
-        # silent clients' x_K never enters the state: average the active set
-        "client_drift": T.masked_client_mean(
-            jnp.sum(jnp.square((x_K - x_s_row[None]).astype(f32)), axis=1),
-            mask),
-        "used_arena": jnp.ones((), f32),
-    }
-    if fplan is not None or keep is not None:
-        tx = faults.combine_mask(pmask, fplan, None)
-        if faults.async_on(cfg):
-            tx = staleness.fresh_mask(tx, fplan)
-        metrics |= faults.fault_metrics(fplan, tx, keep) | sm
-    return new_state, metrics
+    adm = admit(cfg, x_K, arena=spec, round_idx=state["round"], m=m,
+                ref=x_s_row, fallback=state.get("u_hat"), state=state)
+    x_s_new = jnp.mean(adm.admitted, axis=0)  # <- the round's single all-reduce
+    new_state = adm.state | {"x_s": spec.unpack(x_s_new),
+                             "round": state["round"] + 1}
+    if "u_hat" in state:
+        new_state["u_hat"] = adm.admitted
+    return new_state, arena_metrics(adm, x_K, x_s_row)
 
 
 def _round(cfg: FederatedConfig, state, grad_fn, batch, per_step_batches=False):
@@ -225,45 +154,18 @@ def _round(cfg: FederatedConfig, state, grad_fn, batch, per_step_batches=False):
     else:
         x_K, _ = jax.lax.scan(one_step, x_s_b, None, length=K)
 
-    uplink = x_K
-    new_state = {}
-    if cfg.uplink_bits is not None:  # beyond-paper: EF21 delta-quantised uplink
-        uplink = T.tree_quantize_delta(uplink, state["u_hat"], cfg.uplink_bits)
-    # robustness layer: inject -> participation -> screen -> combined select
-    fplan = faults.plan(cfg, state["round"], m)
-    uplink = faults.inject_tree(cfg.faults, fplan, uplink)
-    pmask = None
-    if cfg.participation < 1.0:
-        pmask = T.participation_mask(
-            participation_key(cfg, state["round"]), m, cfg.participation
-        )
-    keep = None
-    if faults.screening_on(cfg):
-        keep = faults.screen_keep_tree(cfg, uplink, x_s)
-    mask = faults.combine_mask(pmask, fplan, keep)
-    sm = {}
-    if faults.async_on(cfg):
-        # bounded-staleness engine: delayed rows buffer, arrivals mix
-        uplink, mask, stale_up, sm = staleness.step_tree(
-            cfg, fplan, uplink, state["u_hat"], mask, state)
-        new_state |= stale_up
-    elif mask is not None:
-        uplink = T.tree_select(mask, uplink, state["u_hat"])
+    adm = admit(cfg, x_K, arena=None, round_idx=state["round"], m=m, ref=x_s,
+                fallback=state.get("u_hat"), state=state)
+    new_state = adm.state | {"x_s": T.tree_client_mean(adm.admitted),
+                             "round": state["round"] + 1}
     if "u_hat" in state:
-        new_state["u_hat"] = uplink  # the server's per-client view
-    x_s_new = T.tree_client_mean(uplink)
-    new_state |= {"x_s": x_s_new, "round": state["round"] + 1}
+        new_state["u_hat"] = adm.admitted  # the server's per-client view
     metrics = {
         # silent clients' x_K never enters the state: average the active set
         "client_drift": T.masked_client_mean(
-            T.tree_client_sqnorms(T.tree_sub(x_K, x_s_b)), mask),
+            T.tree_client_sqnorms(T.tree_sub(x_K, x_s_b)), adm.mask),
         "used_arena": jnp.zeros((), jnp.float32),
-    }
-    if fplan is not None or keep is not None:
-        tx = faults.combine_mask(pmask, fplan, None)
-        if faults.async_on(cfg):
-            tx = staleness.fresh_mask(tx, fplan)
-        metrics |= faults.fault_metrics(fplan, tx, keep) | sm
+    } | adm.metrics
     return new_state, metrics
 
 

@@ -16,6 +16,17 @@ Per round r (client i, K inner steps, rho = 1/(K eta) by default):
 where xref_i = mean_k x_i^{r,k} (eq. 23, Alg. 1) or x_i^{r,K} (eq. 24,
 Remark 1) when ``use_avg=False``.
 
+The bodies here serve the whole family: with ``accel`` bound they run
+AGPDMM (Alg. 2, ``core.agpdmm``), which starts every round at the server
+row x_s^r and uplinks from x_i^{r,K}, so no x_c is stored, gathered,
+scattered or staged.  Each layout has one body: the stacked pytree
+(``_family_round``), the masked arena (``_round_arena``), the sampled
+cohort (``_round_arena_cohort``) and the host popstore
+(``popstore_body``).  What of the uplink reaches the server -- EF21, faults,
+participation, the screen, stale rows -- is decided in one place,
+``core.uplink.admit``; the bodies keep the family's own server step, the
+mean and the dual refresh (``server_tail``).
+
 Communication note (recorded in EXPERIMENTS.md): in the SPMD mapping the
 uplink-mean is one all-reduce of a single parameter-sized tensor; the downlink
 combination x_s - lam_{s|i}/rho is reconstructed client-locally, so GPDMM's
@@ -36,6 +47,7 @@ from repro.core.api import (
     FedOpt, affine_case, arena_grad, cohort_batch, map_clients, resolved_rho,
     run_cohort_inner, step_by_client, use_arena, use_cohort,
 )
+from repro.core.uplink import admit, participation_key
 from repro.kernels import ops
 
 
@@ -225,233 +237,132 @@ def avg_scale(spec, grad_fn, K, *, per_step, svrg=False):
     return None if affine is not None else 1.0 / K
 
 
-def uplink_ref(cfg: FederatedConfig, spec, grad_fn, per_step, x_K, x_sum):
+def uplink_ref(avg: bool, cfg: FederatedConfig, spec, grad_fn, per_step, x_K,
+               x_sum):
     """``(x_ref, scale)`` for ``ops.round_tail``: the average of the inner
     iterates (eq. 23) as ``inner_steps_arena``'s sum and its factor, or x_K
-    (eq. 24, ``use_avg=False``) as it is."""
-    if not cfg.use_avg:
+    (eq. 24: ``use_avg=False``, and AGPDMM) as it is."""
+    if not avg:
         return x_K, None
     return x_sum, avg_scale(spec, grad_fn, cfg.inner_steps, per_step=per_step,
                             svrg=cfg.variance_reduction == "svrg")
 
 
-def participation_key(cfg: FederatedConfig, round_idx):
-    """The round's participation RNG key: folded from ``cfg.seed``, so every
-    algorithm under comparison draws the SAME mask sequence by contract (the
-    old hard-coded key(17) made that an accident of duplication)."""
-    return jax.random.fold_in(jax.random.key(cfg.seed), round_idx)
-
-
-def arena_tail(cfg: FederatedConfig, spec, state, uplink, m):
-    """Shared GPDMM/AGPDMM arena round tail: fused EF21 quantise-delta,
-    fault injection + uplink screening (core.faults), the combined
-    participation/fault/screen select vs the u_hat cache, the single
-    client-mean all-reduce, and the fused dual refresh.  Returns
-    (state_updates, x_s_new_row, lam_s_new, mask, fault_metrics) -- ``mask``
-    is the round's effective active mask (None = every uplink entered the
-    mean); demoted and faulted clients are SILENT, full stop, so the round
-    is bit-identical to a participation-masked round with the same mask.
-
-    With the bounded-staleness engine on (``faults.async_on``) the select
-    against the cache routes through ``staleness.step_arena`` instead:
-    delayed rows are buffered, arriving stale rows mix into the cache with
-    their discounted weight, and the returned mask additionally excludes
-    delayed clients (their carry keeps, like a silent client's)."""
-    rho = resolved_rho(cfg)
-    new_state = {}
-    u_hat = state.get("u_hat")  # arena-resident (m, width) or absent
-    if cfg.uplink_bits is not None:  # fused EF21: 2 passes instead of ~4
-        uplink = ops.ef21_update(uplink, u_hat, cfg.uplink_bits, spec.leaf_rows())
-    # the wire corrupts what was TRANSMITTED, i.e. the EF21-integrated view
-    fplan = faults.plan(cfg, state["round"], m)
-    uplink = faults.inject(cfg.faults, fplan, uplink)
-    pmask = None
-    if cfg.participation < 1.0:
-        pmask = T.participation_mask(
-            participation_key(cfg, state["round"]), m, cfg.participation
-        )
-    keep = None
-    if faults.screening_on(cfg):
-        keep = faults.screen_keep(cfg, uplink, spec.pack(state["x_s"]))
-    mask = faults.combine_mask(pmask, fplan, keep)
-    sm = {}
-    if faults.async_on(cfg):
-        uplink, mask, stale_up, sm = staleness.step_arena(
-            cfg, fplan, uplink, u_hat, mask, state)
-        new_state |= stale_up
-    elif mask is not None:
-        uplink = jnp.where(mask[:, None], uplink, u_hat)
-    if u_hat is not None:
-        new_state["u_hat"] = uplink
+def server_tail(rho, uplink):
+    """The family's server step over the admitted ``(m, width)`` uplink
+    buffer: the round's single client-mean all-reduce and the fused dual
+    refresh.  Returns ``(x_s_new_row, lam_s_new)``."""
     with jax.named_scope("round.server_mean"):
         x_s_new = jnp.mean(uplink, axis=0)  # <- the round's single all-reduce
     # fused tail pass 2: lam' = rho (u - x_s'), server row broadcast in-kernel
     with jax.named_scope("round.dual_refresh"):
         lam_s_new = ops.dual_from_uplink(uplink, x_s_new, rho)
-    fm = {}
-    if fplan is not None or keep is not None:
-        tx = faults.combine_mask(pmask, fplan, None)
-        if faults.async_on(cfg):
-            # delayed clients transmit nothing fresh this round
-            tx = staleness.fresh_mask(tx, fplan)
-        fm = faults.fault_metrics(fplan, tx, keep) | sm
-    return new_state, x_s_new, lam_s_new, mask, fm
+    return x_s_new, lam_s_new
 
 
-def arena_metrics(lam_s_new, x_K, x_s_row, mask=None):
-    """KKT-invariant and drift metrics straight off the arena buffers;
-    padding columns are identically zero, so no masking is needed there.
-    ``client_drift`` averages over the ACTIVE cohort only (``mask``, or all
-    rows of ``x_K`` when None -- the cohort path passes its already-gathered
-    x_K): silent clients' x_K is computed-then-discarded on the masked path
-    (the carry is kept), so averaging it in reported movement that never
-    entered the state.  ``used_arena`` records the (static) layout decision
-    so benches can see which path a round actually ran."""
+def arena_metrics(adm, x_K, x_s_row, lam_s_new=None):
+    """Round metrics straight off the arena buffers, with the admission's
+    counters.  ``lam_sum_norm``, GPDMM's KKT invariant, where the round has
+    its dual (padding columns are identically zero, so no masking is needed
+    there).  ``client_drift`` averages over the ADMITTED rows only
+    (``adm.mask``, or every row of ``x_K`` when None): silent clients' x_K
+    is computed-then-discarded (the carry is kept), so averaging it in
+    would report movement that never entered the state.  ``used_arena``
+    records the (static) layout decision so benches can see which path a
+    round actually ran."""
     f32 = jnp.float32
     with jax.named_scope("round.metrics"):
-        return {
-            "lam_sum_norm": jnp.linalg.norm(jnp.sum(lam_s_new.astype(f32), axis=0)),
+        out = {} if lam_s_new is None else {
+            "lam_sum_norm": jnp.linalg.norm(jnp.sum(lam_s_new.astype(f32), axis=0))}
+        out |= {
             "client_drift": T.masked_client_mean(
-                jnp.sum(jnp.square((x_K - x_s_row[None]).astype(f32)), axis=1), mask
-            ),
+                jnp.sum(jnp.square((x_K - x_s_row[None]).astype(f32)), axis=1),
+                adm.mask),
             "used_arena": jnp.ones((), f32),
         }
+    return out | adm.metrics
 
 
-def cohort_tail(cfg: FederatedConfig, spec, state, uplink, idx, fplan=None):
-    """Shared GPDMM/AGPDMM cohort round tail (the cohort sibling of
-    ``arena_tail``): fused EF21 against the cohort's cached ``u_hat`` rows,
-    fault injection + screening on the cohort uplink, the scatter into the
-    population cache, the scattered-mean server update (the
-    ``(sum_active uplink + sum_silent u_hat) / m`` identity, computed as ONE
-    mean over the scattered buffer so it matches the masked path bitwise),
-    and the full dual refresh.  Returns ``({u_hat, x_s, lam_s}, keep_c,
-    fault_metrics)`` -- ``keep_c`` is the cohort-shaped surviving mask (None
-    = the whole cohort's uplink entered the cache).  Note the screening
-    median is taken over the COHORT, not the population."""
+def _kept(mask, x_K, x0):
+    """The new carry rows: silent and demoted clients did not really run
+    their inner steps, so they keep their round-start row."""
+    return x_K if mask is None else jnp.where(mask[:, None], x_K, x0)
+
+
+def _cohort_uplink(cfg: FederatedConfig, spec, grad_fn, per_step, accel,
+                   x_s_row, lam_c, x0_c, batch_c, idx):
+    """The cohort's inner loop and uplink, shared by the device cohort round
+    and the popstore body: ``(x_K, uplink)`` over the cohort's rows, tiled
+    by ``cohort_tile`` when set.  GPDMM starts from the cohort's carry rows
+    ``x0_c``; AGPDMM (``accel``) from the server row."""
     rho = resolved_rho(cfg)
-    u_hat = state["u_hat"]  # guaranteed: participation < 1 carries the cache
-    if cfg.uplink_bits is not None:  # EF21 on the cohort's cached rows only
-        uplink = ops.ef21_update(uplink, ops.row_gather(u_hat, idx),
-                                 cfg.uplink_bits, spec.leaf_rows())
-    plan_c = faults.take(fplan, idx)
-    uplink = faults.inject(cfg.faults, plan_c, uplink)
-    keep = None
-    if faults.screening_on(cfg):
-        keep = faults.screen_keep(cfg, uplink, spec.pack(state["x_s"]))
-    keep_c = faults.combine_mask(None, plan_c, keep)
-    if keep_c is not None:
-        uplink = jnp.where(keep_c[:, None], uplink, ops.row_gather(u_hat, idx))
-    u_hat_new = ops.row_scatter(u_hat, idx, uplink)
-    with jax.named_scope("round.server_mean"):
-        x_s_new = jnp.mean(u_hat_new, axis=0)  # <- the round's single all-reduce
-    with jax.named_scope("round.dual_refresh"):
-        lam_s_new = ops.dual_from_uplink(u_hat_new, x_s_new, rho)
-    fm = {}
-    if fplan is not None or keep is not None:
-        fm = faults.fault_metrics(
-            fplan, None if plan_c is None else ~plan_c.silent, keep)
-    return {
-        "u_hat": u_hat_new,
-        "x_s": spec.unpack(x_s_new),
-        "lam_s": lam_s_new,
-    }, keep_c, fm
-
-
-def popstore_tail(cfg: FederatedConfig, spec, x_s_row, u_hat_c, uplink, idx,
-                  round_idx, m):
-    """Cohort-resident round tail for the HOST-popstore path (shared by
-    GPDMM/AGPDMM/FedAvg): identical per-row math to ``cohort_tail`` --
-    fused EF21 against the STAGED cohort ``u_hat`` rows (the host store's
-    copy of exactly the rows ``cohort_tail`` would ``row_gather``), fault
-    injection + screening on the cohort uplink, and the combined keep-select
-    back to the staged rows.  What it does NOT do is the O(m) tail: no
-    scatter into a device-resident population buffer, no full-buffer mean,
-    no dense dual refresh -- the host driver (``core.popstore.Runner``)
-    scatters the returned rows into the host store and maintains the server
-    mean incrementally.  Returns ``(uplink, keep_c, fault_metrics)``."""
-    if cfg.uplink_bits is not None:
-        uplink = ops.ef21_update(uplink, u_hat_c, cfg.uplink_bits,
-                                 spec.leaf_rows())
-    fplan = faults.plan(cfg, round_idx, m)
-    plan_c = faults.take(fplan, idx)
-    uplink = faults.inject(cfg.faults, plan_c, uplink)
-    keep = None
-    if faults.screening_on(cfg):
-        keep = faults.screen_keep(cfg, uplink, x_s_row)
-    keep_c = faults.combine_mask(None, plan_c, keep)
-    if keep_c is not None:
-        # demoted/faulted cohort rows are silent: the store keeps their row
-        uplink = jnp.where(keep_c[:, None], uplink, u_hat_c)
-    fm = {}
-    if fplan is not None or keep is not None:
-        fm = faults.fault_metrics(
-            fplan, None if plan_c is None else ~plan_c.silent, keep)
-    return uplink, keep_c, fm
-
-
-def popstore_body(cfg: FederatedConfig, spec, m: int, grad_fn, per_step):
-    """Device half of a host-popstore GPDMM round (see ``core.popstore``).
-
-    The returned ``body(server, staged, idx, round_idx, batch)`` touches
-    ONLY O(cohort) device memory: ``staged`` carries the sampled rows of the
-    host store (``u_hat`` -- the server's cached uplink view -- and ``x_c``,
-    the primal carry), and the dual rows are reconstructed LAZILY via the
-    round invariant lam_{s|i} = rho (u_hat_i - x_s) (``ops.dual_from_uplink``
-    on the staged rows -- elementwise, so bit-identical to gathering rows of
-    the dense refresh the arena path materialises).  Returns
-    ``(rows_out, server_rows, metrics)`` where ``rows_out = {u_hat, x_c}``
-    scatters back into the host store."""
-    rho = resolved_rho(cfg)
-    K = cfg.inner_steps
-    f32 = jnp.float32
-
+    avg = cfg.use_avg and not accel
     eta_v = _eta_val(cfg.eta)
     per_client = np.ndim(eta_v) > 0
 
-    def body(server, staged, idx, round_idx, batch):
-        x_s_row = spec.pack(server["x_s"])
-        u_hat_c, x0_c = staged["u_hat"], staged["x_c"]
-        lam_c = ops.dual_from_uplink(u_hat_c, x_s_row, rho)  # lazy dual
-        batch_c = cohort_batch(batch, idx, m, per_step)
+    def inner(rows, b):
+        lam_t = rows[0]
+        # per-client eta rides the rows tuple so the cohort tiler slices it
+        # alongside the state rows (a closure capture would stay
+        # cohort-sized inside a tile-sized call)
+        eta_t = rows[1] if per_client else eta_v
+        x0 = jnp.broadcast_to(x_s_row[None], lam_t.shape) if accel else rows[-1]
+        snap = (jnp.broadcast_to(x_s_row[None], x0.shape)
+                if cfg.variance_reduction == "svrg" else None)
+        return inner_steps_arena(
+            spec, grad_fn, x0, x_s_row, lam_t, b, K=cfg.inner_steps, eta=eta_t,
+            rho=rho, per_step=per_step, vr_snapshot=snap, average=avg,
+        )
 
-        def inner(rows, b):
-            x0, lam_t = rows[0], rows[1]
-            # per-client eta rides the rows tuple so the cohort tiler slices
-            # it alongside the state rows (a closure capture would stay
-            # cohort-sized inside a tile-sized call)
-            eta_t = rows[2] if per_client else eta_v
-            snap = (jnp.broadcast_to(x_s_row[None], x0.shape)
-                    if cfg.variance_reduction == "svrg" else None)
-            return inner_steps_arena(
-                spec, grad_fn, x0, x_s_row, lam_t, b, K=K, eta=eta_t,
-                rho=rho, per_step=per_step, vr_snapshot=snap,
-                average=cfg.use_avg,
-            )
-
-        rows = (x0_c, lam_c) + (
-            (jnp.asarray(eta_v)[idx],) if per_client else ())
-        x_K, x_sum = run_cohort_inner(cfg, inner, rows, batch_c,
-                                      per_step=per_step)
-        x_ref, scale = uplink_ref(cfg, spec, grad_fn, per_step, x_K, x_sum)
+    rows = ((lam_c,) + ((jnp.asarray(eta_v)[idx],) if per_client else ())
+            + (() if accel else (x0_c,)))
+    x_K, x_sum = run_cohort_inner(cfg, inner, rows, batch_c, per_step=per_step)
+    x_ref, scale = uplink_ref(avg, cfg, spec, grad_fn, per_step, x_K, x_sum)
+    with jax.named_scope("round.uplink"):
         _, uplink = ops.round_tail(x_ref, lam_c, x_s_row, rho,
                                    with_lam_is=False, scale=scale)
-        uplink, keep_c, fm = popstore_tail(cfg, spec, x_s_row, u_hat_c,
-                                           uplink, idx, round_idx, m)
-        x_K_kept = (x_K if keep_c is None
-                    else jnp.where(keep_c[:, None], x_K, x0_c))
-        metrics = {
-            "client_drift": T.masked_client_mean(
-                jnp.sum(jnp.square((x_K - x_s_row[None]).astype(f32)),
-                        axis=1), keep_c),
-            "used_arena": jnp.ones((), f32),
-        } | fm
-        return {"u_hat": uplink, "x_c": x_K_kept}, {}, metrics
+    return x_K, uplink
+
+
+def popstore_body(cfg: FederatedConfig, spec, m: int, grad_fn, per_step,
+                  accel=False):
+    """Device half of a host-popstore GPDMM round (see ``core.popstore``);
+    ``accel`` makes it AGPDMM's.
+
+    The returned ``body(server, staged, idx, round_idx, batch)`` touches
+    ONLY O(cohort) device memory: ``staged`` carries the sampled rows of the
+    host store (``u_hat`` -- the server's cached uplink view -- and, for
+    GPDMM, ``x_c``, the primal carry), and the dual rows are reconstructed
+    LAZILY via the round invariant lam_{s|i} = rho (u_hat_i - x_s)
+    (``ops.dual_from_uplink`` on the staged rows -- elementwise, so
+    bit-identical to gathering rows of the dense refresh the arena path
+    materialises).  No O(m) tail runs here: the host driver
+    (``core.popstore.Runner``) scatters the returned rows into the store and
+    maintains the server mean incrementally.  Returns ``(rows_out,
+    server_rows, metrics)`` where ``rows_out`` (``u_hat``, and ``x_c`` for
+    GPDMM) scatters back into the host store."""
+    rho = resolved_rho(cfg)
+
+    def body(server, staged, idx, round_idx, batch):
+        x_s_row = spec.pack(server["x_s"])
+        u_hat_c = staged["u_hat"]
+        x0_c = None if accel else staged["x_c"]
+        lam_c = ops.dual_from_uplink(u_hat_c, x_s_row, rho)  # lazy dual
+        x_K, uplink = _cohort_uplink(
+            cfg, spec, grad_fn, per_step, accel, x_s_row, lam_c, x0_c,
+            cohort_batch(batch, idx, m, per_step), idx)
+        adm = admit(cfg, uplink, arena=spec, round_idx=round_idx, m=m,
+                    ref=x_s_row, fallback=u_hat_c, idx=idx)
+        rows_out = {"u_hat": adm.admitted}
+        if not accel:
+            rows_out["x_c"] = _kept(adm.mask, x_K, x0_c)
+        return rows_out, {}, arena_metrics(adm, x_K, x_s_row)
 
     return body
 
 
-def _round_arena_cohort(cfg: FederatedConfig, state, grad_fn, batch, per_step_batches):
+def _round_arena_cohort(cfg: FederatedConfig, state, grad_fn, batch,
+                        per_step_batches, accel):
     """GPDMM round over the SAMPLED COHORT (ISSUE 5): gather the round's
     active rows out of the population arena, run the fused inner loop +
     round tail on the ``(m_active, width)`` cohort buffer (tiled via
@@ -459,110 +370,103 @@ def _round_arena_cohort(cfg: FederatedConfig, state, grad_fn, batch, per_step_ba
     gradient-batch traffic scale with the cohort, not the population; the
     O(m) work that remains is inherent to the algorithm (every client's
     lam_{s|i} moves with the new x_s, and the server mean reads every cached
-    u_hat row).
+    u_hat row).  AGPDMM (``accel``) gathers and scatters no carry rows.
 
     Row-for-row identical to the masked path: the cohort rows see the same
     per-row kernels, and the server mean is taken over the SCATTERED
     population buffer -- the same mean-of-selected-rows the masked path
     computes, realising (sum_active uplink + sum_silent u_hat) / m without a
     reordered reduction (tests/test_cohort.py pins this per round)."""
-    rho = resolved_rho(cfg)
-    K = cfg.inner_steps
     spec = arena.ArenaSpec.from_tree(state["x_s"])
-    lam, x_c = state["lam_s"], state["x_c"]
+    lam, u_hat = state["lam_s"], state["u_hat"]  # participation < 1 caches
     m = lam.shape[0]
     x_s_row = spec.pack(state["x_s"])
-    idx, mask = T.cohort_indices(
+    idx, _mask = T.cohort_indices(
         participation_key(cfg, state["round"]), m, cfg.participation
     )
     lam_c = ops.row_gather(lam, idx)
-    x0_c = ops.row_gather(x_c, idx)
-    batch_c = cohort_batch(batch, idx, m, per_step_batches)
-    eta_v = _eta_val(cfg.eta)
-    per_client = np.ndim(eta_v) > 0
-
-    def inner(rows, b):
-        x0, lam_t = rows[0], rows[1]
-        eta_t = rows[2] if per_client else eta_v  # tiled with the state rows
-        snap = (jnp.broadcast_to(x_s_row[None], x0.shape)
-                if cfg.variance_reduction == "svrg" else None)
-        return inner_steps_arena(
-            spec, grad_fn, x0, x_s_row, lam_t, b, K=K, eta=eta_t, rho=rho,
-            per_step=per_step_batches, vr_snapshot=snap, average=cfg.use_avg,
-        )
-
-    rows = (x0_c, lam_c) + ((jnp.asarray(eta_v)[idx],) if per_client else ())
-    x_K, x_sum = run_cohort_inner(cfg, inner, rows, batch_c,
-                                  per_step=per_step_batches)
-    x_ref, scale = uplink_ref(cfg, spec, grad_fn, per_step_batches, x_K, x_sum)
-
-    with jax.named_scope("round.uplink"):
-        _, uplink = ops.round_tail(x_ref, lam_c, x_s_row, rho,
-                                   with_lam_is=False, scale=scale)
-    fplan = faults.plan(cfg, state["round"], m)
-    new_state, keep_c, fm = cohort_tail(cfg, spec, state, uplink, idx, fplan)
-    # demoted cohort rows are silent, full stop: the carry keeps its
-    # round-start row exactly as a never-sampled client's does
-    x_K_kept = x_K if keep_c is None else jnp.where(keep_c[:, None], x_K, x0_c)
-    new_state |= {
-        "x_c": ops.row_scatter(x_c, idx, x_K_kept),  # silent clients keep carry
+    x0_c = None if accel else ops.row_gather(state["x_c"], idx)
+    x_K, uplink = _cohort_uplink(
+        cfg, spec, grad_fn, per_step_batches, accel, x_s_row, lam_c, x0_c,
+        cohort_batch(batch, idx, m, per_step_batches), idx)
+    adm = admit(cfg, uplink, arena=spec, round_idx=state["round"], m=m,
+                ref=x_s_row, fallback=ops.row_gather(u_hat, idx), idx=idx)
+    u_hat_new = ops.row_scatter(u_hat, idx, adm.admitted)
+    # ONE mean over the scattered buffer, so it matches the masked path
+    x_s_new, lam_s_new = server_tail(resolved_rho(cfg), u_hat_new)
+    new_state = {
+        "u_hat": u_hat_new,
+        "x_s": spec.unpack(x_s_new),
+        "lam_s": lam_s_new,
         "round": state["round"] + 1,
     }
-    return new_state, arena_metrics(new_state["lam_s"], x_K, x_s_row, keep_c) | fm
+    if not accel:
+        # demoted cohort rows are silent, full stop: the carry keeps its
+        # round-start row exactly as a never-sampled client's does
+        new_state["x_c"] = ops.row_scatter(state["x_c"], idx,
+                                           _kept(adm.mask, x_K, x0_c))
+    return new_state, arena_metrics(adm, x_K, x_s_row, lam_s_new)
 
 
-def _round_arena(cfg: FederatedConfig, state, grad_fn, batch, per_step_batches, return_trace):
+def _round_arena(cfg: FederatedConfig, state, grad_fn, batch, per_step_batches,
+                 return_trace, accel):
     """GPDMM round over the flat arena: the tail is 3 fused kernels + the
     single client-mean all-reduce instead of ~6 per-leaf pytree passes.
 
     The stacked hot state (lam_s, x_c, u_hat) is arena-RESIDENT: it enters
     and leaves the round as ``(m, width)`` buffers (donated in place by the
     launchers), so the only per-round layout work is packing the
-    server-sized x_s row -- 1/m of the state."""
+    server-sized x_s row -- 1/m of the state.  AGPDMM (``accel``) starts
+    every row at the server row and keeps no x_c."""
     rho = resolved_rho(cfg)
     K = cfg.inner_steps
     spec = arena.ArenaSpec.from_tree(state["x_s"])
     lam = state["lam_s"]
-    x_c = state["x_c"]
     m = lam.shape[0]
     if use_cohort(cfg, m) and not return_trace:
         # trace consumers need the full-population x_K/x_ref stacking, so
         # traced rounds stay on the masked path
-        return _round_arena_cohort(cfg, state, grad_fn, batch, per_step_batches)
+        return _round_arena_cohort(cfg, state, grad_fn, batch, per_step_batches,
+                                   accel)
     x_s_row = spec.pack(state["x_s"])
+    avg = cfg.use_avg and not accel
+    x0 = jnp.broadcast_to(x_s_row[None], (m, spec.width)) if accel else state["x_c"]
 
     snapshot = None
     if cfg.variance_reduction == "svrg":
-        snapshot = jnp.broadcast_to(x_s_row[None], x_c.shape)
+        snapshot = jnp.broadcast_to(x_s_row[None], x0.shape)
     x_K, x_sum = inner_steps_arena(
-        spec, grad_fn, x_c, x_s_row, lam, batch, K=K, eta=cfg.eta, rho=rho,
+        spec, grad_fn, x0, x_s_row, lam, batch, K=K, eta=cfg.eta, rho=rho,
         per_step=per_step_batches, vr_snapshot=snapshot,
-        average=cfg.use_avg or return_trace,
+        average=avg or return_trace,
     )
-    x_ref, scale = uplink_ref(cfg, spec, grad_fn, per_step_batches, x_K, x_sum)
+    x_ref, scale = uplink_ref(avg, cfg, spec, grad_fn, per_step_batches, x_K,
+                              x_sum)
 
     # fused tail pass 1: the uplink (and lam_is only when a trace wants it --
     # 3 reads + 1 write on the training path, +1 write with the trace)
     with jax.named_scope("round.uplink"):
         lam_is, uplink = ops.round_tail(x_ref, lam, x_s_row, rho,
                                         with_lam_is=return_trace, scale=scale)
-    new_state, x_s_new, lam_s_new, mask, fm = arena_tail(cfg, spec, state, uplink, m)
-
-    # silent clients did not really run their inner steps: keep their carry
-    x_c_new = x_K if mask is None else jnp.where(mask[:, None], x_K, x_c)
-    new_state |= {
+    adm = admit(cfg, uplink, arena=spec, round_idx=state["round"], m=m,
+                ref=x_s_row, fallback=state.get("u_hat"), state=state)
+    x_s_new, lam_s_new = server_tail(rho, adm.admitted)
+    new_state = adm.state | {
         "x_s": spec.unpack(x_s_new),  # server-sized; clients stay packed
         "lam_s": lam_s_new,
-        "x_c": x_c_new,
         "round": state["round"] + 1,
     }
-    metrics = arena_metrics(lam_s_new, x_K, x_s_row, mask) | fm
+    if "u_hat" in state:
+        new_state["u_hat"] = adm.admitted
+    if not accel:
+        new_state["x_c"] = _kept(adm.mask, x_K, x0)
+    metrics = arena_metrics(adm, x_K, x_s_row, lam_s_new)
     if return_trace:
         s_bar = avg_scale(spec, grad_fn, K, per_step=per_step_batches,
                           svrg=snapshot is not None)
         x_bar = x_sum if s_bar is None else x_sum * s_bar
         metrics["trace"] = {
-            "x_ref": spec.unpack_stacked(x_bar if cfg.use_avg else x_K),
+            "x_ref": spec.unpack_stacked(x_bar if avg else x_K),
             "x_bar": spec.unpack_stacked(x_bar),
             "lam_is": spec.unpack_stacked(lam_is),
             "x_K": spec.unpack_stacked(x_K),
@@ -570,82 +474,74 @@ def _round_arena(cfg: FederatedConfig, state, grad_fn, batch, per_step_batches, 
     return new_state, metrics
 
 
-def _round(cfg: FederatedConfig, state, grad_fn, batch, per_step_batches=False, return_trace=False):
+def _family_round(accel, cfg: FederatedConfig, state, grad_fn, batch,
+                  per_step_batches=False, return_trace=False):
+    """One round of the GPDMM family, on the layout ``use_arena`` picks.
+    ``accel`` selects AGPDMM (Alg. 2): each round starts at the server row
+    and uplinks from x_K (eq. 24), so no x_c is kept; otherwise GPDMM
+    (Alg. 1) starts from the carried x_c."""
     if use_arena(cfg, state["x_s"]):
-        return _round_arena(cfg, state, grad_fn, batch, per_step_batches, return_trace)
+        return _round_arena(cfg, state, grad_fn, batch, per_step_batches,
+                            return_trace, accel)
     rho = resolved_rho(cfg)
     K = cfg.inner_steps
-    x_s, lam_s, x_c = state["x_s"], state["lam_s"], state["x_c"]
+    x_s, lam_s = state["x_s"], state["lam_s"]
     m = jax.tree.leaves(lam_s)[0].shape[0]
     x_s_b = T.tree_broadcast(x_s, m)
+    x0 = x_s_b if accel else state["x_c"]
 
     x_K, x_bar = inner_steps(
-        grad_fn, x_c, x_s_b, lam_s, batch, K=K, eta=cfg.eta, rho=rho,
+        grad_fn, x0, x_s_b, lam_s, batch, K=K, eta=cfg.eta, rho=rho,
         per_step=per_step_batches,
         vr_snapshot=x_s_b if cfg.variance_reduction == "svrg" else None,
     )
-    x_ref = x_bar if cfg.use_avg else x_K
+    x_ref = x_bar if cfg.use_avg and not accel else x_K
 
     lam_is = T.tmap(lambda s, xr, l: rho * (s - xr) - l, x_s_b, x_ref, lam_s)
     uplink = T.tmap(lambda xr, l: xr - l / rho, x_ref, lam_is)
-    new_state = {}
-    if cfg.uplink_bits is not None:  # beyond-paper: EF21 delta-quantised uplink
-        uplink = T.tree_quantize_delta(uplink, state["u_hat"], cfg.uplink_bits)
-    # the robustness layer is layout-independent: the same inject ->
-    # participation -> screen -> combined-select pipeline as arena_tail
-    fplan = faults.plan(cfg, state["round"], m)
-    uplink = faults.inject_tree(cfg.faults, fplan, uplink)
-    pmask = None
-    if cfg.participation < 1.0:  # beyond-paper: async PDMM (partial rounds)
-        pmask = T.participation_mask(
-            participation_key(cfg, state["round"]), m, cfg.participation
-        )
-    keep = None
-    if faults.screening_on(cfg):
-        keep = faults.screen_keep_tree(cfg, uplink, x_s)
-    mask = faults.combine_mask(pmask, fplan, keep)
-    sm = {}
-    if faults.async_on(cfg):
-        # bounded-staleness engine: delayed rows buffer, arrivals mix
-        uplink, mask, stale_up, sm = staleness.step_tree(
-            cfg, fplan, uplink, state["u_hat"], mask, state)
-        new_state |= stale_up
-    elif mask is not None:
-        # silent clients transmit nothing; the server keeps its cached view
-        uplink = T.tree_select(mask, uplink, state["u_hat"])
-    if "u_hat" in state:
-        new_state["u_hat"] = uplink  # the server's per-client view
-    x_s_new = T.tree_client_mean(uplink)  # <- the round's single all-reduce
+    adm = admit(cfg, uplink, arena=None, round_idx=state["round"], m=m,
+                ref=x_s, fallback=state.get("u_hat"), state=state)
+    x_s_new = T.tree_client_mean(adm.admitted)  # <- the round's single all-reduce
     x_s_new_b = T.tree_broadcast(x_s_new, m)
     # lam_{s|i}^{r+1} = rho (x_ref - x_s) - lam_{i|s} == rho (u_i - x_s):
     # reconstructed from the TRANSMITTED uplink, so the quantised variant
     # stays faithful to what a real server would see (it cannot separate
     # x_ref from lam_{i|s} inside u_i).
-    lam_s_new = T.tmap(lambda u, s: rho * (u - s), uplink, x_s_new_b)
+    lam_s_new = T.tmap(lambda u, s: rho * (u - s), adm.admitted, x_s_new_b)
 
-    # silent clients did not really run their inner steps: keep their carry
-    x_c_new = x_K if mask is None else T.tree_select(mask, x_K, x_c)
-    new_state |= {"x_s": x_s_new, "lam_s": lam_s_new, "x_c": x_c_new, "round": state["round"] + 1}
+    new_state = adm.state | {"x_s": x_s_new, "lam_s": lam_s_new,
+                             "round": state["round"] + 1}
+    if "u_hat" in state:
+        new_state["u_hat"] = adm.admitted  # the server's per-client view
+    if not accel:
+        # silent clients did not really run their inner steps: keep their carry
+        new_state["x_c"] = (x_K if adm.mask is None
+                            else T.tree_select(adm.mask, x_K, state["x_c"]))
     metrics = {
         # KKT invariant (25): sum_i lam_{s|i} == 0 identically
         "lam_sum_norm": T.tree_norm(T.tree_client_sum(lam_s_new)),
         # silent clients keep their carry, so drift averages the ACTIVE set
         "client_drift": T.masked_client_mean(
-            T.tree_client_sqnorms(T.tree_sub(x_K, x_s_b)), mask),
+            T.tree_client_sqnorms(T.tree_sub(x_K, x_s_b)), adm.mask),
         "used_arena": jnp.zeros((), jnp.float32),
-    }
-    if fplan is not None or keep is not None:
-        tx = faults.combine_mask(pmask, fplan, None)
-        if faults.async_on(cfg):
-            tx = staleness.fresh_mask(tx, fplan)
-        metrics |= faults.fault_metrics(fplan, tx, keep) | sm
+    } | adm.metrics
     if return_trace:  # quantities the convergence-theory checks need
         metrics["trace"] = {"x_ref": x_ref, "x_bar": x_bar, "lam_is": lam_is, "x_K": x_K}
     return new_state, metrics
 
 
-def make(cfg: FederatedConfig) -> FedOpt:
+def _round(cfg: FederatedConfig, state, grad_fn, batch, per_step_batches=False, return_trace=False):
+    """GPDMM's round (Alg. 1): the family round from the carried x_c."""
+    return _family_round(False, cfg, state, grad_fn, batch, per_step_batches,
+                         return_trace)
+
+
+def make(cfg: FederatedConfig, *, accel=False) -> FedOpt:
+    """GPDMM's optimiser; ``accel=True`` makes AGPDMM's (``core.agpdmm``),
+    whose state has no x_c."""
     def init(params, m):
+        cache = (cfg.uplink_bits is not None or cfg.participation < 1.0
+                 or faults.needs_cache(cfg))
         if use_arena(cfg, params):
             # arena-resident client state: one (m, width) buffer per stacked
             # tensor, donated in place round over round; x_s stays a pytree
@@ -655,11 +551,11 @@ def make(cfg: FederatedConfig) -> FedOpt:
             st = {
                 "x_s": params,
                 "lam_s": arena.zeros(spec, m),
-                "x_c": jnp.broadcast_to(row[None], (m, spec.width)),
                 "round": jnp.zeros((), jnp.int32),
             }
-            if (cfg.uplink_bits is not None or cfg.participation < 1.0
-                    or faults.needs_cache(cfg)):
+            if not accel:
+                st["x_c"] = jnp.broadcast_to(row[None], (m, spec.width))
+            if cache:
                 st["u_hat"] = jnp.broadcast_to(row[None], (m, spec.width))
             if faults.async_on(cfg):
                 st |= staleness.init_arena(spec, m)
@@ -667,11 +563,11 @@ def make(cfg: FederatedConfig) -> FedOpt:
         st = {
             "x_s": params,
             "lam_s": T.tree_zeros_like(T.tree_broadcast(params, m)),
-            "x_c": T.tree_broadcast(params, m),  # x_i^{0,K} = x_s^1 (Alg. 1)
             "round": jnp.zeros((), jnp.int32),
         }
-        if (cfg.uplink_bits is not None or cfg.participation < 1.0
-                or faults.needs_cache(cfg)):
+        if not accel:
+            st["x_c"] = T.tree_broadcast(params, m)  # x_i^{0,K} = x_s^1 (Alg. 1)
+        if cache:
             # server's running view of each client's uplink (EF21 integrator /
             # async-PDMM cache / fault-silence fallback); init == round-0
             # uplink x_c - 0/rho.  A fresh broadcast, NOT an alias of x_c:
@@ -684,6 +580,6 @@ def make(cfg: FederatedConfig) -> FedOpt:
     return FedOpt(
         name="gpdmm",
         init=init,
-        round=partial(_round, cfg),
+        round=partial(_family_round, True, cfg) if accel else partial(_round, cfg),
         server_params=lambda s: s["x_s"],
     )

@@ -48,6 +48,7 @@ unaffected).
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Optional
 
 import jax
@@ -55,15 +56,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import FederatedConfig
-from repro.core import agpdmm, arena, fedavg, gpdmm, scaffold
+from repro.core import arena, fedavg, gpdmm, scaffold
 from repro.core import tree_util as T
 from repro.core.api import resolved_rho, use_cohort
-from repro.core.gpdmm import participation_key
+from repro.core.uplink import participation_key
 from repro.telemetry import spans as _spans
 
 _BODY_FACTORY = {
     "gpdmm": gpdmm.popstore_body,
-    "agpdmm": agpdmm.popstore_body,
+    "agpdmm": partial(gpdmm.popstore_body, accel=True),
     "scaffold": scaffold.popstore_body,
     "fedavg": fedavg.popstore_body,
 }

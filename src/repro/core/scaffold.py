@@ -45,7 +45,8 @@ from repro.core.api import (
     FedOpt, affine_case, arena_grad, cohort_batch, map_clients,
     run_cohort_inner, use_arena, use_cohort,
 )
-from repro.core.gpdmm import _eta_val, _step_for, participation_key
+from repro.core.gpdmm import _eta_val, _kept, _step_for, arena_metrics
+from repro.core.uplink import admit, participation_key
 from repro.kernels import ops
 
 
@@ -99,6 +100,50 @@ def inner_steps_plain_arena(spec, grad_fn, x0, x_s_row, batch, *, K, eta,
     return x_K
 
 
+def _cohort_round(cfg: FederatedConfig, spec, grad_fn, per_step, m, x_s_row,
+                  c_row, c_i_c, batch_c, idx, round_idx):
+    """SCAFFOLD over one cohort, shared by the device cohort round and the
+    popstore body: the offset inner loop, the fused control-variate refresh
+    and both server all-reduces over the cohort's deltas.  Silent clients
+    transmit nothing, so both server means decompose as sum_active(delta) /
+    m -- the same zero-delta contract the masked path realises with selects
+    (equal at f32: the masked path subtracts the server row back out of the
+    mean, this path never adds it in).  Returns ``(c_i_new_c, x_s_new_row,
+    c_new_row, x_K, admission)``."""
+    K, eta = cfg.inner_steps, _eta_val(cfg.eta)
+    per_client = np.ndim(eta) > 0
+    eta_c = jnp.asarray(eta)[idx] if per_client else None
+
+    def inner(rows, b):
+        ci_t = rows[0]
+        eta_t = rows[1] if per_client else eta  # tiled with the state rows
+        x0 = jnp.broadcast_to(x_s_row[None], ci_t.shape)
+        return inner_steps_plain_arena(
+            spec, grad_fn, x0, x_s_row, b, K=K, eta=eta_t,
+            per_step=per_step, c_i=ci_t, c_row=c_row,
+        )
+
+    rows = (c_i_c,) + ((eta_c,) if per_client else ())
+    x_K = run_cohort_inner(cfg, inner, rows, batch_c, per_step=per_step)
+    # the wire corrupts the transmitted packet x_i^{r,K}; both uplinked
+    # variables (dx_i and dc_i) derive from it, so both see the corruption
+    adm = admit(cfg, x_K, arena=spec, round_idx=round_idx, m=m, ref=x_s_row,
+                fallback=x_s_row, idx=idx)
+    # fused per-cohort tail: c_i' = c_i - c + (x_s - x_t)/(K eta_i)
+    alpha = 1.0 / (K * (eta_c if per_client else eta))
+    c_i_new_c = _kept(adm.mask,
+                      ops.scaffold_cv(c_i_c, adm.sent, c_row, x_s_row, alpha),
+                      c_i_c)
+    # server: TWO all-reduces over the cohort's deltas (silent rows are zero)
+    f32 = jnp.float32
+    inv_m = 1.0 / m
+    x_s_new = x_s_row + cfg.eta_g * inv_m * jnp.sum(
+        (adm.admitted - x_s_row[None]).astype(f32), axis=0).astype(x_s_row.dtype)
+    c_new = c_row + inv_m * jnp.sum(
+        (c_i_new_c - c_i_c).astype(f32), axis=0).astype(c_row.dtype)
+    return c_i_new_c, x_s_new, c_new, x_K, adm
+
+
 def popstore_body(cfg: FederatedConfig, spec, m: int, grad_fn, per_step):
     """Device half of a host-popstore SCAFFOLD round (see
     gpdmm.popstore_body): the cohort's ``c_i`` rows stage from the host
@@ -108,58 +153,15 @@ def popstore_body(cfg: FederatedConfig, spec, m: int, grad_fn, per_step):
     ``_round_arena_cohort`` -- and returns them in ``server_rows``; only the
     ``c_sum_norm`` diagnostic needs the host driver's incremental
     ``sum(c_i)``."""
-    K, eta = cfg.inner_steps, _eta_val(cfg.eta)
-    per_client = np.ndim(eta) > 0
-    f32 = jnp.float32
 
     def body(server, staged, idx, round_idx, batch):
         x_s_row = spec.pack(server["x_s"])
-        c_row = spec.pack(server["c"])
-        c_i_c = staged["c_i"]
-        batch_c = cohort_batch(batch, idx, m, per_step)
-        eta_c = jnp.asarray(eta)[idx] if per_client else None
-
-        def inner(rows, b):
-            ci_t = rows[0]
-            eta_t = rows[1] if per_client else eta  # tiled with the rows
-            x0 = jnp.broadcast_to(x_s_row[None], ci_t.shape)
-            return inner_steps_plain_arena(
-                spec, grad_fn, x0, x_s_row, b, K=K, eta=eta_t,
-                per_step=per_step, c_i=ci_t, c_row=c_row,
-            )
-
-        rows = (c_i_c,) + ((eta_c,) if per_client else ())
-        x_K = run_cohort_inner(cfg, inner, rows, batch_c,
-                               per_step=per_step)
-
-        fplan = faults.plan(cfg, round_idx, m)
-        plan_c = faults.take(fplan, idx)
-        x_t = faults.inject(cfg.faults, plan_c, x_K)
-        alpha = 1.0 / (K * (eta_c if per_client else eta))
-        c_i_new_c = ops.scaffold_cv(c_i_c, x_t, c_row, x_s_row, alpha)
-        keep = None
-        if faults.screening_on(cfg):
-            keep = faults.screen_keep(cfg, x_t, x_s_row)
-        keep_c = faults.combine_mask(None, plan_c, keep)
-        if keep_c is not None:
-            c_i_new_c = jnp.where(keep_c[:, None], c_i_new_c, c_i_c)
-            x_t = jnp.where(keep_c[:, None], x_t, x_s_row[None])
-        inv_m = 1.0 / m
-        x_s_new = x_s_row + cfg.eta_g * inv_m * jnp.sum(
-            (x_t - x_s_row[None]).astype(f32), axis=0).astype(x_s_row.dtype)
-        c_new = c_row + inv_m * jnp.sum(
-            (c_i_new_c - c_i_c).astype(f32), axis=0).astype(c_row.dtype)
-        metrics = {
-            "client_drift": T.masked_client_mean(
-                jnp.sum(jnp.square((x_K - x_s_row[None]).astype(f32)),
-                        axis=1), keep_c),
-            "used_arena": jnp.ones((), f32),
-        }
-        if fplan is not None or keep is not None:
-            metrics |= faults.fault_metrics(
-                fplan, None if plan_c is None else ~plan_c.silent, keep)
-        return ({"c_i": c_i_new_c},
-                {"x_s": x_s_new, "c": c_new}, metrics)
+        c_i_new_c, x_s_new, c_new, x_K, adm = _cohort_round(
+            cfg, spec, grad_fn, per_step, m, x_s_row, spec.pack(server["c"]),
+            staged["c_i"], cohort_batch(batch, idx, m, per_step), idx,
+            round_idx)
+        return ({"c_i": c_i_new_c}, {"x_s": x_s_new, "c": c_new},
+                arena_metrics(adm, x_K, x_s_row))
 
     return body
 
@@ -167,60 +169,18 @@ def popstore_body(cfg: FederatedConfig, spec, m: int, grad_fn, per_step):
 def _round_arena_cohort(cfg: FederatedConfig, state, grad_fn, batch, per_step_batches):
     """SCAFFOLD round over the sampled cohort (see gpdmm._round_arena_cohort):
     the cohort's c_i rows gather, run the offset inner loop + fused
-    control-variate refresh, and scatter back.  Silent clients transmit
-    nothing, so both server means decompose as sum_active(delta) / m -- the
-    same zero-delta contract the masked path realises with selects (equal at
-    f32: the masked path subtracts the server row back out of the mean, this
-    path never adds it in)."""
-    K, eta = cfg.inner_steps, _eta_val(cfg.eta)
-    per_client = np.ndim(eta) > 0
+    control-variate refresh (``_cohort_round``), and scatter back."""
     spec = arena.ArenaSpec.from_tree(state["x_s"])
     c_i = state["c_i"]
     m = c_i.shape[0]
     x_s_row = spec.pack(state["x_s"])
-    c_row = spec.pack(state["c"])
     idx, _mask = T.cohort_indices(
         participation_key(cfg, state["round"]), m, cfg.participation
     )
-    c_i_c = ops.row_gather(c_i, idx)
-    batch_c = cohort_batch(batch, idx, m, per_step_batches)
-    eta_c = jnp.asarray(eta)[idx] if per_client else None
-
-    def inner(rows, b):
-        ci_t = rows[0]
-        eta_t = rows[1] if per_client else eta  # tiled with the state rows
-        x0 = jnp.broadcast_to(x_s_row[None], ci_t.shape)
-        return inner_steps_plain_arena(
-            spec, grad_fn, x0, x_s_row, b, K=K, eta=eta_t,
-            per_step=per_step_batches, c_i=ci_t, c_row=c_row,
-        )
-
-    rows = (c_i_c,) + ((eta_c,) if per_client else ())
-    x_K = run_cohort_inner(cfg, inner, rows, batch_c,
-                           per_step=per_step_batches)
-
-    # the wire corrupts the transmitted packet x_i^{r,K}; both uplinked
-    # variables (dx_i and dc_i) derive from it, so both see the corruption
-    fplan = faults.plan(cfg, state["round"], m)
-    plan_c = faults.take(fplan, idx)
-    x_t = faults.inject(cfg.faults, plan_c, x_K)
-    # fused per-cohort tail: c_i' = c_i - c + (x_s - x_t)/(K eta_i)
-    alpha = 1.0 / (K * (eta_c if per_client else eta))
-    c_i_new_c = ops.scaffold_cv(c_i_c, x_t, c_row, x_s_row, alpha)
-    keep = None
-    if faults.screening_on(cfg):
-        keep = faults.screen_keep(cfg, x_t, x_s_row)
-    keep_c = faults.combine_mask(None, plan_c, keep)
-    if keep_c is not None:
-        # demoted/silent cohort rows: zero delta on both means, c_i kept
-        c_i_new_c = jnp.where(keep_c[:, None], c_i_new_c, c_i_c)
-        x_t = jnp.where(keep_c[:, None], x_t, x_s_row[None])
-    # server: TWO all-reduces over the cohort's deltas (silent rows are zero)
-    inv_m = 1.0 / m
-    x_s_new = x_s_row + cfg.eta_g * inv_m * jnp.sum(
-        (x_t - x_s_row[None]).astype(jnp.float32), axis=0).astype(x_s_row.dtype)
-    c_new = c_row + inv_m * jnp.sum(
-        (c_i_new_c - c_i_c).astype(jnp.float32), axis=0).astype(c_row.dtype)
+    c_i_new_c, x_s_new, c_new, x_K, adm = _cohort_round(
+        cfg, spec, grad_fn, per_step_batches, m, x_s_row,
+        spec.pack(state["c"]), ops.row_gather(c_i, idx),
+        cohort_batch(batch, idx, m, per_step_batches), idx, state["round"])
     c_i_new = ops.row_scatter(c_i, idx, c_i_new_c)  # silent clients keep c_i
 
     new_state = {
@@ -229,19 +189,10 @@ def _round_arena_cohort(cfg: FederatedConfig, state, grad_fn, batch, per_step_ba
         "c_i": c_i_new,
         "round": state["round"] + 1,
     }
-    f32 = jnp.float32
-    metrics = {
+    return new_state, {
         "c_sum_norm": jnp.linalg.norm(
-            jnp.sum((c_i_new - c_new[None]).astype(f32), axis=0)),
-        "client_drift": T.masked_client_mean(
-            jnp.sum(jnp.square((x_K - x_s_row[None]).astype(f32)), axis=1),
-            keep_c),
-        "used_arena": jnp.ones((), f32),
-    }
-    if fplan is not None or keep is not None:
-        metrics |= faults.fault_metrics(
-            fplan, None if plan_c is None else ~plan_c.silent, keep)
-    return new_state, metrics
+            jnp.sum((c_i_new - c_new[None]).astype(jnp.float32), axis=0)),
+    } | arena_metrics(adm, x_K, x_s_row)
 
 
 def _round_arena(cfg: FederatedConfig, state, grad_fn, batch, per_step_batches):
@@ -265,66 +216,34 @@ def _round_arena(cfg: FederatedConfig, state, grad_fn, batch, per_step_batches):
     )
 
     # the wire corrupts the transmitted packet x_i^{r,K}; both uplinked
-    # variables (dx_i and dc_i) derive from it, so both see the corruption
-    fplan = faults.plan(cfg, state["round"], m)
-    x_t = faults.inject(cfg.faults, fplan, x_K)
-    # fused per-client tail: c_i' = c_i - c + (x_s - x_t)/(K eta_i)
-    c_i_new = ops.scaffold_cv(c_i, x_t, c_row, x_s_row, 1.0 / (K * eta))
-    x_up = x_t
-    pmask = None
-    if cfg.participation < 1.0:
-        pmask = T.participation_mask(
-            participation_key(cfg, state["round"]), m, cfg.participation
-        )
-    keep = None
-    if faults.screening_on(cfg):
-        keep = faults.screen_keep(cfg, x_t, x_s_row)
-    mask = faults.combine_mask(pmask, fplan, keep)
-    sm = {}
-    stale_up = {}
-    if faults.async_on(cfg):
-        # bounded-staleness engine: the fresh-select baseline is the
-        # zero-delta server row; a buffered x_t lands s rounds later and
-        # mixes toward it with weight gamma**s.  The control variate
-        # refreshes on FRESH participation only -- an arriving stale row
-        # carries no variate update
-        x_up, mask, stale_up, sm = staleness.step_arena(
-            cfg, fplan, x_t, x_s_row, mask, state)
-        c_i_new = jnp.where(mask[:, None], c_i_new, c_i)
-    elif mask is not None:
-        # silent/demoted clients transmit nothing: zero delta on both server
-        # means, control variate kept
-        c_i_new = jnp.where(mask[:, None], c_i_new, c_i)
-        x_up = jnp.where(mask[:, None], x_t, x_s_row[None])
+    # variables (dx_i and dc_i) derive from it, so both see the corruption.
+    # Silent/demoted clients transmit nothing: the zero-delta server row
+    # stands in, and under the staleness engine a buffered row lands s
+    # rounds later and mixes toward it with weight gamma**s
+    adm = admit(cfg, x_K, arena=spec, round_idx=state["round"], m=m,
+                ref=x_s_row, fallback=x_s_row, state=state)
+    # fused per-client tail: c_i' = c_i - c + (x_s - x_t)/(K eta_i); the
+    # control variate refreshes on FRESH participation only -- an arriving
+    # stale row carries no variate update
+    c_i_new = _kept(adm.mask,
+                    ops.scaffold_cv(c_i, adm.sent, c_row, x_s_row, 1.0 / (K * eta)),
+                    c_i)
     # server: TWO all-reduces (x-delta and c-delta)
-    x_s_new = x_s_row + cfg.eta_g * (jnp.mean(x_up, axis=0) - x_s_row)
+    x_s_new = x_s_row + cfg.eta_g * (jnp.mean(adm.admitted, axis=0) - x_s_row)
     c_new = c_row + jnp.mean(c_i_new - c_i, axis=0)
 
-    new_state = {
+    new_state = adm.state | {
         "x_s": spec.unpack(x_s_new),  # server-sized; clients stay packed
         "c": spec.unpack(c_new),
         "c_i": c_i_new,
         "round": state["round"] + 1,
-        **stale_up,
     }
-    f32 = jnp.float32
-    metrics = {
+    return new_state, {
         # invariant: sum_i (c_i - c) = 0 given zero init (padding is zero on
         # both sides, so no masking is needed)
         "c_sum_norm": jnp.linalg.norm(
-            jnp.sum((c_i_new - c_new[None]).astype(f32), axis=0)),
-        # silent clients' x_K never enters the state: average the active set
-        "client_drift": T.masked_client_mean(
-            jnp.sum(jnp.square((x_K - x_s_row[None]).astype(f32)), axis=1),
-            mask),
-        "used_arena": jnp.ones((), f32),
-    }
-    if fplan is not None or keep is not None:
-        tx = faults.combine_mask(pmask, fplan, None)
-        if faults.async_on(cfg):
-            tx = staleness.fresh_mask(tx, fplan)
-        metrics |= faults.fault_metrics(fplan, tx, keep) | sm
-    return new_state, metrics
+            jnp.sum((c_i_new - c_new[None]).astype(jnp.float32), axis=0)),
+    } | arena_metrics(adm, x_K, x_s_row)
 
 
 def _round(cfg: FederatedConfig, state, grad_fn, batch, per_step_batches=False):
@@ -351,64 +270,39 @@ def _round(cfg: FederatedConfig, state, grad_fn, batch, per_step_batches=False):
     else:
         x_K, _ = jax.lax.scan(one_step, x_s_b, None, length=K)
 
+    # same admission contract as the arena path: x_s_b is the zero-delta
+    # fallback, c_i refreshes on fresh participation only
+    adm = admit(cfg, x_K, arena=None, round_idx=state["round"], m=m, ref=x_s,
+                fallback=x_s_b, state=state)
     # multiply by the precomputed 1/(K eta), NOT divide by (K eta): the same
     # rounding as the fused arena kernel, so the parity tests compare paths
     # at f32 resolution instead of absorbing a divide-vs-multiply ulp
     alpha = 1.0 / (K * eta)
-    fplan = faults.plan(cfg, state["round"], m)
-    x_t = faults.inject_tree(cfg.faults, fplan, x_K)
     c_i_new = T.tmap(
         lambda ci, cc, s, xk: ci - cc + (s - xk) * _step_for(alpha, xk),
-        c_i, c_b, x_s_b, x_t)
-    x_up = x_t
-    pmask = None
-    if cfg.participation < 1.0:
-        pmask = T.participation_mask(
-            participation_key(cfg, state["round"]), m, cfg.participation
-        )
-    keep = None
-    if faults.screening_on(cfg):
-        keep = faults.screen_keep_tree(cfg, x_t, x_s)
-    mask = faults.combine_mask(pmask, fplan, keep)
-    sm = {}
-    stale_up = {}
-    if faults.async_on(cfg):
-        # same stale-dual contract as the arena path: x_s_b is the
-        # zero-delta baseline, c_i refreshes on fresh participation only
-        x_up, mask, stale_up, sm = staleness.step_tree(
-            cfg, fplan, x_t, x_s_b, mask, state)
-        c_i_new = T.tree_select(mask, c_i_new, c_i)
-    elif mask is not None:
-        # silent/demoted clients transmit nothing (zero delta, c_i kept) --
-        # same contract as the arena path
-        c_i_new = T.tree_select(mask, c_i_new, c_i)
-        x_up = T.tree_select(mask, x_t, x_s_b)
+        c_i, c_b, x_s_b, adm.sent)
+    if adm.mask is not None:
+        c_i_new = T.tree_select(adm.mask, c_i_new, c_i)
     # server: TWO all-reduces (x-delta and c-delta)
-    dx = T.tree_client_mean(T.tree_sub(x_up, x_s_b))
+    dx = T.tree_client_mean(T.tree_sub(adm.admitted, x_s_b))
     dc = T.tree_client_mean(T.tree_sub(c_i_new, c_i))
     x_s_new = T.tree_axpy(cfg.eta_g, dx, x_s)
     c_new = T.tree_add(c, dc)
 
-    new_state = {
+    new_state = adm.state | {
         "x_s": x_s_new,
         "c": c_new,
         "c_i": c_i_new,
         "round": state["round"] + 1,
-        **stale_up,
     }
     metrics = {
         # invariant: sum_i (c_i - c) = 0 given zero init
         "c_sum_norm": T.tree_norm(T.tree_client_sum(T.tree_sub(c_i_new, T.tree_broadcast(c_new, m)))),
         # silent clients' x_K never enters the state: average the active set
         "client_drift": T.masked_client_mean(
-            T.tree_client_sqnorms(T.tree_sub(x_K, x_s_b)), mask),
+            T.tree_client_sqnorms(T.tree_sub(x_K, x_s_b)), adm.mask),
         "used_arena": jnp.zeros((), jnp.float32),
-    }
-    if fplan is not None or keep is not None:
-        tx = faults.combine_mask(pmask, fplan, None)
-        if faults.async_on(cfg):
-            tx = staleness.fresh_mask(tx, fplan)
-        metrics |= faults.fault_metrics(fplan, tx, keep) | sm
+    } | adm.metrics
     return new_state, metrics
 
 

@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from repro.configs.base import FederatedConfig
-from repro.core import fedsplit, make, pdmm, quadratic
+from repro.core import arena, fedsplit, make, pdmm, quadratic
 from repro.core import tree_util as T
 
 
@@ -144,6 +144,40 @@ def test_gpdmm_last_iterate_variant(prob, x0):
         dist[use_avg] = float(jnp.linalg.norm(opt.server_params(s) - prob.x_star))
     assert dist[False] <= dist[True] * 1.5, dist
     assert dist[False] < 1.0 and dist[True] < 1.0, dist
+
+
+# ---------------------------------------------------------------------------
+# Alg. 2 as Alg. 1: AGPDMM runs through GPDMM's round bodies
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("layout", ["pytree", "arena", "cohort"])
+def test_agpdmm_is_gpdmm_restarted_at_the_server(prob, x0, layout):
+    """AGPDMM (Alg. 2) is GPDMM with the last-iterate uplink (eq. 24) whose
+    carry restarts at the broadcast server row every round: three rounds,
+    bitwise, on every layout."""
+    kw = dict(inner_steps=3, eta=0.5 / prob.L, use_arena=layout != "pytree")
+    if layout == "cohort":
+        kw.update(participation=0.5, cohort=True)
+    acc = make(FederatedConfig(algorithm="agpdmm", **kw))
+    gp = make(FederatedConfig(algorithm="gpdmm", use_avg=False, **kw))
+    grad = prob.grad if layout == "pytree" else prob.oracle()
+    s_a, s_g = acc.init(x0, prob.m), gp.init(x0, prob.m)
+    for r in range(3):
+        x_s = gp.server_params(s_g)
+        if layout == "pytree":
+            x_c = T.tree_broadcast(x_s, prob.m)
+        else:
+            row = arena.ArenaSpec.from_tree(x_s).pack(x_s)
+            x_c = jnp.broadcast_to(row[None], s_g["x_c"].shape)
+        s_a, m_a = acc.round(s_a, grad, prob.batch())
+        s_g, m_g = gp.round(dict(s_g, x_c=x_c), grad, prob.batch())
+        assert set(s_g) - set(s_a) == {"x_c"}
+        for k in s_a:
+            np.testing.assert_array_equal(np.asarray(s_a[k]), np.asarray(s_g[k]),
+                                          err_msg=f"round {r}: {k}")
+        for k in m_a:
+            np.testing.assert_array_equal(np.asarray(m_a[k]), np.asarray(m_g[k]),
+                                          err_msg=f"round {r}: {k}")
 
 
 # ---------------------------------------------------------------------------

@@ -286,6 +286,95 @@ def test_cohort_screened_equals_masked_population():
 
 
 # ---------------------------------------------------------------------------
+# the one uplink admission (core.uplink.admit), checked row by row
+# ---------------------------------------------------------------------------
+
+# (round, config): each round's plan holds the rows its case is about --
+# silent, Inf-corrupted and (async) delayed clients
+ADMIT_CASES = {
+    "participation": (2, dict(participation=0.5)),
+    "screen": (2, dict(faults=FaultConfig(dropout=0.3, corrupt=0.3, seed=5),
+                       screen=True, participation=0.5)),
+    "async": (4, dict(faults=FaultConfig(delay=0.4, dropout=0.2, corrupt=0.2,
+                                         delay_max=2, seed=9),
+                      screen=True, max_staleness=2, stale_gamma=0.5)),
+}
+
+
+@pytest.mark.parametrize("case", list(ADMIT_CASES))
+@pytest.mark.parametrize("layout", ["arena", "pytree"])
+def test_admit_contract(layout, case):
+    # silent and demoted rows take the fallback, the screen's median and
+    # faults_demoted see only the round's transmitters, and the mask is
+    # combine_mask's (fresh rows only under the staleness engine)
+    from repro.core import staleness
+    from repro.core.uplink import admit, participation_key
+
+    r, kw = ADMIT_CASES[case]
+    cfg = FederatedConfig(algorithm="gpdmm", inner_steps=1, eta=0.1, **kw)
+    plan = faults.plan(cfg, r, M)
+    pmask = (T.participation_mask(participation_key(cfg, r), M,
+                                  cfg.participation)
+             if cfg.participation < 1.0 else None)
+    tx = faults.combine_mask(pmask, plan, None)
+    tx = np.ones(M, bool) if tx is None else np.asarray(tx)
+    k1, k2 = jax.random.split(jax.random.key(4))
+    ref = 0.5 * np.ones(D, np.float32)
+    rows = np.array(jax.random.normal(k1, (M, D)))
+    # rows that do not transmit sit on the reference: counted in the median,
+    # they would pull it to zero and demote every honest transmitter
+    rows[~tx] = ref
+    cache = np.asarray(jax.random.normal(k2, (M, D)))
+
+    params = {"w": jnp.zeros((D,), jnp.float32)}
+    spec = arena.ArenaSpec.from_tree(params)
+    if layout == "arena":
+        pack = lambda t: np.asarray(spec.pack_stacked({"w": t}))[:, :D]  # noqa: E731
+        up, fb = spec.pack_stacked({"w": rows}), spec.pack_stacked({"w": cache})
+        adm = admit(cfg, up, arena=spec, round_idx=r, m=M,
+                    ref=spec.pack({"w": ref}), fallback=fb,
+                    state=staleness.init_arena(spec, M))
+    else:
+        pack = lambda t: np.asarray(t["w"])  # noqa: E731
+        adm = admit(cfg, {"w": jnp.asarray(rows)}, arena=None, round_idx=r,
+                    m=M, ref={"w": jnp.asarray(ref)},
+                    fallback={"w": jnp.asarray(cache)},
+                    state=staleness.init_tree(params, M))
+    sent = pack(adm.sent) if layout == "pytree" else np.asarray(adm.sent)[:, :D]
+    admitted = (pack(adm.admitted) if layout == "pytree"
+                else np.asarray(adm.admitted)[:, :D])
+
+    keep = None
+    if faults.screening_on(cfg):
+        finite = np.isfinite(sent).all(axis=1)
+        d = np.where(np.isfinite(sent), sent - ref, 0.0)
+        sq = (d * d).sum(axis=1)
+        med = np.median(sq[finite & tx])
+        keep = finite & (sq <= cfg.screen_mult * max(med, 1e-12))
+    mask = faults.combine_mask(pmask, plan, None if keep is None
+                               else jnp.asarray(keep))
+    fresh = tx
+    if faults.async_on(cfg):
+        delayed = np.asarray(plan.delayed)
+        assert delayed.any()
+        mask = staleness.fresh_mask(mask, plan)
+        fresh = tx & ~delayed
+    mask = np.asarray(mask)
+    np.testing.assert_array_equal(np.asarray(adm.mask), mask)
+    assert (~mask).any() and mask.any()
+    # empty stale slots: no arrival mixes in, so every dropped row is the
+    # fallback's and every admitted row is what was sent
+    np.testing.assert_array_equal(admitted[mask], sent[mask])
+    np.testing.assert_array_equal(admitted[~mask], cache[~mask])
+    if keep is None:
+        assert adm.metrics == {}
+    else:
+        assert (~keep & tx).any()
+        assert float(adm.metrics["faults_demoted"]) == float(
+            (fresh & ~keep).sum())
+
+
+# ---------------------------------------------------------------------------
 # all-silent round: a well-defined no-op
 # ---------------------------------------------------------------------------
 
