@@ -470,7 +470,10 @@ class ArchConfig:
 
     # --- distribution ---
     fed: FederatedConfig = field(default_factory=FederatedConfig)
-    remat: bool = True
+    remat: bool = True  # checkpoint each layer unit in training: its weight
+    #   matmuls' outputs (q/k/v/o projections, the MLP's three) are saved, and
+    #   the backward pass recomputes only the norms, RoPE, activations and
+    #   attention's score and value products (models/stack.py)
     scan_layers: bool = True
     microbatch: Optional[int] = None  # split the per-client batch into this
     #   many grad-accumulation chunks inside each inner step (activation

@@ -246,7 +246,10 @@ def stack_apply(
             return x, ncs, aux_u
 
         if cfg.remat and mode == "train":
-            unit_fn = jax.checkpoint(unit_fn, static_argnums=())
+            # keep the outputs of the weight matmuls (dot_generals with no
+            # batch dims), recompute the rest in the backward pass
+            unit_fn = jax.checkpoint(
+                unit_fn, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
 
         if not cfg.scan_layers:
             # unrolled path: used by the roofline probes (XLA cost analysis

@@ -255,17 +255,15 @@ def test_arena_kernels_read_the_arena_in_place(sds, case):
     assert ops.LAYOUT[case.split("-")[0]][0] % 8 == 0
 
 
-def test_silo_round_fits_one_chip(topo, monkeypatch):
-    """The olmo1b.silo2 round compiles for one v5e: OLMo-1B at its
+@pytest.fixture(scope="module")
+def silo_round(topo):
+    """The olmo1b.silo2 round compiled for one v5e: OLMo-1B at its
     published widths and 4 layers, float32 state and bfloat16 compute, two
-    silos, one 2048-token sequence each.  Its five float32 arena rows (x_s,
-    two x_c, two lam) leave the round about 8 GB, which holds because a
-    plain gradient steps the arena a client at a time and the kernels read
-    the arena in place: no copy or pad of the client arena's size."""
+    silos, one 2048-token sequence each.  Returns the compiled round, the
+    arena width and what the round kernels resolved to."""
     from repro.configs.base import FederatedConfig
     from repro.core import make
 
-    monkeypatch.setattr(ops, "default_impl", lambda: "pallas")
     one_chip = SingleDeviceSharding(topo.devices[0])
     on_chip = lambda t: jax.tree.map(  # noqa: E731
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), t)
@@ -279,14 +277,51 @@ def test_silo_round_fits_one_chip(topo, monkeypatch):
     def one_round(s, b):
         return fed.round(s, lambda p, bi: jax.grad(lambda q: model.loss(q, bi)[0])(p), b)
 
-    compiled = jax.jit(one_round, donate_argnums=0).lower(
-        state, {"tokens": toks, "targets": toks}).compile()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "default_impl", lambda: "pallas")
+        compiled = jax.jit(one_round, donate_argnums=0).lower(
+            state, {"tokens": toks, "targets": toks}).compile()
+        resolved = dict(ops.RESOLVED)
     width = arena.ArenaSpec.from_tree(params).width
     assert state["x_c"].shape == (M, width) and state["x_c"].dtype == jnp.float32
+    return compiled, width, resolved
+
+
+def test_silo_round_fits_one_chip(silo_round):
+    """The olmo1b.silo2 round compiles for one v5e.  Its five float32 arena
+    rows (x_s, two x_c, two lam) leave the round about 8 GB, which holds
+    because a plain gradient steps the arena a client at a time and the
+    kernels read the arena in place: no copy or pad of the client arena's
+    size."""
+    compiled, width, resolved = silo_round
     big = [(n, op, dims) for n, (op, dims) in _hlo_shapes(compiled.as_text()).items()
            if op in ("pad", "copy") and math.prod(dims) >= M * width]
     assert not big, big
-    assert ops.RESOLVED["fused_update_client"] == "pallas"
+    assert resolved["fused_update_client"] == "pallas"
+
+
+def _batch_dims(spec: str) -> str:
+    """The batch dims of an einsum ``spec``: letters of both operands and
+    the output."""
+    ins, out = spec.split("->")
+    lhs, rhs = ins.split(",")
+    return "".join(c for c in out if c in lhs and c in rhs)
+
+
+def test_silo_round_recomputes_no_weight_matmul(silo_round):
+    """The backward pass of the olmo1b.silo2 round reruns none of the
+    model's weight matmuls: each checkpointed layer keeps their outputs, so
+    the only matmuls the compiled program recomputes (under JAX's
+    ``rematted_computation`` name) are attention's score and value
+    products, which have batch dims.  An einsum is known by the spec in its
+    name; a plain ``@`` names none and counts as a weight matmul."""
+    compiled = silo_round[0]
+    rematted = [name.split("/")[-2] for name in re.findall(
+        r"= \S+ (?:convolution|dot)\(.*op_name=\"([^\"]*)\"", compiled.as_text())
+        if "/rematted_computation/" in name]
+    assert rematted, "no rematerialised matmul: attention's products should be"
+    weight = [n for n in rematted if "->" not in n or not _batch_dims(n)]
+    assert not weight, weight
 
 
 def test_platform_selects_the_implementation(monkeypatch):

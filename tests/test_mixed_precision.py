@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.ad_checkpoint import print_saved_residuals
 
 from repro.configs import ARCHS, get_arch
 from repro.configs.base import FederatedConfig
@@ -128,7 +129,8 @@ def test_mixed_matmuls_take_bfloat16_operands():
     params = scale_002_params(model)
     b = tokens()
     jaxpr = jax.make_jaxpr(jax.grad(lambda p: model.loss(p, b)[0]))(params).jaxpr
-    weights = {s for a in jax.tree.leaves(params) for s in (a.shape, a.shape[1:])}
+    weights = {s for a in jax.tree.leaves(params)
+               for s in (a.shape, a.shape[1:], a.shape[::-1])}  # the head reads W^T
     with_weight = [d for d in _dots(jaxpr) if d[2] & weights]
     assert all(d[:2] == (jnp.bfloat16, jnp.bfloat16) for d in with_weight), with_weight
     # 7 weights a layer, each in 3 products, and the tied head in 3
@@ -205,6 +207,34 @@ def test_rounds_match_reference_driven_by_the_programs_gradient():
     miss = flat({"x_s": dropped["x_s"], "x_c": spec.pack_stacked(dropped["x_c"]),
                  "lam": spec.pack_stacked(dropped["lam"])})
     assert (np.linalg.norm(miss - want, axis=-1) / np.linalg.norm(want, axis=-1)).max() > 1e-2
+
+
+def test_remat_saves_weight_matmul_outputs_and_keeps_the_gradient(capsys):
+    """Each checkpointed layer keeps the outputs of its weight matmuls (the
+    q/k/v/o projections, the MLP's up and gate matmuls) for the backward
+    pass and recomputes the rest, attention's score and value products
+    included; the gradient is the one the model gives without remat, to
+    the bit on the CPU.  The residuals are listed with the layers unrolled,
+    so that each names the function that made it."""
+    b = tokens()
+    params = scale_002_params(build(small_olmo()))
+    grads = {}
+    for remat in (True, False):
+        model = build(small_olmo(remat=remat))
+        grads[remat] = jax.jit(jax.grad(lambda p, model=model: model.loss(p, b)[0]))(params)
+    for (path, g), r in zip(jax.tree_util.tree_leaves_with_path(grads[True]),
+                            jax.tree.leaves(grads[False])):
+        err = float(jnp.max(jnp.abs(g - r)) / jnp.max(jnp.abs(r)))
+        assert err <= 1e-6, (jax.tree_util.keystr(path), err)
+    unrolled = build(small_olmo(scan_layers=False))
+    print_saved_residuals(lambda p: unrolled.loss(p, b)[0], params)
+    saved = capsys.readouterr().out.splitlines()
+    layers = SMALL["n_layers"]
+    # q, k, v and the attention output projection; the MLP's up matmul and
+    # its gate (saved past the SiLU, which is what the backward reads)
+    assert sum("(gqa_apply)" in line for line in saved) == 4 * layers, saved
+    assert sum("(mlp_apply)" in line for line in saved) == 2 * layers, saved
+    assert not any("_flash_xla" in line for line in saved), saved
 
 
 def test_cast_weights_are_not_cast_again():
